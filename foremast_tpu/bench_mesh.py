@@ -18,9 +18,10 @@ Two caveats, encoded in the output rather than hidden:
   * on a real v5e the scoring denominator is ~100× faster than CPU, so
     the share measured here UNDERSTATES what the reduction would cost on
     TPU by roughly that factor; `share_vs_device_scoring_est` re-rates
-    the measured overhead against the real-chip scoring time from the
-    device bench (BENCH_DEVICE_SCORE_S, default the r3 measured 0.106 s
-    fused verdict) for an honest upper-bound estimate.
+    the measured overhead against the real-chip scoring time THIS run's
+    device leg measured (BENCH_DEVICE_SCORE_S, exported by bench.py) for
+    an upper-bound estimate. With no measured device time the estimate
+    is null: nothing is assumed.
 
 Run as a module inside an 8-virtual-device CPU process; prints ONE JSON
 line (bench.py runs it as a child and merges `mesh_*` fields):
@@ -42,10 +43,10 @@ def run(B_total: int = 8192, T: int = 128, k: int = 8,
         n_runs: int = 15) -> dict:
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .parallel import fleet
-    from .parallel.fleet import shard_map  # version-compat shim
     from .parallel.mesh import FLEET_AXIS, fleet_mesh
 
     mesh = fleet_mesh()
@@ -115,7 +116,8 @@ def run(B_total: int = 8192, T: int = 128, k: int = 8,
     # the noise floor is reported so a 0.0 overhead is interpretable
     overhead = max(with_s - without_s, 0.0)
     noise = max(with_std, without_std)
-    device_score_s = float(os.environ.get("BENCH_DEVICE_SCORE_S", "0.106"))
+    raw = os.environ.get("BENCH_DEVICE_SCORE_S", "")
+    device_score_s = float(raw) if raw else None
     return {
         "metric": "fleet_reduction_overhead",
         "value": round(overhead, 6),
@@ -127,9 +129,9 @@ def run(B_total: int = 8192, T: int = 128, k: int = 8,
         "reduction_share_cpu_mesh": round(overhead / with_s, 5) if with_s else 0.0,
         # overhead re-rated against the real-chip scoring denominator:
         # an upper-bound estimate (host-RAM collectives vs ICI)
-        "share_vs_device_scoring_est": round(
-            overhead / (overhead + device_score_s), 5),
-        "device_score_s_assumed": device_score_s,
+        "share_vs_device_scoring_est": None if device_score_s is None
+        else round(overhead / (overhead + device_score_s), 5),
+        "device_score_s_measured": device_score_s,
         "pairs": B,
         "window": T,
         "k": k,

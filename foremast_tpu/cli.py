@@ -15,7 +15,7 @@ One entrypoint covers the reference's process zoo and kubectl plugins:
             with kubectl; here we speak to the API server directly).
   status <app>            print the monitor's phase / job / anomaly.
   prewarm   compile the (family x rung x T-bucket) scoring grid — into
-            the persistent compile cache when COMPILE_CACHE_PATH is set —
+            the persistent compile cache (JAX_COMPILATION_CACHE_DIR) —
             so runtime pods start without the first-cycle compile storm
             (engine/pipeline.py, docs/performance.md).
   demo      self-contained local loop: chaos app + fake metric source +
@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import logging
 import sys
 
 from .utils import knobs
@@ -599,20 +600,21 @@ def cmd_trigger(args) -> int:
 def cmd_prewarm(args) -> int:
     """Compile the standard (family x rung x T-bucket) scoring grid.
 
-    With COMPILE_CACHE_PATH set the compiled programs land in the
-    persistent cache, so every runtime pointed at the same cache dir
-    (ReadWriteMany volume in the shipped manifests) starts warm; without
-    it this is a dry-run that prints what a cold start would compile.
+    The compiled programs land in the persistent compile cache
+    (JAX_COMPILATION_CACHE_DIR, or a source checkout's `.jax_cache/`), so
+    every runtime pointed at the same directory (ReadWriteMany volume in
+    the shipped manifests) starts warm; with no cache directory this is a
+    dry-run that prints what a cold start would compile. The JSON names
+    the device the programs were compiled for.
     """
     from .engine.config import from_env
-    from .engine.pipeline import enable_compile_cache, prewarm
+    from .engine.pipeline import device_info, enable_compile_cache, prewarm
 
+    # per-program progress on stderr (stdout stays the one JSON record)
+    logging.basicConfig(level=logging.INFO,
+                        format="%(asctime)s %(levelname)s %(message)s")
     cfg = from_env()
-    cache_on = bool(cfg.compile_cache_path) and enable_compile_cache(
-        cfg.compile_cache_path)
-    if cfg.compile_cache_path and not cache_on:
-        print("warning: this jax build has no persistent compilation "
-              "cache; prewarm only warms THIS process", file=sys.stderr)
+    cache_dir = enable_compile_cache()
     try:
         rungs = tuple(int(r) for r in args.rungs.split(",") if r.strip())
         buckets = tuple(int(b) for b in args.buckets.split(",") if b.strip())
@@ -622,8 +624,8 @@ def cmd_prewarm(args) -> int:
         print(f"invalid prewarm grid: {e}", file=sys.stderr)
         return 2
     info = prewarm(cfg, families=families, rungs=rungs, t_buckets=buckets)
-    # report the cache as active only when the knob actually took
-    info["compile_cache"] = cfg.compile_cache_path if cache_on else None
+    info["compile_cache"] = cache_dir or None
+    info.update(device_info())
     print(json.dumps(info, indent=2))
     return 0
 
@@ -760,8 +762,8 @@ def build_parser() -> argparse.ArgumentParser:
         sp.set_defaults(func=fn)
     pw = sub.add_parser(
         "prewarm",
-        help="compile the scoring-program grid (into COMPILE_CACHE_PATH "
-             "when set) so runtimes start without the compile storm",
+        help="compile the scoring-program grid into the persistent "
+             "compile cache so runtimes start without the compile storm",
     )
     pw.add_argument("--families",
                     default="pair,band,bivariate,hpa,triage",

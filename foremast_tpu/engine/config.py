@@ -172,11 +172,6 @@ class EngineConfig:
     # past the cap chunk at it — still ~8x fewer launches than the rung
     # path's score_batch chunks.
     megabatch_max_rows: int = 32768
-    # persistent XLA compilation cache directory (COMPILE_CACHE_PATH;
-    # empty = disabled). A restarted process reuses compiled programs
-    # instead of re-paying the first-cycle compile storm (~26 s per mixed
-    # fleet on CPU, BENCH_r05).
-    compile_cache_path: str = ""
     # compile the standard (family x rung x T-bucket) grid in a background
     # thread at startup (PREWARM_ON_START; engine/pipeline.py:prewarm), so
     # the first live cycle doesn't eat the compile storm either. Also
@@ -449,7 +444,6 @@ def from_env(env=None) -> EngineConfig:
         ),
         megabatch=_env_bool(env, "MEGABATCH", False),
         megabatch_max_rows=_env_int(env, "MEGABATCH_MAX_ROWS", 32768),
-        compile_cache_path=env.get("COMPILE_CACHE_PATH", ""),
         prewarm_on_start=_env_bool(env, "PREWARM_ON_START", False),
         ma_window=_env_int(env, "MA_WINDOW", 30),
         long_window_steps=_env_int(env, "LONG_WINDOW_STEPS", 4096),

@@ -18,10 +18,12 @@ a pipeline at three levels:
      nothing blocks until the final collect phase materializes them, so
      the four batch families interleave freely on the device queue.
   3. **persistent compile cache + prewarm** — `enable_compile_cache`
-     points XLA's persistent compilation cache at COMPILE_CACHE_PATH so
-     a restarted process skips the first-cycle compile storm, and
-     `prewarm` compiles the standard (family x rung x T-bucket) grid up
-     front (CLI: `foremast-tpu prewarm`; runtime: PREWARM_ON_START).
+     keeps XLA's persistent compilation cache where
+     JAX_COMPILATION_CACHE_DIR says (a source checkout defaults to its
+     own `.jax_cache/`) so a restarted process skips the first-cycle
+     compile storm, and `prewarm` compiles the standard (family x rung x
+     T-bucket) grid up front (CLI: `foremast-tpu prewarm`; runtime:
+     PREWARM_ON_START).
 
 Two contracts are preserved exactly:
 
@@ -36,12 +38,18 @@ Two contracts are preserved exactly:
 """
 from __future__ import annotations
 
+import logging
+import os
 import time
+from contextlib import contextmanager
 
-from ..utils import tracing
+from ..utils import knobs, tracing
 
-__all__ = ["CyclePipeline", "CompileCounter", "enable_compile_cache",
-           "prewarm", "STANDARD_RUNGS", "STANDARD_T_BUCKETS"]
+log = logging.getLogger("foremast_tpu.engine.pipeline")
+
+__all__ = ["CyclePipeline", "CompileCounter", "compile_cache_dir",
+           "enable_compile_cache", "device_info", "prewarm",
+           "STANDARD_RUNGS", "STANDARD_T_BUCKETS"]
 
 
 class CyclePipeline:
@@ -368,7 +376,7 @@ class CompileCounter:
     which is what the steady-state zero-recompile gate asserts. With the
     persistent cache enabled, backend_compile wraps retrieval too, so the
     compile-storm question becomes `cache_misses` (fresh work) vs
-    `cache_hits` (replayed from COMPILE_CACHE_PATH).
+    `cache_hits` (replayed from the persistent cache directory).
     """
 
     COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -392,51 +400,86 @@ class CompileCounter:
         elif event == self.CACHE_MISS_EVENT:
             self.cache_misses += 1
 
-    def __enter__(self):
+    def start(self):
+        """Begin counting (also the `with` entry). A long-lived owner —
+        the runtime, between start() and stop() — calls this directly."""
         import jax.monitoring as jm
 
         jm.register_event_duration_secs_listener(self._on_duration)
         jm.register_event_listener(self._on_event)
         return self
 
-    def __exit__(self, *exc):
-        try:
-            from jax._src import monitoring as _m
+    def stop(self):
+        """Stop counting and take both listeners back out."""
+        import jax.monitoring as jm
 
-            _m._unregister_event_duration_listener_by_callback(
-                self._on_duration)
-            _m._unregister_event_listener_by_callback(self._on_event)
-        except Exception:  # noqa: BLE001 - best-effort on private API drift
-            pass
+        jm.unregister_event_duration_listener(self._on_duration)
+        jm.unregister_event_listener(self._on_event)
+
+    __enter__ = start
+
+    def __exit__(self, *exc):
+        self.stop()
         return False
 
 
-def enable_compile_cache(path: str) -> bool:
-    """Point JAX's persistent compilation cache at `path` (COMPILE_CACHE_PATH).
+# the in-checkout persistent cache: ONE fixed path (the directory is part
+# of XLA's cache key, so a moving path never hits), gitignored
+_CHECKOUT_CACHE = ".jax_cache"
+
+
+def compile_cache_dir(env: dict | None = None) -> str:
+    """Where the persistent XLA compilation cache lives, or "".
+
+    JAX_COMPILATION_CACHE_DIR wins whenever it is set — JAX reads it
+    itself and no code here sets another directory. Unset, a process run
+    from a source checkout (pyproject.toml beside the package) uses the
+    checkout's fixed `.jax_cache/`; an installed package gets no
+    persistent cache until the deployment sets the variable."""
+    explicit = knobs.read("JAX_COMPILATION_CACHE_DIR", env)
+    if explicit:
+        return explicit
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    if os.path.isfile(os.path.join(root, "pyproject.toml")):
+        return os.path.join(root, _CHECKOUT_CACHE)
+    return ""
+
+
+def enable_compile_cache(env: dict | None = None) -> str:
+    """Turn the persistent compilation cache on for this process and
+    return the directory in effect ("" = none). Entry points call this
+    before anything jits (`serve`, `prewarm`, the bench children).
 
     Zeroes the min-compile-time/entry-size gates so even the small
     per-(rung, T) programs persist — they are exactly what the first-cycle
-    compile storm is made of. Returns False (without raising) on jax
-    builds that lack the knobs: the engine must run identically, just
-    without restart amortization.
-    """
-    if not path:
-        return False
+    compile storm is made of. The directory itself is only ever set here
+    when JAX_COMPILATION_CACHE_DIR is NOT (see `compile_cache_dir`)."""
     import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-    except Exception:  # noqa: BLE001 - knob missing on this jax build
-        return False
-    for knob, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # noqa: BLE001 - defaults still cache big entries
-            pass
-    return True
+    if not knobs.read("JAX_COMPILATION_CACHE_DIR", env):
+        default = compile_cache_dir(env)
+        if default:
+            jax.config.update("jax_compilation_cache_dir", default)
+    # what JAX itself holds is the answer: its own reading of the
+    # variable at import, or the checkout default set just above
+    path = jax.config.jax_compilation_cache_dir or ""
+    if path:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
+
+
+def device_info() -> dict:
+    """Where this process computes, as JAX reports it — stamped on
+    `/status.build` and `prewarm`'s JSON so no result can pass for a
+    chip's without naming one. Initializes the backend."""
+    import jax
+
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
 
 
 # ----------------------------------------------------------------- prewarm
@@ -492,6 +535,18 @@ def prewarm(config=None,
 
     t0 = time.perf_counter()
     programs = 0
+
+    @contextmanager
+    def program(family, rung, T):
+        # one log line per program: on the chip a single program can take
+        # a minute to compile, and a silent prewarm looks like a hang
+        nonlocal programs
+        t1 = time.perf_counter()
+        yield
+        programs += 1
+        log.info("prewarm %s rung=%d T=%d: %.1fs", family, rung, T,
+                 time.perf_counter() - t1)
+
     with CompileCounter() as cc:
         for T in t_buckets:
             n_c = max(T // 4, 8)
@@ -508,32 +563,32 @@ def prewarm(config=None,
                     {b for b in Analyzer._BATCH_BUCKETS if b < cap}
                     | {cap})
                 for r in t_rungs:
-                    np.asarray(triage_ops.screen_rows(
-                        *triage_ops.triage_arg_spec(r, T),
-                        cfg.ma_window)["count"])
-                    programs += 1
+                    with program("triage", r, T):
+                        np.asarray(triage_ops.screen_rows(
+                            *triage_ops.triage_arg_spec(r, T),
+                            cfg.ma_window)["count"])
             for r in rungs:
                 if "pair" in families:
                     # the fused pairwise program straight at the kernel:
                     # fleet.pair_arg_spec mirrors _launch_pairs' packing
-                    np.asarray(fl.score_pairs(*fl.pair_arg_spec(r, T))
-                               ["unhealthy"])
-                    programs += 1
+                    with program("pair", r, T):
+                        np.asarray(fl.score_pairs(*fl.pair_arg_spec(r, T))
+                                   ["unhealthy"])
                 if "band" in families:
-                    an._score_bands([
-                        _BandItem(f"w{i}", "latency", win(n_h), win(n_c),
-                                  policy)
-                        for i in range(r)
-                    ])
-                    programs += 1
+                    with program("band", r, T):
+                        an._score_bands([
+                            _BandItem(f"w{i}", "latency", win(n_h),
+                                      win(n_c), policy)
+                            for i in range(r)
+                        ])
                 if "bivariate" in families:
-                    an._score_bivariate([
-                        _BiItem(f"w{i}", ("latency", "cpu"),
-                                (win(n_h), win(n_h)), (win(n_c), win(n_c)),
-                                (policy, policy))
-                        for i in range(r)
-                    ])
-                    programs += 1
+                    with program("bivariate", r, T):
+                        an._score_bivariate([
+                            _BiItem(f"w{i}", ("latency", "cpu"),
+                                    (win(n_h), win(n_h)),
+                                    (win(n_c), win(n_c)), (policy, policy))
+                            for i in range(r)
+                        ])
                 if "hpa" in families:
                     items = []
                     for i in range(r):
@@ -541,8 +596,8 @@ def prewarm(config=None,
                                               win(n_c), True, 0))
                         items.append(_HpaItem(f"w{i}", "latency", win(n_h),
                                               win(n_c), True, 1))
-                    an._score_hpa(items)
-                    programs += 1
+                    with program("hpa", r, T):
+                        an._score_hpa(items)
     return {
         "families": list(families),
         "rungs": list(rungs),

@@ -2,13 +2,19 @@
 
 Build-on-first-use: if the shared library is absent and a C++ toolchain is
 available, it is compiled once into the package directory (g++ -O3, ~1 s)
-and cached. Every entry point degrades to ``None`` when the library is
-unavailable so callers keep their pure-Python fallbacks — the extension is
-an accelerator, never a dependency. Disable with FOREMAST_NATIVE=0.
+and cached. The artifact is NAMED by a hash of its source
+(`foremast_native-<sha256[:12]>.so`): a binary copied in from another
+machine or left behind by an older source can never be loaded for this
+source — a different source is a different file name, which does not
+exist until it is built here. Every entry point degrades to ``None`` when
+the library is unavailable so callers keep their pure-Python fallbacks —
+the extension is an accelerator, never a dependency. Disable with
+FOREMAST_NATIVE=0.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,15 +24,14 @@ import numpy as np
 from ..utils import knobs
 
 __all__ = ["available", "parse_series", "parse_grid", "resample",
-           "render_matrix", "lib_path"]
+           "render_matrix", "lib_path", "parser_name"]
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "src", "foremast_native.cpp")
 # FOREMAST_NATIVE_SO points the loader at an alternate build (the ASAN
 # fuzz leg in tests/test_native_fuzz.py); default is the cached in-package
 # artifact. Read at import: the override is a per-process test seam.
-_SO = (knobs.read("FOREMAST_NATIVE_SO")
-       or os.path.join(_DIR, "foremast_native.so"))
+_SO_OVERRIDE = knobs.read("FOREMAST_NATIVE_SO")
 
 _lock = threading.Lock()
 _lib = None
@@ -37,19 +42,42 @@ FLAVOR_WAVEFRONT = 1
 
 
 def lib_path() -> str:
-    return _SO
+    """The library this process loads (or would build): the override, or
+    the in-package artifact named by the hash of the source as it is on
+    disk now. "" when there is neither override nor source."""
+    if _SO_OVERRIDE:
+        return _SO_OVERRIDE
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    except OSError:
+        return ""
+    return os.path.join(_DIR, f"foremast_native-{digest}.so")
 
 
-def _build() -> bool:
+def parser_name() -> str:
+    """Which response parser this process runs: the native library's file
+    name, or "python" for the pure-Python fallback."""
+    return os.path.basename(lib_path()) if available() else "python"
+
+
+def _build(so: str) -> bool:
     cxx = knobs.read("CXX")
     extra = knobs.read("FOREMAST_NATIVE_CXXFLAGS").split()
+    # build beside the target and rename into place: concurrent first
+    # loads (replica subprocesses) never dlopen a half-written file
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = [cxx, "-O3", "-shared", "-fPIC", "-std=c++17",
-           *extra, _SRC, "-o", _SO]
+           *extra, _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so)
         return True
     except (OSError, subprocess.SubprocessError):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _load():
@@ -79,19 +107,18 @@ def _try_load():
     global _lib, _state
     if not knobs.read("FOREMAST_NATIVE"):
         return None
-    if not os.path.exists(_SO) or (
-        os.path.exists(_SRC)
-        and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-    ):
-        if not _build():
-            return None
+    so = lib_path()
+    if not so:
+        return None
+    if not os.path.exists(so) and not _build(so):
+        return None
     try:
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
         _bind(lib)
     except (OSError, AttributeError):
-        # AttributeError: a stale prebuilt .so missing a newer symbol (src
-        # absent so the rebuild check couldn't fire) — degrade to the
-        # Python path rather than crashing the first fetch
+        # AttributeError: an override build (FOREMAST_NATIVE_SO) missing a
+        # newer symbol — degrade to the Python path rather than crashing
+        # the first fetch
         return None
     _lib = lib
     _state = "ready"

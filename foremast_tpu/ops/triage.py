@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import forecast as fc
+from .rowblock import vmap_rows
 
 __all__ = ["screen_rows", "triage_arg_spec"]
 
@@ -144,9 +145,12 @@ def screen_rows(values, mask, region, threshold, bound, min_lower_bound,
     explicit signature lets jit resolve the name to its position, which
     `jit(vmap(...), static_argnames=...)` cannot (vmap's *args wrapper
     hides the signature, silently tracing `window` instead)."""
-    return jax.vmap(_screen_1d, in_axes=(0, 0, 0, 0, 0, 0, 0, None))(
-        values, mask, region, threshold, bound, min_lower_bound, margin,
-        window)
+    # row-blocked (ops/rowblock.py): the screen's median/MAD sorts are the
+    # same compile-time hazard on the TPU as the pair family's rank view
+    return vmap_rows(
+        partial(_screen_1d, window=window),
+        (values, mask, region, threshold, bound, min_lower_bound, margin),
+        values.shape[-1])
 
 
 def triage_arg_spec(B: int, T: int):

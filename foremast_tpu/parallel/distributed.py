@@ -61,11 +61,20 @@ def initialize(coordinator: str | None = None, num_processes: int | None = None,
         else knobs.read("PROCESS_ID", env)
     if not coordinator or n <= 1:
         # single-host, or Cloud TPU pod where jax auto-detects: only call
-        # into jax.distributed when the pod metadata says we are multi-host.
-        # A partial config (coordinator without world size or vice versa,
-        # or a templated NUM_PROCESSES=1) must not kill a runtime that
-        # works fine single-host — warn and proceed local.
-        if knobs.read("TPU_WORKER_HOSTNAMES", env):
+        # into jax.distributed when the pod metadata names MORE THAN ONE
+        # worker. A single-host TPU VM sets TPU_WORKER_HOSTNAMES=localhost
+        # too, and there the argument-less jax.distributed.initialize()
+        # goes to the metadata server for the rest of the cluster spec —
+        # on a machine with no metadata server it raises before the first
+        # cycle (observed on the v5e check machine), for a world of one
+        # that needs no handshake. A partial config (coordinator without
+        # world size or vice versa, or a templated NUM_PROCESSES=1) must
+        # not kill a runtime that works fine single-host either — warn
+        # and proceed local.
+        workers = [h for h in
+                   knobs.read("TPU_WORKER_HOSTNAMES", env).split(",")
+                   if h.strip()]
+        if len(workers) > 1:
             jax.distributed.initialize()
             _initialized = True
             return True

@@ -25,19 +25,12 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.5 exports shard_map at top level (check_vma spelling)
-    from jax import shard_map
-except ImportError:  # older jax: experimental module, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, /, **kw):
-        kw["check_rep"] = kw.pop("check_vma", True)
-        return _shard_map_legacy(f, **kw)
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops import forecast as fc
 from ..ops.pairwise import sign_test_exact, two_sample_tests
+from ..ops.rowblock import vmap_rows
 from .mesh import FLEET_AXIS, fleet_sharding
 
 __all__ = ["score_pairs", "pair_arg_spec", "make_fleet_scorer",
@@ -173,10 +166,18 @@ def _pair_verdict(
     }
 
 
+def _score_rows(*args):
+    """`vmap(_pair_verdict)` over the batch axis in row blocks
+    (ops/rowblock.py: the TPU compiler's time grows with the elements of
+    the block it is shown; a row's sorted view spans both samples)."""
+    return vmap_rows(_pair_verdict, args,
+                     args[0].shape[-1] + args[2].shape[-1])
+
+
 # NOTE: jitted calls ASYNC-dispatch — the returned dict holds device
 # values that materialize only when the caller converts them (the engine's
 # launch/collect split in analyzer._launch_chunks rides exactly this).
-score_pairs = jax.jit(jax.vmap(_pair_verdict))
+score_pairs = jax.jit(_score_rows)
 
 
 def pair_arg_spec(B: int, T: int):
@@ -229,7 +230,7 @@ def make_fleet_scorer(mesh, k: int = 8):
         pvalue_threshold, test_mask, combine, ma_window,
         band_threshold, bound_mode, min_lower_bound, min_points, global_idx,
     ):
-        out = jax.vmap(_pair_verdict)(
+        out = _score_rows(
             baseline, b_mask, current, c_mask,
             pvalue_threshold, test_mask, combine, ma_window,
             band_threshold, bound_mode, min_lower_bound, min_points,

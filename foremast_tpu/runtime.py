@@ -100,8 +100,10 @@ Env surface (union of the reference services'):
   SCORE_MEMO             fingerprint score memoization (default on):
                          unchanged job rows reuse last cycle's verdict
                          without a device launch (engine/pipeline.py)
-  COMPILE_CACHE_PATH     persistent XLA compilation cache dir: restarts
-                         skip the first-cycle compile storm
+  JAX_COMPILATION_CACHE_DIR  JAX's own persistent compilation cache
+                         dir: restarts skip the first-cycle compile
+                         storm. Unset, `serve` from a source checkout
+                         uses the checkout's fixed .jax_cache/
   PREWARM_ON_START       background-compile the standard (family x rung
                          x T-bucket) grid at startup (also available as
                          `foremast-tpu prewarm`)
@@ -120,6 +122,7 @@ from .dataplane.fetch import CachingDataSource, PrometheusDataSource
 from .engine.analyzer import Analyzer
 from .engine.config import EngineConfig, from_env
 from .engine.jobs import JobStore
+from .engine.pipeline import CompileCounter
 from .service.api import ForemastService, make_server
 from .utils import knobs
 
@@ -176,19 +179,10 @@ class Runtime:
         from .utils import tracing as tracing_mod
 
         tracing_mod.tracer.set_sample_rate(trace_sample)
-        # persistent XLA compile cache (COMPILE_CACHE_PATH): point the
-        # backend at the shared cache dir BEFORE anything jits, so a
-        # restarted pod replays compiled programs instead of re-paying the
-        # first-cycle compile storm (engine/pipeline.py)
-        if self.config.compile_cache_path:
-            from .engine.pipeline import enable_compile_cache
-
-            if enable_compile_cache(self.config.compile_cache_path):
-                log.info("compile cache at %s",
-                         self.config.compile_cache_path)
-            else:
-                log.warning("compile cache unsupported by this jax build; "
-                            "continuing without")
+        # XLA compile work between start() and stop(): /status.build says
+        # whether a start was warm (persistent-cache hits) or paid the
+        # compile storm (engine/pipeline.py CompileCounter)
+        self.compile_counter = CompileCounter()
         self.exporter = VerdictExporter()
         source = data_source or PrometheusDataSource()
         # -- chaos layer (FOREMAST_CHAOS): deterministic fault injection
@@ -540,6 +534,7 @@ class Runtime:
             worker = self.replica_id if self.shard is not None else "worker-0"
         self.cycle_seconds = cycle_seconds
         self.analyzer.health.configure(cycle_seconds=cycle_seconds)
+        self.service.compile_counter = self.compile_counter.start()
         http_kw = {} if http_max_inflight is None else {
             "max_in_flight": http_max_inflight}
         self._server = make_server(self.service, host, port, **http_kw)
@@ -763,6 +758,9 @@ class Runtime:
             return
         self._stopped = True
         self._stop.set()
+        if self.service.compile_counter is not None:  # start() ran
+            self.compile_counter.stop()
+            self.service.compile_counter = None
         if drain_seconds is None:
             drain_seconds = max(self.config.cycle_deadline_seconds,
                                 self.config.fetch_cycle_deadline_seconds,
@@ -874,8 +872,13 @@ def main():
 
     install_log_filter()
 
+    from .engine.pipeline import device_info, enable_compile_cache
     from .parallel.distributed import host_info, initialize, replica_identity
 
+    # before anything jits: a restarted process replays compiled programs
+    # from the persistent cache instead of re-paying the compile storm
+    cache_dir = enable_compile_cache()
+    log.info("compile cache: %s", cache_dir or "off")
     # multi-host (DCN) deploys join the jax.distributed world here; plain
     # single-host deploys fall straight through
     if initialize():
@@ -885,6 +888,10 @@ def main():
             hi.process_id, hi.num_processes, hi.local_devices,
             hi.global_devices,
         )
+    # claim the device NOW: a process that cannot reach its accelerator
+    # dies at boot with JAX's own error, instead of serving while every
+    # launch fails into per-job errors
+    log.info("device: %s", device_info())
     archive = None
     es = knobs.read("ES_ENDPOINT")
     archive_path = knobs.read("ARCHIVE_PATH")

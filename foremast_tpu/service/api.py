@@ -330,6 +330,9 @@ class ForemastService:
         # OtlpTraceExporter): /status gets a trace_export section
         self.trace_exporter = trace_exporter
         self.chaos_active = False  # stamped by the runtime when chaos is on
+        # stamped by Runtime.start(): the CompileCounter running since
+        # start, surfaced as /status build.compile
+        self.compile_counter = None
         # set by make_server: () -> the HTTP admission gate's shed counter
         self.http_shed_count = None
         # /status build section: dumps and bug reports self-identify
@@ -757,6 +760,7 @@ class ForemastService:
         counters. The answer to "is the brain healthy, and if not, which
         dependency is it protecting itself from?" in one request."""
         from .. import __version__
+        from ..engine.pipeline import device_info
 
         out = {
             "status": "ok",
@@ -766,8 +770,17 @@ class ForemastService:
                 "version": __version__,
                 "uptime_s": round(time.time() - self.started_at, 1),
                 "cycle_id": getattr(self.analyzer, "current_cycle_id", ""),
+                # where the scoring programs run, as JAX reports it
+                **device_info(),
             },
         }
+        cc = self.compile_counter
+        if cc is not None:
+            out["build"]["compile"] = {
+                "backend_compiles": cc.compiles,
+                "cache_hits": cc.cache_hits,
+                "cache_misses": cc.cache_misses,
+            }
         if self.analyzer is not None and getattr(
                 self.analyzer, "last_cycle_stages", None):
             # the last cycle's stage/family timing decomposition (the

@@ -18,7 +18,7 @@ import json
 import time
 
 __all__ = ["run_fleet", "run_fleet_ab", "run_jobstore", "run_live",
-           "main"]
+           "create_body", "main"]
 
 
 def _rss_bytes() -> int:
@@ -492,6 +492,23 @@ def run_jobstore(jobs: int = 100000, seed: int = 0, shape: str = "diurnal",
     }
 
 
+def create_body(doc, strategy: str, start_rfc: str, end_rfc: str) -> dict:
+    """The /v1/healthcheck/create request for a simulator Document: its
+    per-metric query URLs by category, with the hpa flags riding every
+    entry (they equal the service's defaults on non-hpa metrics). The one
+    place that knows the wire shape, for `run_live` and `chip_smoke.py`."""
+    info: dict = {"current": {}, "baseline": {}, "historical": {}}
+    for m, q in doc.metrics.items():
+        flags = {"priority": q.priority, "isIncrease": q.is_increase}
+        for cat, url in (("current", q.current), ("baseline", q.baseline),
+                         ("historical", q.historical)):
+            if url:
+                info[cat][m] = {"url": url, **flags}
+    return {"appName": doc.app_name, "namespace": doc.namespace,
+            "strategy": strategy, "startTime": start_rfc,
+            "endTime": end_rfc, "metricsInfo": info}
+
+
 def run_live(endpoint: str, jobs: int = 200, seed: int = 0,
              shape: str = "diurnal", duration_s: float = 60.0,
              push: bool = False, serve_port: int = 0) -> dict:
@@ -521,25 +538,10 @@ def run_live(endpoint: str, jobs: int = 200, seed: int = 0,
     submitted, errors = [], 0
     id_map: dict = {}  # simulator job idx -> the replica's assigned id
     try:
+        start_rfc = to_rfc3339(t0)
+        end_rfc = to_rfc3339(int(time.time() + duration_s + 3600))
         for idx, doc in enumerate(backend.make_docs()):
-            body = {
-                "appName": doc.app_name, "namespace": doc.namespace,
-                "strategy": "canary",
-                "startTime": to_rfc3339(t0),
-                "endTime": to_rfc3339(int(time.time() + duration_s
-                                          + 3600)),
-                "metricsInfo": {
-                    "current": {m: {"url": q.current}
-                                for m, q in doc.metrics.items()
-                                if q.current},
-                    "baseline": {m: {"url": q.baseline}
-                                 for m, q in doc.metrics.items()
-                                 if q.baseline},
-                    "historical": {m: {"url": q.historical}
-                                   for m, q in doc.metrics.items()
-                                   if q.historical},
-                },
-            }
+            body = create_body(doc, "canary", start_rfc, end_rfc)
             req = urllib.request.Request(
                 endpoint.rstrip("/") + "/v1/healthcheck/create",
                 data=json.dumps(body).encode(),

@@ -345,7 +345,11 @@ register("PROCESS_ID", -1, int, "this process's jax.distributed rank")
 register("LOCAL_DEVICE_IDS", "", str,
          "comma-separated local device ids for jax.distributed")
 register("TPU_WORKER_HOSTNAMES", "", str,
-         "Cloud TPU pod metadata: presence selects auto-initialize")
+         "Cloud TPU pod metadata: more than one worker selects "
+         "auto-initialize; a single host starts local")
+register("JAX_COMPILATION_CACHE_DIR", "", str,
+         "JAX's own persistent compilation-cache directory; read here "
+         "only to decide that no code sets another")
 
 # -- kernel-grid constants read at module import (ops/) --
 register("FOREMAST_KS_EXACT_MAX_T", 256, int,

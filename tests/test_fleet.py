@@ -232,9 +232,4 @@ def test_moving_average_band_lowers_with_one_batched_gather_at_most():
     # (per-element indexing reintroduced); an XLA improvement lowering the
     # batched roll without any gather should pass, not fail
     n_gather = hlo.count("stablehlo.gather")
-    # jax < 0.5 lowers the batched roll through two extra gathers (4
-    # total); the per-element regression this pin guards produces O(T)
-    # of them, so the looser legacy bound still catches it
-    legacy_jax = tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5)
-    bound = 4 if legacy_jax else 2
-    assert n_gather <= bound, n_gather  # the batched roll, possibly quoted+typed
+    assert n_gather <= 2, n_gather  # the batched roll, possibly quoted+typed

@@ -225,3 +225,27 @@ def test_parse_grid_empty_and_malformed():
     vals, mask, start = native.parse_grid(empty, native.FLAVOR_PROMETHEUS)
     assert len(vals) == 1 and not mask.any() and start == 0
     assert native.parse_grid(b"{nope", native.FLAVOR_PROMETHEUS) is None
+
+
+def test_artifact_is_named_by_source_hash(tmp_path, monkeypatch):
+    """The chip tool copies the working tree as it stands, gitignored
+    binaries included, so a library built elsewhere (or from an older
+    source) could sit beside the source with a NEWER mtime — the old
+    loader's only rebuild trigger. Naming the artifact by the source's
+    hash makes loading it impossible: another source is another file."""
+    import hashlib
+    import os
+
+    with open(native._SRC, "rb") as f:
+        src = f.read()
+    want = f"foremast_native-{hashlib.sha256(src).hexdigest()[:12]}.so"
+    assert os.path.basename(native.lib_path()) == want
+    if native.available():
+        assert native.parser_name() == want
+    # a changed source names a different artifact, whatever sits on disk
+    edited = tmp_path / "foremast_native.cpp"
+    edited.write_bytes(src + b"\n// edited\n")
+    monkeypatch.setattr(native, "_SRC", str(edited))
+    assert os.path.basename(native.lib_path()) != want
+    # the legacy fixed name is never the load target
+    assert os.path.basename(native.lib_path()) != "foremast_native.so"

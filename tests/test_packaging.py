@@ -4,11 +4,7 @@ package-data globs, and the deploy/Docker entrypoint contract.
 from __future__ import annotations
 
 import os
-
-try:
-    import tomllib  # 3.11+
-except ModuleNotFoundError:  # pragma: no cover - 3.10 (requires-python floor)
-    import tomli as tomllib
+import tomllib
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

@@ -440,9 +440,11 @@ def test_prewarm_grid_covers_matching_cycle_shapes():
 @pytest.mark.perf
 @pytest.mark.slow
 def test_compile_cache_restart_skips_compile_storm(tmp_path):
-    """With COMPILE_CACHE_PATH set, a restarted process replays compiled
-    programs from disk: run the same tiny cycle in two fresh interpreters
-    and require the second to compile (almost) nothing fresh."""
+    """With JAX_COMPILATION_CACHE_DIR set, a restarted process replays
+    compiled programs from disk: run the same tiny cycle in two fresh
+    interpreters and require the second to compile (almost) nothing fresh.
+    The variable is SET here, never inherited: a driver that exports its
+    own warm cache directory must not turn the cold first run warm."""
     cache = str(tmp_path / "xla-cache")
     script = r"""
 import json, os, sys
@@ -452,7 +454,7 @@ from foremast_tpu.engine.pipeline import CompileCounter, enable_compile_cache
 from foremast_tpu.dataplane import FixtureDataSource
 from foremast_tpu.utils.timeutils import to_rfc3339
 
-assert enable_compile_cache(sys.argv[1])
+assert enable_compile_cache() == os.environ["JAX_COMPILATION_CACHE_DIR"]
 rng = np.random.default_rng(0)
 fixtures, store = {}, JobStore()
 for i in range(4):
@@ -469,11 +471,12 @@ with CompileCounter() as cc:
     eng.run_cycle(now=1000.0)
 print(json.dumps({"cache_misses": cc.cache_misses, "cache_hits": cc.cache_hits}))
 """
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=cache)
 
     def run_once():
         out = subprocess.run(
-            [sys.executable, "-c", script, cache], env=env,
+            [sys.executable, "-c", script], env=env,
             capture_output=True, text=True, timeout=420, check=True,
         )
         return json.loads(out.stdout.strip().splitlines()[-1])
@@ -488,10 +491,80 @@ print(json.dumps({"cache_misses": cc.cache_misses, "cache_hits": cc.cache_hits})
     assert second["cache_misses"] < first["cache_misses"], (first, second)
 
 
+def test_compile_cache_dir_precedence(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: JAX's own reading of it stands and no
+    code sets another directory (the two size/time gates are still
+    zeroed). Unset: a source checkout uses its one fixed `.jax_cache/` —
+    never a temp name, pid or timestamp."""
+    import jax
+
+    from foremast_tpu.engine import pipeline
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert pipeline.compile_cache_dir({}) == os.path.join(repo, ".jax_cache")
+    assert pipeline.compile_cache_dir(
+        {"JAX_COMPILATION_CACHE_DIR": "/srv/xla"}) == "/srv/xla"
+
+    updates = []
+    state = {"jax_compilation_cache_dir": "/srv/xla"}  # as JAX read it
+
+    class FakeConfig:
+        def update(self, name, value):
+            updates.append((name, value))
+            state[name] = value
+
+        @property
+        def jax_compilation_cache_dir(self):
+            return state["jax_compilation_cache_dir"]
+
+    monkeypatch.setattr(jax, "config", FakeConfig())
+    got = pipeline.enable_compile_cache(
+        {"JAX_COMPILATION_CACHE_DIR": "/srv/xla"})
+    assert got == "/srv/xla"
+    assert "jax_compilation_cache_dir" not in dict(updates)
+    assert dict(updates) == {
+        "jax_persistent_cache_min_compile_time_secs": 0.0,
+        "jax_persistent_cache_min_entry_size_bytes": -1}
+
+    updates.clear()
+    state["jax_compilation_cache_dir"] = None  # variable unset at import
+    got = pipeline.enable_compile_cache({})
+    assert got == os.path.join(repo, ".jax_cache")
+    assert dict(updates)["jax_compilation_cache_dir"] == got
+
+
+def test_compile_counter_stops_counting_and_leaves_no_listener():
+    """CompileCounter.__exit__ used a private unregister call that jax
+    0.9.0 no longer has, under a blanket except: two listeners leaked per
+    block and a counter kept counting after its block (every compile
+    count in prewarm and the zero-recompile gate was suspect)."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import monitoring
+
+    def listeners():
+        return (len(monitoring.get_event_duration_listeners()),
+                len(monitoring.get_event_listeners()))
+
+    before = listeners()
+    with CompileCounter() as cc:
+        assert listeners() == (before[0] + 1, before[1] + 1)
+        jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+    inside = cc.compiles
+    assert inside >= 1
+    assert listeners() == before
+    # a fresh program compiled AFTER the block must not reach the counter
+    jax.jit(lambda x: x * 5 - 2)(jnp.arange(11)).block_until_ready()
+    assert cc.compiles == inside
+
+
 # ------------------------------------------------------------ prewarm CLI
-def test_prewarm_cli_prints_grid_summary(capsys):
+def test_prewarm_cli_prints_grid_summary(capsys, monkeypatch, tmp_path):
     from foremast_tpu import cli
 
+    # keep the checkout's .jax_cache out of the pytest process: with the
+    # variable set no code sets a directory, and JAX read nothing at import
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     rc = cli.main(["prewarm", "--rungs", "16", "--buckets", "32",
                    "--families", "pair,hpa"])
     assert rc == 0
@@ -500,3 +573,6 @@ def test_prewarm_cli_prints_grid_summary(capsys):
     assert rec["rungs"] == [16]
     assert rec["programs"] == 2
     assert rec["seconds"] >= 0
+    # the record says where the programs were compiled
+    assert rec["platform"] == "cpu" and rec["device_count"] == 8
+    assert rec["device_kind"]

@@ -304,8 +304,18 @@ def test_runtime_end_to_end(tmp_path, port):
         assert status == "anomaly"
         m = urllib.request.urlopen(f"http://127.0.0.1:{port}/metrics").read().decode()
         assert "foremastbrain:error5xx_anomaly" in m
+        # /status says WHERE the scoring programs ran and what they cost to
+        # compile since start() — chip_smoke.py fails unless this reads tpu
+        build = json.loads(urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/status").read())["build"]
+        assert build["platform"] == "cpu" and build["device_kind"]
+        assert build["device_count"] == 8  # conftest's virtual mesh
+        assert set(build["compile"]) == {
+            "backend_compiles", "cache_hits", "cache_misses"}
     finally:
         rt.stop()
+    # stop() takes the runtime's compile listeners back out
+    assert rt.service.compile_counter is None
 
 
 def test_runtime_serves_grpc_when_enabled():

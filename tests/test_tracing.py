@@ -422,6 +422,27 @@ def test_initialize_single_host_is_noop():
     assert distributed.initialize(env={}) is False  # no coordinator config
 
 
+def test_initialize_single_host_tpu_vm_needs_no_handshake(monkeypatch):
+    """A single-host TPU VM sets TPU_WORKER_HOSTNAMES=localhost; there the
+    argument-less jax.distributed.initialize() asks a metadata server for
+    the cluster and raises where there is none (seen on the v5e check
+    machine). One listed worker is a world of one: no call. Two or more
+    still auto-initialize."""
+    from foremast_tpu.parallel import distributed
+
+    calls = []
+    monkeypatch.setattr(distributed.jax.distributed, "initialize",
+                        lambda **kw: calls.append(kw))
+    monkeypatch.setattr(distributed, "_initialized", False)
+    assert distributed.initialize(
+        env={"TPU_WORKER_HOSTNAMES": "localhost"}) is False
+    assert calls == []
+    assert distributed.initialize(
+        env={"TPU_WORKER_HOSTNAMES": "t1v-0,t1v-1"}) is True
+    assert calls == [{}]
+    monkeypatch.setattr(distributed, "_initialized", False)
+
+
 def test_initialize_partial_config_degrades_to_single_host(caplog):
     """A templated NUM_PROCESSES=1 or a lone COORDINATOR_ADDRESS must not
     crash the runtime at boot — warn (through logging, the lint suite's
