@@ -1,0 +1,37 @@
+"""The pair family's side of `correct`: a job with a `baseline` window is
+judged by the rank test of its current window against it, beside the
+baseline's own band (`lib/reference.py`).
+
+Number compared:
+  pair_p_gap  widest gap between the program's and the reference's
+              smallest p-value
+"""
+import numpy as np
+
+from lib import reference
+
+NUMBERS = (("pair_p_gap", "max", "pair_p_gap"),)
+
+
+def reference_rows(fleet, jobs: list, k_now: int, limits: dict,
+                   precision: str = "float64") -> dict:
+    base = np.stack([fleet.served(
+        j, 0, fleet.base_lo, fleet.base_lo + fleet.window_steps)
+        for j in jobs])
+    cur = np.stack([fleet.served(j, 0, fleet.hist_hi, k_now) for j in jobs])
+    return reference.pair_rows(base, cur, fleet.cls(jobs[0])["metric"],
+                               float(limits["band_gap_sigmas"]), precision)
+
+
+def answer(ref: dict, i: int) -> dict:
+    return {"unhealthy": bool(ref["min_p"][i] < reference.PAIR_ALPHA
+                              or ref["band_min"][i]),
+            "min_p": round(float(ref["min_p"][i]), 8)}
+
+
+def judge(entry: dict, ref: dict, i: int, limits: dict):
+    p, p_lim = ref["min_p"][i], float(limits["pair_p_gap"])
+    return ({"pair_p_gap": float(abs(entry["min_p"] - p))},
+            bool(p < reference.PAIR_ALPHA - p_lim or ref["band_min"][i]),
+            bool(p > reference.PAIR_ALPHA + p_lim
+                 and not ref["band_max"][i]))

@@ -1,0 +1,45 @@
+"""Bytes and operations the scoring families need, from their shapes.
+
+They count what the algorithm needs for the real rows and samples of a
+launch, not what today's programs do (padding to a rung and a bucket,
+predictions that cross the host between programs, row blocks), so a
+later kernel change cannot make them stale and cannot push a roofline
+share past 100% by doing less than this.
+"""
+from __future__ import annotations
+
+import math
+
+
+def band(rows: int, points: int) -> dict:
+    """One band launch over `rows` series of `points` samples (history
+    and judged window together).
+
+    bytes: read the values (float32), their validity and the judged-region
+    mark (one byte each), write the upper and lower bounds (float32) and
+    the anomaly flags (one byte) that the collect reads: 15 per sample,
+    and 12 per row for the count, the first index and the points checked.
+    operations, per sample: the moving sum and count (2 adds, 2
+    subtracts), the mean (1 divide), the residual and its square and sum
+    (3), the two bounds (2) and the two comparisons (2): 12."""
+    return {"bytes": rows * (15 * points + 12), "ops": 12 * rows * points}
+
+
+def pair(rows: int, base_points: int, cur_points: int) -> dict:
+    """One pair launch over `rows` baseline and current windows.
+
+    bytes: read both windows (float32) and their validity (one byte),
+    write five scalars per row.
+    operations, per row: the comparisons of one sort of the combined
+    sample, n log2 n, and 12 per sample for the baseline band."""
+    n = base_points + cur_points
+    return {"bytes": rows * (5 * n + 20),
+            "ops": rows * (n * math.log2(n) + 12 * n)}
+
+
+def least_seconds(cost: dict, peaks: dict) -> tuple[float, str]:
+    """The least time the chip could take, and which peak bounds it."""
+    by_bytes = cost["bytes"] / peaks["bytes_per_s"]
+    by_ops = cost["ops"] / peaks["flops_per_s"]
+    return (by_bytes, "bandwidth") if by_bytes >= by_ops \
+        else (by_ops, "compute")

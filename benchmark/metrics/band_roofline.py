@@ -1,0 +1,26 @@
+"""The band launch's share of its roofline: the least time the chip
+could take for the bytes and operations the band scorer needs
+(`lib/costs.py`, from each traced cycle's real rows and samples) over
+the summed device time of the band programs' runs in the trace."""
+from lib import costs
+
+PROGRAMS = ("jit__moving_average_1d", "jit_residual_sigma",
+            "jit_band_anomalies")
+
+
+def read(ctx):
+    tr, fl = ctx["trace"], ctx["fleet"]
+    if not tr or ctx["peaks"] is None:
+        return None
+    device_s = sum(tr["programs"].get(p, (0.0, 0))[0] for p in PROGRAMS)
+    if device_s <= 0:
+        return None
+    least = 0.0
+    for c in ctx["cycles"]:
+        points = fl.hist_steps + 1 + c["now_slot"] - fl.hist_hi + 1
+        secs, bound = costs.least_seconds(
+            costs.band(c["rows"].get("band", 0), points), ctx["peaks"])
+        least += secs
+    ctx["notes"]["band_roofline_bound"] = bound
+    ctx["notes"]["band_device_s"] = device_s
+    return 100.0 * least / device_s
