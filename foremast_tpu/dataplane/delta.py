@@ -191,6 +191,21 @@ def _split_finite(ts, vals):
     return ts, vals, np.unique(ts[bad])
 
 
+def _note_fetch_seconds(lock_wait: float, lock_held: float,
+                        source: float) -> None:
+    """Where one fetch's seconds went, on the caller's open per-job notes
+    (the engine's fetch pool; a no-op on any other thread): queued for the
+    splice lock and inside the inner source's call are THREAD-seconds,
+    summed over the pool's threads; the lock is serial, so the seconds it
+    was held sum to wall seconds. One flush per fetch, from timestamps
+    taken inline: a timed context manager at every lock site cost ten
+    times as much under the pool's contention for the interpreter lock."""
+    note = tracing.tracer.add_note
+    note("lock_wait_thread_seconds", lock_wait)
+    note("lock_held_seconds", lock_held)
+    note("source_thread_seconds", source)
+
+
 class DeltaWindowSource:
     """fetch_window with per-query delta fetch + splice.
 
@@ -701,44 +716,53 @@ class DeltaWindowSource:
         hold (``qend < pushed_until + step``) and the cache provably
         retains every sample at/after the requested start."""
         qstart, qend, url_step = rng
-        step = self.step
         if url_step != entry.url_step or qstart < entry.qstart:
             return None
+        t0 = time.perf_counter()
         with self._cpu_lock:
-            if entry.pushed_until <= 0:
-                return None
-            # coverage proof: every on-grid sample the backend could
-            # return at/below the EFFECTIVE end is already in the cache.
-            # A future query end clamps to the wall clock — the backend
-            # cannot hold samples from the future either.
-            eff_end = min(qend, float(self.clock()))
-            if eff_end >= entry.pushed_until + step:
-                return None
-            w = entry.win
-            if w.values.shape[0] >= MAX_WINDOW_STEPS:
-                # span-clipped cache: samples may have been dropped at
-                # the head, so full-refetch geometry is no longer
-                # provable from the cache alone
-                return None
-            valid_ts = (w.start
-                        + np.nonzero(w.mask)[0].astype(np.float64) * w.step)
-            all_ts = np.concatenate([valid_ts, entry.nan_ts])
-            sel = (all_ts >= qstart) & (all_ts <= qend)
-            if not np.any(sel):
-                return None
-            mn = float(np.min(all_ts[sel]))
-            mx = float(np.max(all_ts[sel]))
-            end = align_step(mx, step) + step
-            start = max(align_step(mn, step), end - MAX_WINDOW_STEPS * step)
-            off = int((start - w.start) // step)
-            n = int((end - start) // step)
-            if off < 0 or off + n > w.values.shape[0]:
-                return None
-            out = Window(w.values[off:off + n].copy(),
-                         w.mask[off:off + n].copy(), int(start), step)
-            with self._lock:
-                if self._cache.get(key) is entry:  # evicted mid-serve?
-                    self._cache.move_to_end(key)
+            t1 = time.perf_counter()
+            out = self._serve_pushed(key, entry, qstart, qend)
+            t2 = time.perf_counter()
+        _note_fetch_seconds(t1 - t0, t2 - t1, 0.0)
+        return out
+
+    def _serve_pushed(self, key, entry, qstart, qend):
+        """`_try_ingest_serve` under the cpu lock."""
+        step = self.step
+        if entry.pushed_until <= 0:
+            return None
+        # coverage proof: every on-grid sample the backend could
+        # return at/below the EFFECTIVE end is already in the cache.
+        # A future query end clamps to the wall clock — the backend
+        # cannot hold samples from the future either.
+        eff_end = min(qend, float(self.clock()))
+        if eff_end >= entry.pushed_until + step:
+            return None
+        w = entry.win
+        if w.values.shape[0] >= MAX_WINDOW_STEPS:
+            # span-clipped cache: samples may have been dropped at
+            # the head, so full-refetch geometry is no longer
+            # provable from the cache alone
+            return None
+        valid_ts = (w.start
+                    + np.nonzero(w.mask)[0].astype(np.float64) * w.step)
+        all_ts = np.concatenate([valid_ts, entry.nan_ts])
+        sel = (all_ts >= qstart) & (all_ts <= qend)
+        if not np.any(sel):
+            return None
+        mn = float(np.min(all_ts[sel]))
+        mx = float(np.max(all_ts[sel]))
+        end = align_step(mx, step) + step
+        start = max(align_step(mn, step), end - MAX_WINDOW_STEPS * step)
+        off = int((start - w.start) // step)
+        n = int((end - start) // step)
+        if off < 0 or off + n > w.values.shape[0]:
+            return None
+        out = Window(w.values[off:off + n].copy(),
+                     w.mask[off:off + n].copy(), int(start), step)
+        with self._lock:
+            if self._cache.get(key) is entry:  # evicted mid-serve?
+                self._cache.move_to_end(key)
         return out
 
     # ------------------------------------------------------------- fetch
@@ -808,9 +832,14 @@ class DeltaWindowSource:
     def _full(self, url: str, key, rng) -> Window:
         """Full refetch; (re)prime the cache entry when the response is
         exact-grid (spliceable next cycle)."""
+        t0 = time.perf_counter()
         ts, vals, nbytes = self._series(url)
+        t1 = time.perf_counter()
         with self._cpu_lock:
+            t2 = time.perf_counter()
             win = self._full_grid(ts, vals, nbytes, key, rng)
+            t3 = time.perf_counter()
+        _note_fetch_seconds(t2 - t1, t3 - t2, t1 - t0)
         self._flush_spills()
         return win
 
@@ -859,7 +888,11 @@ class DeltaWindowSource:
             # range extends backwards past what the cache ever covered
             self._count_fallback("range_extended")
             return None
+        # t0..t5: where this fetch's seconds go (_note_fetch_seconds); a
+        # fallback that returns from under the first lock notes nothing
+        t0 = time.perf_counter()
         with self._cpu_lock:
+            t1 = time.perf_counter()
             w = entry.win
             valid_ts = (w.start
                         + np.nonzero(w.mask)[0].astype(np.float64) * w.step)
@@ -873,16 +906,23 @@ class DeltaWindowSource:
             if delta_start > qend:
                 self._count_fallback("range_regressed")
                 return None
+            t2 = time.perf_counter()
 
         # a delta-query failure propagates like a full-fetch failure would:
         # same backend, same URL shape — the resilience layer already ran.
         # The fetch itself stays OUTSIDE the cpu lock: network I/O is the
         # part that genuinely overlaps across the engine's fetch pool.
         ts_d, vals_d, nbytes = self._series(_set_range(url, delta_start, qend))
+        t3 = time.perf_counter()
         with self._cpu_lock:
-            return self._splice(key, entry, w, valid_ts, sample_ts,
-                                delta_start, qstart, qend, ts_d, vals_d,
-                                nbytes)
+            t4 = time.perf_counter()
+            out = self._splice(key, entry, w, valid_ts, sample_ts,
+                               delta_start, qstart, qend, ts_d, vals_d,
+                               nbytes)
+            t5 = time.perf_counter()
+        _note_fetch_seconds((t1 - t0) + (t4 - t3), (t2 - t1) + (t5 - t4),
+                            t3 - t2)
+        return out
 
     def _splice(self, key, entry, w, valid_ts, sample_ts, delta_start,
                 qstart, qend, ts_d, vals_d, nbytes) -> Window | None:

@@ -321,6 +321,18 @@ class Analyzer:
         # the steady-state no-change gate asserts this stays flat over a
         # memo-hit cycle
         self.device_launches = 0
+        # -- what crosses to and from the chip, counted where it crosses
+        # (cumulative; per-cycle deltas land on the engine.score span and
+        # in last_cycle_stages["partition"]): nbytes of every host array
+        # handed to a jitted program (_call) and of every device value
+        # brought back (_host), over the four batch families, period
+        # detection and the triage screen (the lstm family's eager
+        # training path is not counted); and the real samples against
+        # the padded (rung, T) blocks of the packed value arrays
+        self.h2d_bytes_total = 0
+        self.d2h_bytes_total = 0
+        self.pack_real_elems_total = 0
+        self.pack_total_elems_total = 0
         # -- single-dispatch mega-batching (MEGABATCH) cumulative
         # counters: launches through the mega path, real rows carried and
         # padding rows added (the packing-efficiency signal satellite
@@ -439,7 +451,8 @@ class Analyzer:
             table.popitem(last=False)
 
     def _memo_key_fp(self, family: str, entry, T: int):
-        """(result_key, fingerprint) for one routed accumulator entry.
+        """(result_key, fingerprint, window bytes hashed) for one routed
+        accumulator entry.
 
         The fingerprint covers everything the family's launch+collect
         reads from the entry: every window's full identity, the policy,
@@ -448,25 +461,27 @@ class Analyzer:
         lifetime, and the memo dies with the analyzer."""
         if family == "pair":
             it = entry
-            return ((it.job_id, it.metric, "pair"),
-                    _fp(b"pair", T, it.metric, it.baseline, it.current,
-                        it.policy))
-        if family == "band":
+            key = (it.job_id, it.metric, "pair")
+            parts = (b"pair", T, it.metric, it.baseline, it.current,
+                     it.policy)
+        elif family == "band":
             it = entry
-            return ((it.job_id, it.metric, "band"),
-                    _fp(b"band", T, it.metric, it.historical, it.current,
-                        it.policy))
-        if family == "bivariate":
+            key = (it.job_id, it.metric, "band")
+            parts = (b"band", T, it.metric, it.historical, it.current,
+                     it.policy)
+        elif family == "bivariate":
             it = entry[0]  # (item, joint-grid prep)
-            return ((it.job_id, "&".join(it.metrics), "bivariate"),
-                    _fp(b"bi", T, it.metrics, *it.hist, *it.cur,
-                        *it.policies))
-        job_id, t, s = entry  # hpa row
-        return (job_id,
-                _fp(b"hpa", T, t.metric, t.historical, t.current,
-                    t.is_increase, t.priority, t.is_absolute, t.pod_window,
-                    s.metric, s.historical, s.current, s.is_increase,
-                    s.priority, s.is_absolute))
+            key = (it.job_id, "&".join(it.metrics), "bivariate")
+            parts = (b"bi", T, it.metrics, *it.hist, *it.cur, *it.policies)
+        else:
+            key, t, s = entry  # hpa row
+            parts = (b"hpa", T, t.metric, t.historical, t.current,
+                     t.is_increase, t.priority, t.is_absolute, t.pod_window,
+                     s.metric, s.historical, s.current, s.is_increase,
+                     s.priority, s.is_absolute)
+        return key, _fp(*parts), sum(
+            p.values.nbytes + p.mask.nbytes for p in parts
+            if isinstance(p, Window))
 
     def _dump_knobs(self) -> dict:
         """Knob values folded into flight-recorder dumps: the degraded-mode
@@ -920,7 +935,25 @@ class Analyzer:
         budget = max_rows * 1024  # row-steps at the base T
         return int(min(max_rows, max(budget // max(int(T), 1024), 1024)))
 
-    def _launch_chunks(self, fn, arrays: list, donate: int = 0) -> list:
+    def _call(self, fn, *args, **kw):
+        """Call a jitted program, counting the host arrays handed to it
+        (each one is a transfer to the device)."""
+        self.h2d_bytes_total += sum(
+            a.nbytes for a in (*args, *kw.values())
+            if isinstance(a, np.ndarray))
+        return fn(*args, **kw)
+
+    def _host(self, x) -> np.ndarray:
+        """`np.asarray` of a device value, counted: blocks until the
+        program has run and copies the result to the host."""
+        if isinstance(x, np.ndarray):
+            return x
+        a = np.asarray(x)
+        self.d2h_bytes_total += a.nbytes
+        return a
+
+    def _launch_chunks(self, fn, arrays: list, donate: int = 0,
+                       row_elems=None) -> list:
         """Row-chunk packed (B, ...) arrays into FIXED batch buckets and
         call fn per chunk WITHOUT materializing the outputs.
 
@@ -940,6 +973,14 @@ class Analyzer:
         async-dispatch device values; nothing blocks until
         `_collect_chunks` materializes them, so the caller can keep
         packing the next bucket while the device drains this one.
+
+        `donate` > 0 says fn is a jitted program that takes the chunk
+        itself, so the chunk's bytes cross to the device here; a host
+        closure (band, hpa, period detection) counts what it hands on.
+        `row_elems` (a family's launch half passes it) holds each row's
+        real samples over the packed value arrays, the chunk's float
+        (rows, T) blocks: the pack's fill is their sum against the
+        blocks' padded size.
         """
         B = arrays[0].shape[0]
         mega = self.config.megabatch
@@ -965,13 +1006,24 @@ class Analyzer:
                 sl = [np.pad(a, ((0, target - n),) + ((0, 0),) * (a.ndim - 1),
                              mode="edge") for a in sl]
             self.device_launches += 1
-            if mega:
-                self.megabatch_launches_total += 1
-                self.megabatch_real_rows_total += n
-                self.megabatch_pad_rows_total += target - n
-                launches.append((self._mega_call(fn, sl, donate), n))
-            else:
-                launches.append((fn(*sl), n))
+            if row_elems is not None:
+                self.pack_real_elems_total += int(row_elems[i:i + C].sum())
+                self.pack_total_elems_total += sum(
+                    a.size for a in sl if a.ndim == 2 and a.dtype.kind == "f")
+            h0 = self.h2d_bytes_total
+            with tracing.span(tracing.SPAN_ENGINE_LAUNCH, rows=n,
+                              padded_rows=target) as sp:
+                if mega:
+                    self.megabatch_launches_total += 1
+                    self.megabatch_real_rows_total += n
+                    self.megabatch_pad_rows_total += target - n
+                    out = self._mega_call(fn, sl, donate)
+                elif donate:
+                    out = self._call(fn, *sl)
+                else:
+                    out = fn(*sl)
+                sp.attrs["h2d_bytes"] = self.h2d_bytes_total - h0
+            launches.append((out, n))
         return launches
 
     def _mega_call(self, fn, sl: list, donate: int):
@@ -993,17 +1045,21 @@ class Analyzer:
                     self._donated_twins[id(fn)] = tw
                 args = [jax.device_put(a) if i < donate else a
                         for i, a in enumerate(sl)]
+                self.h2d_bytes_total += sum(a.nbytes for a in sl)
                 return tw(*args)
+            return self._call(fn, *sl)
         return fn(*sl)
 
-    @staticmethod
-    def _collect_chunks(launches: list) -> dict:
+    def _collect_chunks(self, launches: list) -> dict:
         """Materialize `_launch_chunks` output: block on the device values,
         trim padded rows, concatenate chunks back into one (B, ...) dict."""
-        outs = [
-            {k: np.asarray(v)[:n] for k, v in out.items()}
-            for out, n in launches
-        ]
+        d0 = self.d2h_bytes_total
+        with tracing.span(tracing.SPAN_ENGINE_MATERIALIZE) as sp:
+            outs = [
+                {k: self._host(v)[:n] for k, v in out.items()}
+                for out, n in launches
+            ]
+            sp.attrs["d2h_bytes"] = self.d2h_bytes_total - d0
         if len(outs) == 1:
             return outs[0]
         return {k: np.concatenate([o[k] for o in outs]) for k in outs[0]}
@@ -1012,7 +1068,8 @@ class Analyzer:
         """Synchronous launch+collect (the pre-pipeline contract)."""
         return self._collect_chunks(self._launch_chunks(fn, arrays))
 
-    def _launch_period_partitions(self, band_fn, args, xv, xm, regions) -> list:
+    def _launch_period_partitions(self, band_fn, args, xv, xm, regions,
+                                  row_elems) -> list:
         """Launch a band scorer, partitioned by detected seasonal period.
 
         The HW/seasonal-trend scan needs a STATIC period (the season buffer
@@ -1027,13 +1084,14 @@ class Analyzer:
         """
         chosen = self._detect_periods(xv, xm, regions)
         if chosen is None:
-            return [(None, self._launch_chunks(band_fn, args))]
+            return [(None, self._launch_chunks(band_fn, args,
+                                               row_elems=row_elems))]
         parts = []
         for p in np.unique(chosen):
             idx = np.nonzero(chosen == p)[0]
             parts.append((idx, self._launch_chunks(
                 lambda *a, _p=int(p): band_fn(*a, _period=_p),
-                [a[idx] for a in args],
+                [a[idx] for a in args], row_elems=row_elems[idx],
             )))
         return parts
 
@@ -1105,7 +1163,9 @@ class Analyzer:
                 ),
                 (B, 1),
             ),
-        ], donate=4)
+        ], donate=4, row_elems=np.asarray(
+            [it.baseline.values.shape[0] + it.current.values.shape[0]
+             for it in group]))
         return (group, launches)
 
     def _collect_pairs(self, state) -> dict:
@@ -1161,8 +1221,8 @@ class Analyzer:
         fallback = min(cfg.hw_period, max(T // 2, 2))
 
         def detect_fn(xv_c, xm_c, reg_c):
-            period, _ = fc.detect_period(
-                xv_c, xm_c & ~reg_c, cands,
+            period, _ = self._call(
+                fc.detect_period, xv_c, xm_c & ~reg_c, cands,
                 np.int32(fallback), np.float32(cfg.hw_min_seasonal_acf),
                 alias_margin=np.float32(cfg.hw_alias_margin),
                 contrast_margin=np.float32(cfg.hw_contrast_margin),
@@ -1196,27 +1256,31 @@ class Analyzer:
                 else xv.shape[1]) >= self.config.long_window_steps
         if algo.startswith("exponential_smoothing"):
             ses = sq.ses_predictions_assoc if long else fc.ses_predictions
-            preds = ses(xv, hist_mask, np.full(B, 0.3, np.float32))
+            preds = self._call(ses, xv, hist_mask,
+                               np.full(B, 0.3, np.float32))
         elif algo.startswith("double_exponential"):
-            preds = fc.des_predictions(
-                xv, hist_mask, np.full(B, 0.5, np.float32), np.full(B, 0.1, np.float32)
-            )
+            preds = self._call(
+                fc.des_predictions, xv, hist_mask,
+                np.full(B, 0.5, np.float32), np.full(B, 0.1, np.float32))
         elif algo.startswith("holt_winters"):
             period = (period_override if period_override is not None
                       else min(self.config.hw_period, max(xv.shape[1] // 2, 2)))
             fitm = hist_mask.copy()
             fitm[:, : 2 * period] = False
-            _, preds = fc.fit_holt_winters(xv, hist_mask, fitm, period)
+            _, preds = self._call(fc.fit_holt_winters, xv, hist_mask, fitm,
+                                  period)
         elif algo.startswith("seasonal_trend") or algo.startswith("prophet"):
             period = (period_override if period_override is not None
                       else min(self.config.hw_period, max(xv.shape[1] // 2, 2)))
-            _, preds = fc.fit_seasonal_trend(
+            _, preds = self._call(
+                fc.fit_seasonal_trend,
                 xv, hist_mask, hist_mask, period, self.config.st_order,
                 n_changepoints=self.config.st_changepoints,
             )
         else:  # moving_average_all default
-            preds = fc.moving_average_predictions(xv, hist_mask, self.config.ma_window)
-        return np.asarray(preds), hist_mask
+            preds = self._call(fc.moving_average_predictions, xv, hist_mask,
+                               self.config.ma_window)
+        return self._host(preds), hist_mask
 
     @staticmethod
     def _band_T(it: _BandItem) -> int:
@@ -1250,9 +1314,10 @@ class Analyzer:
             # points, where the assoc scan is the right kernel anyway.
             preds, hist_mask = self._predict(
                 xv_c, xm_c, reg_c, T, period_override=_period)
-            sigma = np.asarray(
-                fc.residual_sigma(xv_c, preds, hist_mask, ~reg_c))
-            return fc.band_anomalies(
+            sigma = self._host(self._call(
+                fc.residual_sigma, xv_c, preds, hist_mask, ~reg_c))
+            return self._call(
+                fc.band_anomalies,
                 xv_c, xm_c, reg_c, preds, sigma, thr_c, bnd_c, mlb_c)
 
         args = [
@@ -1261,7 +1326,9 @@ class Analyzer:
             np.asarray([it.policy.bound for it in group], np.int32),
             np.asarray([it.policy.min_lower_bound for it in group], np.float32),
         ]
-        parts = self._launch_period_partitions(band_fn, args, xv, xm, regions)
+        parts = self._launch_period_partitions(
+            band_fn, args, xv, xm, regions,
+            np.asarray([c.values.shape[0] for c in concats]))
         return (group, parts, xv, regions, n_hs)
 
     def _collect_bands(self, state) -> dict:
@@ -1344,7 +1411,8 @@ class Analyzer:
             bm2[i] = it.policies[1].bound
         launches = self._launch_chunks(bv.bivariate_normal_anomalies, [
             x1, m1, x2, m2, region, thr, mlb1, mlb2, bm1, bm2,
-        ], donate=5)
+        ], donate=5, row_elems=np.asarray(
+            [2 * x.shape[1] for _, (x, _m, _n_h, _n_c) in entries]))
         return (entries, launches, region)
 
     def _collect_bivariate(self, state) -> dict:
@@ -1928,11 +1996,13 @@ class Analyzer:
                    pn_c, ph_c):
             n = tv_c.shape[0]
             hist_mask = tm_c & ~reg_c
-            preds = np.asarray(
-                fc.ses_predictions(tv_c, hist_mask, np.full(n, 0.3, np.float32))
-            )
-            sigma = np.asarray(fc.residual_sigma(tv_c, preds, hist_mask, ~reg_c))
-            return hpa_ops.hpa_scores(
+            preds = self._host(self._call(
+                fc.ses_predictions, tv_c, hist_mask,
+                np.full(n, 0.3, np.float32)))
+            sigma = self._host(self._call(
+                fc.residual_sigma, tv_c, preds, hist_mask, ~reg_c))
+            return self._call(
+                hpa_ops.hpa_scores,
                 tv_c, tm_c, reg_c, preds, sigma, sv_c, sm_c,
                 lim_c, mode_c,
                 np.full(n, self.config.threshold, np.float32),
@@ -1944,6 +2014,9 @@ class Analyzer:
             hpa_fn,
             [tv, tm, reg, sv, sm, limits, modes, absolutes,
              pods_now, pods_hist],
+            row_elems=np.asarray(
+                [t.values.shape[0] + s.values.shape[0]
+                 for t, s in zip(tps_w, sla_w)]),
         )
         return (rows, launches, had_pods)
 
@@ -2155,12 +2228,16 @@ class Analyzer:
                 -self._shed_streak.get(doc.id, 0))
 
     def _stream_prep(self, claimed: list, now: float,
-                     deadline: Deadline | None = None):
+                     deadline: Deadline | None = None,
+                     pool: dict | None = None):
         """Yield (doc_id, items, failed, fetch_notes) per job, in claim
         order, as the fetch pool completes chunks. `fetch_notes` is the
         tracer's per-job fetch accounting (delta/full/cached counts,
         points, seconds) for the provenance record; shed jobs yield
-        `(doc.id, None, _SHED, {})`.
+        `(doc.id, None, _SHED, {})`. `pool` collects the POOL_SPANS
+        seconds of those notes, summed per chunk on the pool's own
+        threads (thread-seconds: no span is opened there, it would name
+        the device's long idle gap after a chunk of the pool).
 
         Per-job fetches overlap on a bounded pool: fetch is network-bound
         in production (and the native parser releases the GIL during its C
@@ -2201,6 +2278,7 @@ class Analyzer:
 
         def prep_many(chunk):
             out = []
+            sums = dict.fromkeys(tracing.POOL_SPANS, 0.0)
             with tracing.tracer.attach(ctx):
                 for doc in chunk:
                     if (deadline is not None and doc.id != guaranteed
@@ -2210,32 +2288,85 @@ class Analyzer:
                         continue
                     with tracing.tracer.bind(job_id=doc.id):
                         tracing.tracer.begin_notes()
+                        t0 = time.perf_counter()
                         try:
-                            items = self._preprocess(doc, now)
-                            out.append((doc.id, items, "",
-                                        tracing.tracer.take_notes()))
+                            items, failed = self._preprocess(doc, now), ""
                         except FetchError as e:
-                            out.append((doc.id, None, str(e),
-                                        tracing.tracer.take_notes()))
+                            items, failed = None, str(e)
+                        notes = tracing.tracer.take_notes()
+                        notes["prep_thread_seconds"] = (
+                            time.perf_counter() - t0)
+                        for k in sums:
+                            sums[k] += notes.get(k, 0.0)
+                        out.append((doc.id, items, failed, notes))
+            return out, sums
+
+        def merged(result):
+            out, sums = result
+            if pool is not None:
+                for k, v in sums.items():
+                    pool[k] = pool.get(k, 0.0) + v
             return out
 
         workers = min(max(self.config.fetch_concurrency, 1), len(claimed) or 1)
         if workers <= 1:
-            yield from prep_many(claimed)
+            yield from merged(prep_many(claimed))
             return
         step = max(1, -(-len(claimed) // (workers * 8)))
         chunks = [claimed[i:i + step]
                   for i in range(0, len(claimed), step)]
         with ThreadPoolExecutor(max_workers=workers) as ex:
-            for rs in ex.map(prep_many, chunks):
-                yield from rs
+            for result in ex.map(prep_many, chunks):
+                yield from merged(result)
+
+    @staticmethod
+    def _book_pieces(prep_sp, pipe, wait: float, busy: float,
+                     busy_cpu: float, pool: dict):
+        """Name what interleaved per job inside engine.preprocess: the
+        seconds (wait, route, route_cpu, memo_fp, triage) and the memo's
+        counts, written on the span's attrs with the pool's thread-seconds
+        and folded into the per-name stats. `busy` is the cycle thread
+        between two results of the stream; what `feed` booked under a
+        name of its own comes off, and the rest is route. `busy_cpu` is
+        the thread's CPU over the same stream; streamed fires and screens
+        come off, the fingerprint's CPU stays in (no clock reads it), so
+        `route + memo_fp - route_cpu` is the wait for the interpreter
+        lock. (With one fetch worker the preprocess itself runs on this
+        thread and is in `busy_cpu`.)"""
+        part = {"wait": wait, "memo_fp": 0.0, "triage": 0.0,
+                "route": busy, "route_cpu": busy_cpu}
+        memo_counts = {"memo_lookups": 0, "memo_hits": 0, "memo_fp_bytes": 0}
+        if pipe is not None:
+            # streamed screens and fires so far (finish() adds its own)
+            screens = pipe.triage.seconds if pipe.triage else 0.0
+            named = pipe.stage_seconds["dispatch"] + pipe.memo_seconds + screens
+            part.update(memo_fp=pipe.memo_seconds, triage=screens,
+                        route=busy - named,
+                        route_cpu=busy_cpu - pipe.fired_cpu_seconds)
+            memo_counts = {"memo_lookups": pipe.memo_lookups,
+                           "memo_hits": sum(pipe.memo_hits.values()),
+                           "memo_fp_bytes": pipe.memo_fp_bytes}
+        prep_sp.attrs.update(
+            {k + "_s": round(v, 6) for k, v in part.items()},
+            **memo_counts,
+            **{"pool_" + k: round(v, 6) for k, v in pool.items()})
+        tracing.tracer.add_timing(tracing.SPAN_ENGINE_ROUTE, part["route"])
+        tracing.tracer.add_timing(tracing.SPAN_ENGINE_ROUTE_CPU,
+                                  part["route_cpu"])
+        tracing.tracer.add_timing(tracing.SPAN_ENGINE_MEMO_FP,
+                                  part["memo_fp"],
+                                  count=memo_counts["memo_lookups"])
+        for key in tracing.POOL_SPANS:
+            tracing.tracer.add_timing(tracing.POOL_SPANS[key],
+                                      pool.get(key, 0.0))
+        return part, memo_counts
 
     def _run_cycle(self, worker: str, now: float,
                    cycle_dl: Deadline | None = None, job_ids=None,
                    partial: bool = False) -> dict:
         from .pipeline import CyclePipeline
 
-        with tracing.span("engine.claim"):
+        with tracing.span(tracing.SPAN_ENGINE_CLAIM) as claim_sp:
             claimed = self.store.claim_open_jobs(
                 worker,
                 limit=self.config.max_claim_per_cycle,
@@ -2283,19 +2414,30 @@ class Analyzer:
         mega_r0 = self.megabatch_real_rows_total
         mega_p0 = self.megabatch_pad_rows_total
         rescore_skips0 = self.lstm_rescore_skips
+        h2d0, d2h0 = self.h2d_bytes_total, self.d2h_bytes_total
+        real0, total0 = self.pack_real_elems_total, self.pack_total_elems_total
         shed_cycle0 = self.jobs_shed_total
         stale_cycle0 = self.stale_verdicts_served_total
         wd_cycle0 = self.watchdog_fires_total
         pipe = CyclePipeline(self) if self.config.score_pipeline else None
         stages = {"preprocess": 0.0, "dispatch": 0.0, "collect": 0.0,
                   "fold": 0.0}
-        with tracing.span(tracing.SPAN_ENGINE_PREPROCESS, jobs=len(claimed)):
+        # the cycle thread between two results of the fetch stream, in
+        # wall seconds, its CPU seconds over the whole stream (waiting on
+        # the pool burns none; one clock pair a cycle, because
+        # `time.thread_time` is a system call), and the pool's notes
+        pool: dict = {}
+        busy = 0.0
+        with tracing.span(tracing.SPAN_ENGINE_PREPROCESS,
+                          jobs=len(claimed)) as prep_sp:
             for doc in claimed:
                 states[doc.id] = _JobState(doc)
+            c_stream = time.thread_time()
             t_wait = time.perf_counter()
             for doc_id, items, failed, fetch_notes in self._stream_prep(
-                    claimed, now, cycle_dl):
-                stages["preprocess"] += time.perf_counter() - t_wait
+                    claimed, now, cycle_dl, pool):
+                t_got = time.perf_counter()
+                stages["preprocess"] += t_got - t_wait
                 if fetch_notes:
                     states[doc_id].fetch = fetch_notes
                 if failed:
@@ -2320,72 +2462,77 @@ class Analyzer:
                         pipe.feed(pairs, bands, bis, multis, hpas,
                                   strategy=states[doc_id].doc.strategy)
                 t_wait = time.perf_counter()
-        shed_ids: list = []
-        for doc_id, st in states.items():
-            if not st.failed:
-                self._shed_streak.pop(doc_id, None)
-                self.store.advance(doc_id, J.PREPROCESS_COMPLETED,
-                                   J.POSTPROCESS_INPROGRESS, worker=worker)
-                continue
-            doc = st.doc
-            if st.failed == _SHED:
-                # load shedding (CYCLE_DEADLINE_S): the budget burned down
-                # before this job's fetch started. Carry it to the next
-                # cycle — the shed streak promotes it within its class, so
-                # it completes with a verdict byte-identical to the one it
-                # would have produced unshed (tests/test_degraded.py).
-                self.jobs_shed_total += 1
-                self._shed_streak[doc_id] = self._shed_streak.get(doc_id, 0) + 1
-                shed_ids.append(doc_id)
-                self.provenance.record(
-                    doc_id, prov.PATH_SHED_CARRYOVER, status=J.INITIAL,
-                    detail=f"streak {self._shed_streak[doc_id]}")
-                self.exporter.record_counter(
-                    "foremastbrain:jobs_shed_total", {},
-                    help="jobs shed by the cycle deadline budget and "
-                         "carried to the next cycle")
-                self.store.transition(
-                    doc_id, J.INITIAL, worker=worker,
-                    reason="shed: cycle deadline budget exhausted; "
-                           "carried over")
-                outcomes[doc_id] = J.INITIAL
-                continue
-            # real fetch failure (retries exhausted / breaker open /
-            # garbage body): a warm job re-serves its last fresh verdict
-            # instead of flapping (stale-verdict serving, MAX_STALE_S)
-            served = self._serve_stale(doc, st.failed, worker, now)
-            if served is not None:
-                outcomes[doc_id] = served
-            elif doc.strategy in CONTINUOUS_STRATEGIES:
-                # perpetual jobs survive transient fetch errors: requeue
-                # instead of dying terminally on one network blip
-                self.provenance.record(
-                    doc_id, prov.PATH_FETCH_RETRY, status=J.INITIAL,
-                    reason=st.failed, fetch=st.fetch)
-                self.store.transition(
-                    doc_id, J.INITIAL, reason=f"fetch retry: {st.failed}",
-                    worker=worker,
-                )
-                outcomes[doc_id] = J.INITIAL
-            else:
-                self.provenance.record(
-                    doc_id, prov.PATH_NO_DATA, status=J.PREPROCESS_FAILED,
-                    reason=st.failed, fetch=st.fetch)
-                self.store.transition(
-                    doc_id, J.PREPROCESS_FAILED, reason=st.failed,
-                    worker=worker,
-                    processing_content=self._prov_content(doc_id))
-                outcomes[doc_id] = J.PREPROCESS_FAILED
-        if shed_ids:
-            self.flight.record_event(flightrec.EVENT_SHED,
-                                     count=len(shed_ids),
-                                     jobs=shed_ids[:16])
+                busy += t_wait - t_got
+            part, memo_counts = self._book_pieces(
+                prep_sp, pipe, stages["preprocess"], busy,
+                time.thread_time() - c_stream, pool)
+        with tracing.span(tracing.SPAN_ENGINE_ADVANCE) as advance_sp:
+            shed_ids: list = []
+            for doc_id, st in states.items():
+                if not st.failed:
+                    self._shed_streak.pop(doc_id, None)
+                    self.store.advance(doc_id, J.PREPROCESS_COMPLETED,
+                                       J.POSTPROCESS_INPROGRESS, worker=worker)
+                    continue
+                doc = st.doc
+                if st.failed == _SHED:
+                    # load shedding (CYCLE_DEADLINE_S): the budget burned down
+                    # before this job's fetch started. Carry it to the next
+                    # cycle — the shed streak promotes it within its class, so
+                    # it completes with a verdict byte-identical to the one it
+                    # would have produced unshed (tests/test_degraded.py).
+                    self.jobs_shed_total += 1
+                    self._shed_streak[doc_id] = self._shed_streak.get(doc_id, 0) + 1
+                    shed_ids.append(doc_id)
+                    self.provenance.record(
+                        doc_id, prov.PATH_SHED_CARRYOVER, status=J.INITIAL,
+                        detail=f"streak {self._shed_streak[doc_id]}")
+                    self.exporter.record_counter(
+                        "foremastbrain:jobs_shed_total", {},
+                        help="jobs shed by the cycle deadline budget and "
+                             "carried to the next cycle")
+                    self.store.transition(
+                        doc_id, J.INITIAL, worker=worker,
+                        reason="shed: cycle deadline budget exhausted; "
+                               "carried over")
+                    outcomes[doc_id] = J.INITIAL
+                    continue
+                # real fetch failure (retries exhausted / breaker open /
+                # garbage body): a warm job re-serves its last fresh verdict
+                # instead of flapping (stale-verdict serving, MAX_STALE_S)
+                served = self._serve_stale(doc, st.failed, worker, now)
+                if served is not None:
+                    outcomes[doc_id] = served
+                elif doc.strategy in CONTINUOUS_STRATEGIES:
+                    # perpetual jobs survive transient fetch errors: requeue
+                    # instead of dying terminally on one network blip
+                    self.provenance.record(
+                        doc_id, prov.PATH_FETCH_RETRY, status=J.INITIAL,
+                        reason=st.failed, fetch=st.fetch)
+                    self.store.transition(
+                        doc_id, J.INITIAL, reason=f"fetch retry: {st.failed}",
+                        worker=worker,
+                    )
+                    outcomes[doc_id] = J.INITIAL
+                else:
+                    self.provenance.record(
+                        doc_id, prov.PATH_NO_DATA, status=J.PREPROCESS_FAILED,
+                        reason=st.failed, fetch=st.fetch)
+                    self.store.transition(
+                        doc_id, J.PREPROCESS_FAILED, reason=st.failed,
+                        worker=worker,
+                        processing_content=self._prov_content(doc_id))
+                    outcomes[doc_id] = J.PREPROCESS_FAILED
+            if shed_ids:
+                self.flight.record_event(flightrec.EVENT_SHED,
+                                         count=len(shed_ids),
+                                         jobs=shed_ids[:16])
 
         live = {k: v for k, v in states.items() if not v.failed}
         fam_seconds: dict[str, float] = {}
         with tracing.span(tracing.SPAN_ENGINE_SCORE, pairs=len(all_pairs),
                           bands=len(all_bands), bis=len(all_bis),
-                          multis=len(all_multis), hpas=len(all_hpas)):
+                          multis=len(all_multis), hpas=len(all_hpas)) as score_sp:
             if pipe is not None:
                 (pair_res, band_res, bi_res, multi_res, hpa_res,
                  scoring_failed) = pipe.finish()
@@ -2422,420 +2569,448 @@ class Analyzer:
                                   **multi_bad, **hpa_bad}
                 stages["collect"] += sum(fam_seconds.values())
             self.lstm_budget_skips += len(self._lstm_budget_skipped_ids)
+            # every launch of the cycle is collected by now, the streamed
+            # ones too: what crossed to and from the chip, and the pack
+            counters = {
+                "h2d_bytes": self.h2d_bytes_total - h2d0,
+                "d2h_bytes": self.d2h_bytes_total - d2h0,
+                "pack_real_elems": self.pack_real_elems_total - real0,
+                "pack_total_elems": self.pack_total_elems_total - total0}
+            score_sp.attrs.update(counters)
 
-        t_fold = time.perf_counter()
-        # waterfall boundary: everything before this instant is the
-        # `score` stage, everything after is `fold` (_observe_latency)
-        self._cycle_fold_mono = time.monotonic()
-        # -- provenance collection (zero work when recording is off) --
-        # per-family score-vs-threshold entries and judged-result counts
-        # per job; counts vs the pipeline's memo-hit map classify each
-        # verdict as fresh-scored or memo-served.
-        prov_on = self.provenance.enabled
-        fam_entries: dict[str, list] = {}
-        judged_items: dict[str, int] = {}
-        memo_job_hits = pipe.memo_job_hits if pipe is not None else {}
-        triage_gate = pipe.triage if pipe is not None else None
-        triage_job_hits = triage_gate.job_hits if triage_gate is not None \
-            else {}
-        # per-result screen statistics for cleared rows, keyed by the
-        # family result key — folded into the provenance family entries so
-        # `explain` shows the screen's numbers vs its thresholds
-        triage_stats = triage_gate.stats if triage_gate is not None else {}
+        with tracing.span(tracing.SPAN_ENGINE_FOLD):
+            t_fold = time.perf_counter()
+            # waterfall boundary: everything before this instant is the
+            # `score` stage, everything after is `fold` (_observe_latency)
+            self._cycle_fold_mono = time.monotonic()
+            # -- provenance collection (zero work when recording is off) --
+            # per-family score-vs-threshold entries and judged-result counts
+            # per job; counts vs the pipeline's memo-hit map classify each
+            # verdict as fresh-scored or memo-served.
+            prov_on = self.provenance.enabled
+            fam_entries: dict[str, list] = {}
+            judged_items: dict[str, int] = {}
+            memo_job_hits = pipe.memo_job_hits if pipe is not None else {}
+            triage_gate = pipe.triage if pipe is not None else None
+            triage_job_hits = triage_gate.job_hits if triage_gate is not None \
+                else {}
+            # per-result screen statistics for cleared rows, keyed by the
+            # family result key — folded into the provenance family entries so
+            # `explain` shows the screen's numbers vs its thresholds
+            triage_stats = triage_gate.stats if triage_gate is not None else {}
 
-        # a partial (event-driven) cycle's fresh scores carry their own
-        # path tag: `explain` answers "did this verdict wait for the
-        # tick, or did the push wake it?" without cycle-id archaeology
-        scored_path = prov.PATH_STREAM_SCORED if partial \
-            else prov.PATH_SCORED
+            # a partial (event-driven) cycle's fresh scores carry their own
+            # path tag: `explain` answers "did this verdict wait for the
+            # tick, or did the push wake it?" without cycle-id archaeology
+            scored_path = prov.PATH_STREAM_SCORED if partial \
+                else prov.PATH_SCORED
 
-        def _vpath(job_id: str) -> tuple:
-            """(path, detail) for a judged job: memo-hit when EVERY result
-            came from the fingerprint memo, triaged when the tier-0
-            screen cleared the rest, scored otherwise."""
-            n = judged_items.get(job_id, 0)
-            m = memo_job_hits.get(job_id, 0) + (
-                1 if job_id in self._lstm_memo_jobs else 0)
-            t = triage_job_hits.get(job_id, 0)
-            if n and m >= n:
-                return prov.PATH_MEMO_HIT, f"{m}/{n} results from memo"
-            if n and t and m + t >= n:
-                detail = f"{t}/{n} screened clear"
+            def _vpath(job_id: str) -> tuple:
+                """(path, detail) for a judged job: memo-hit when EVERY result
+                came from the fingerprint memo, triaged when the tier-0
+                screen cleared the rest, scored otherwise."""
+                n = judged_items.get(job_id, 0)
+                m = memo_job_hits.get(job_id, 0) + (
+                    1 if job_id in self._lstm_memo_jobs else 0)
+                t = triage_job_hits.get(job_id, 0)
+                if n and m >= n:
+                    return prov.PATH_MEMO_HIT, f"{m}/{n} results from memo"
+                if n and t and m + t >= n:
+                    detail = f"{t}/{n} screened clear"
+                    if m:
+                        detail += f", {m} memo"
+                    return prov.PATH_TRIAGED, detail
+                if t:
+                    return (scored_path,
+                            f"{n - m - t}/{n} fresh, {m} memo, {t} triaged")
                 if m:
-                    detail += f", {m} memo"
-                return prov.PATH_TRIAGED, detail
-            if t:
-                return (scored_path,
-                        f"{n - m - t}/{n} fresh, {m} memo, {t} triaged")
-            if m:
-                return scored_path, f"{n - m}/{n} fresh, {m} memo"
-            return scored_path, ""
+                    return scored_path, f"{n - m}/{n} fresh, {m} memo"
+                return scored_path, ""
 
-        # fold per-metric results into per-job verdicts
-        for it in all_pairs:
-            r = pair_res.get((it.job_id, it.metric, "pair"))
-            if r is None:
-                continue
-            st = live[it.job_id]
-            st.judged_any = True
-            if prov_on:
-                judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
-                entry = {
-                    "family": "pair", "metric": it.metric,
-                    "min_p": round(r["min_p"], 8),
-                    "alpha": self.config.pairwise_threshold,
-                    "unhealthy": bool(r["unhealthy"])}
-                entry.update(triage_stats.get(
-                    (it.job_id, it.metric, "pair"), {}))
-                fam_entries.setdefault(it.job_id, []).append(entry)
-            if r["unhealthy"]:
-                causes = []
-                if r["pairwise_unhealthy"]:
-                    causes.append(f"pairwise rejection p={r['min_p']:.2e}")
-                if r["band_unhealthy"]:
-                    causes.append(
-                        f"{r['band_count']} points outside the baseline band"
-                    )
-                st.unhealthy.append((it.metric, "; ".join(causes), []))
-        for it in all_bands:
-            r = band_res.get((it.job_id, it.metric, "band"))
-            if r is None:
-                continue
-            st = live[it.job_id]
-            st.judged_any = True
-            if prov_on:
-                judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
-                entry = {
-                    "family": "band", "metric": it.metric,
-                    "anomalous_points": int(r["count"]),
-                    "band": [round(r["lower"], 4), round(r["upper"], 4)],
-                    "unhealthy": bool(r["unhealthy"])}
-                entry.update(triage_stats.get(
-                    (it.job_id, it.metric, "band"), {}))
-                fam_entries.setdefault(it.job_id, []).append(entry)
-            self.exporter.record_bounds(
-                st.doc.app_name, st.doc.namespace, it.metric,
-                r["upper"], r["lower"], float(r["unhealthy"]),
-            )
-            if r["unhealthy"]:
-                st.unhealthy.append(
-                    (
-                        it.metric,
-                        f"{r['count']} points outside "
-                        f"[{r['lower']:.4g},{r['upper']:.4g}] from ts {r['first_ts']:.0f}",
-                        r["anomaly_pairs"],
-                    )
-                )
-        for it in all_bis:
-            r = bi_res.get((it.job_id, "&".join(it.metrics), "bivariate"))
-            if r is None:
-                continue
-            st = live[it.job_id]
-            st.judged_any = True
-            if prov_on:
-                judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
-                entry = {
-                    "family": "bivariate", "metric": "&".join(it.metrics),
-                    "anomalous_points": int(r["count"]),
-                    "unhealthy": bool(r["unhealthy"])}
-                entry.update(triage_stats.get(
-                    (it.job_id, "&".join(it.metrics), "bivariate"), {}))
-                fam_entries.setdefault(it.job_id, []).append(entry)
-            for metric, (upper, lower) in r["bounds"].items():
+            # fold per-metric results into per-job verdicts
+            for it in all_pairs:
+                r = pair_res.get((it.job_id, it.metric, "pair"))
+                if r is None:
+                    continue
+                st = live[it.job_id]
+                st.judged_any = True
+                if prov_on:
+                    judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
+                    entry = {
+                        "family": "pair", "metric": it.metric,
+                        "min_p": round(r["min_p"], 8),
+                        "alpha": self.config.pairwise_threshold,
+                        "unhealthy": bool(r["unhealthy"])}
+                    entry.update(triage_stats.get(
+                        (it.job_id, it.metric, "pair"), {}))
+                    fam_entries.setdefault(it.job_id, []).append(entry)
+                if r["unhealthy"]:
+                    causes = []
+                    if r["pairwise_unhealthy"]:
+                        causes.append(f"pairwise rejection p={r['min_p']:.2e}")
+                    if r["band_unhealthy"]:
+                        causes.append(
+                            f"{r['band_count']} points outside the baseline band"
+                        )
+                    st.unhealthy.append((it.metric, "; ".join(causes), []))
+            for it in all_bands:
+                r = band_res.get((it.job_id, it.metric, "band"))
+                if r is None:
+                    continue
+                st = live[it.job_id]
+                st.judged_any = True
+                if prov_on:
+                    judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
+                    entry = {
+                        "family": "band", "metric": it.metric,
+                        "anomalous_points": int(r["count"]),
+                        "band": [round(r["lower"], 4), round(r["upper"], 4)],
+                        "unhealthy": bool(r["unhealthy"])}
+                    entry.update(triage_stats.get(
+                        (it.job_id, it.metric, "band"), {}))
+                    fam_entries.setdefault(it.job_id, []).append(entry)
                 self.exporter.record_bounds(
-                    st.doc.app_name, st.doc.namespace, metric,
-                    upper, lower, float(r["unhealthy"]),
+                    st.doc.app_name, st.doc.namespace, it.metric,
+                    r["upper"], r["lower"], float(r["unhealthy"]),
                 )
-            if r["unhealthy"]:
-                st.unhealthy.append(
-                    (
-                        "&".join(it.metrics),
-                        f"{r['count']} points outside the joint "
-                        f"bivariate-normal ellipse from ts {r['first_ts']:.0f}",
-                        r["anomaly_pairs"],
+                if r["unhealthy"]:
+                    st.unhealthy.append(
+                        (
+                            it.metric,
+                            f"{r['count']} points outside "
+                            f"[{r['lower']:.4g},{r['upper']:.4g}] from ts {r['first_ts']:.0f}",
+                            r["anomaly_pairs"],
+                        )
                     )
-                )
-        for it in all_multis:
-            r = multi_res.get((it.job_id, "+".join(it.metrics), "lstm"))
-            if r is None:
-                continue
-            st = live[it.job_id]
-            st.judged_any = True
+            for it in all_bis:
+                r = bi_res.get((it.job_id, "&".join(it.metrics), "bivariate"))
+                if r is None:
+                    continue
+                st = live[it.job_id]
+                st.judged_any = True
+                if prov_on:
+                    judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
+                    entry = {
+                        "family": "bivariate", "metric": "&".join(it.metrics),
+                        "anomalous_points": int(r["count"]),
+                        "unhealthy": bool(r["unhealthy"])}
+                    entry.update(triage_stats.get(
+                        (it.job_id, "&".join(it.metrics), "bivariate"), {}))
+                    fam_entries.setdefault(it.job_id, []).append(entry)
+                for metric, (upper, lower) in r["bounds"].items():
+                    self.exporter.record_bounds(
+                        st.doc.app_name, st.doc.namespace, metric,
+                        upper, lower, float(r["unhealthy"]),
+                    )
+                if r["unhealthy"]:
+                    st.unhealthy.append(
+                        (
+                            "&".join(it.metrics),
+                            f"{r['count']} points outside the joint "
+                            f"bivariate-normal ellipse from ts {r['first_ts']:.0f}",
+                            r["anomaly_pairs"],
+                        )
+                    )
+            for it in all_multis:
+                r = multi_res.get((it.job_id, "+".join(it.metrics), "lstm"))
+                if r is None:
+                    continue
+                st = live[it.job_id]
+                st.judged_any = True
+                if prov_on:
+                    judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
+                    fam_entries.setdefault(it.job_id, []).append({
+                        "family": "lstm", "metric": "+".join(it.metrics),
+                        "z": round(float(r["z"]), 4),
+                        "threshold": self.config.lstm_threshold,
+                        "unhealthy": bool(r["unhealthy"])})
+                if r["unhealthy"]:
+                    st.unhealthy.append(
+                        (
+                            "+".join(it.metrics),
+                            f"LSTM-AE reconstruction z={r['z']:.2f} exceeds "
+                            f"{self.config.lstm_threshold:.1f}",
+                            [],
+                        )
+                    )
             if prov_on:
-                judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
-                fam_entries.setdefault(it.job_id, []).append({
-                    "family": "lstm", "metric": "+".join(it.metrics),
-                    "z": round(float(r["z"]), 4),
-                    "threshold": self.config.lstm_threshold,
-                    "unhealthy": bool(r["unhealthy"])})
-            if r["unhealthy"]:
-                st.unhealthy.append(
-                    (
-                        "+".join(it.metrics),
-                        f"LSTM-AE reconstruction z={r['z']:.2f} exceeds "
-                        f"{self.config.lstm_threshold:.1f}",
-                        [],
-                    )
-                )
-        if prov_on:
-            # hpa results fold inside _finish_hpa; count them here so the
-            # memo-vs-fresh classification sees them like every family
-            for job_id in hpa_res:
-                if job_id in live:
-                    judged_items[job_id] = judged_items.get(job_id, 0) + 1
+                # hpa results fold inside _finish_hpa; count them here so the
+                # memo-vs-fresh classification sees them like every family
+                for job_id in hpa_res:
+                    if job_id in live:
+                        judged_items[job_id] = judged_items.get(job_id, 0) + 1
 
-        for job_id, st in live.items():
-            doc = st.doc
-            if job_id in scoring_failed:
-                reason = f"scoring failed: {scoring_failed[job_id]}"
-                if scoring_failed[job_id].startswith("WatchdogTimeout"):
-                    # watchdog fires are INFRASTRUCTURE evidence (a hung
-                    # or wedged device), not job poison: every strategy
-                    # requeues for the next cycle — quarantining the job
-                    # (or aborting a canary) would misattribute the
-                    # device's fault to the workload and blank coverage
-                    # long after the device recovers
-                    self.provenance.record(
-                        job_id, prov.PATH_WATCHDOG_FAILOVER,
-                        status=J.INITIAL, reason=reason, fetch=st.fetch)
-                    self.store.transition(
-                        job_id, J.INITIAL, reason=reason, worker=worker)
-                    outcomes[job_id] = J.INITIAL
+            for job_id, st in live.items():
+                doc = st.doc
+                if job_id in scoring_failed:
+                    reason = f"scoring failed: {scoring_failed[job_id]}"
+                    if scoring_failed[job_id].startswith("WatchdogTimeout"):
+                        # watchdog fires are INFRASTRUCTURE evidence (a hung
+                        # or wedged device), not job poison: every strategy
+                        # requeues for the next cycle — quarantining the job
+                        # (or aborting a canary) would misattribute the
+                        # device's fault to the workload and blank coverage
+                        # long after the device recovers
+                        self.provenance.record(
+                            job_id, prov.PATH_WATCHDOG_FAILOVER,
+                            status=J.INITIAL, reason=reason, fetch=st.fetch)
+                        self.store.transition(
+                            job_id, J.INITIAL, reason=reason, worker=worker)
+                        outcomes[job_id] = J.INITIAL
+                        continue
+                    if doc.strategy in CONTINUOUS_STRATEGIES:
+                        # perpetual jobs retry next cycle (data may heal) —
+                        # but a job that keeps poisoning its per-job retry is
+                        # parked (quarantine) instead of re-burning the
+                        # _isolate fallback every cycle forever
+                        self._record_scoring_failure(job_id, now)
+                        self.provenance.record(
+                            job_id, prov.PATH_BLAST_RADIUS, status=J.INITIAL,
+                            reason=reason, fetch=st.fetch)
+                        self.store.transition(job_id, J.INITIAL, reason=reason, worker=worker)
+                        outcomes[job_id] = J.INITIAL
+                    else:
+                        self._quarantine.pop(job_id, None)  # terminal: moot
+                        self.provenance.record(
+                            job_id, prov.PATH_BLAST_RADIUS, status=J.ABORT,
+                            reason=reason, fetch=st.fetch)
+                        self.store.transition(
+                            job_id, J.ABORT, reason=reason, worker=worker,
+                            processing_content=self._prov_content(job_id))
+                        outcomes[job_id] = J.ABORT
                     continue
-                if doc.strategy in CONTINUOUS_STRATEGIES:
-                    # perpetual jobs retry next cycle (data may heal) —
-                    # but a job that keeps poisoning its per-job retry is
-                    # parked (quarantine) instead of re-burning the
-                    # _isolate fallback every cycle forever
-                    self._record_scoring_failure(job_id, now)
-                    self.provenance.record(
-                        job_id, prov.PATH_BLAST_RADIUS, status=J.INITIAL,
-                        reason=reason, fetch=st.fetch)
-                    self.store.transition(job_id, J.INITIAL, reason=reason, worker=worker)
-                    outcomes[job_id] = J.INITIAL
-                else:
-                    self._quarantine.pop(job_id, None)  # terminal: moot
-                    self.provenance.record(
-                        job_id, prov.PATH_BLAST_RADIUS, status=J.ABORT,
-                        reason=reason, fetch=st.fetch)
+                # scored cleanly: full quarantine reset (consecutive = 0)
+                self._quarantine.pop(job_id, None)
+                if doc.strategy == STRATEGY_HPA:
+                    res = hpa_res.get(job_id)
+                    outcomes[job_id] = self._finish_hpa(
+                        st, res, worker, now,
+                        path_info=_vpath(job_id) if prov_on else None)
+                    if res is not None:
+                        # a scored hpa cycle IS the detection; annotates the
+                        # record _finish_hpa just wrote
+                        self._observe_latency(st, now)
+                    continue
+                try:
+                    end_time = from_rfc3339(doc.end_time)
+                except (ValueError, TypeError):
+                    # continuous jobs carry END_TIME placeholders: never expire
+                    end_time = float("inf") if doc.strategy in CONTINUOUS_STRATEGIES else now
+                if st.unhealthy:
+                    metrics = ", ".join(dict.fromkeys(m for m, _, _ in st.unhealthy))
+                    reason = "; ".join(f"{m}: {d}" for m, d, _ in st.unhealthy)
+                    anomaly = {m: pairs for m, _, pairs in st.unhealthy if pairs}
+                    self._stale_state.pop(job_id, None)
+                    reason = f"anomaly detected on {metrics} :: {reason}"
+                    if prov_on:
+                        path, detail = _vpath(job_id)
+                        self.provenance.record(  # lint: disable=trace-registry -- path from _vpath (registered constants only)
+                            job_id, path, status=J.COMPLETED_UNHEALTH,
+                            detail=detail, reason=reason,
+                            families=fam_entries.get(job_id),
+                            fetch=st.fetch)
+                    # observed between record and transition: the latency
+                    # annotation must land before the summary is attached
+                    self._observe_latency(st, now)
                     self.store.transition(
-                        job_id, J.ABORT, reason=reason, worker=worker,
+                        job_id, J.COMPLETED_UNHEALTH,
+                        reason=reason,
+                        anomaly=anomaly, worker=worker,
+                        processing_content=self._prov_content(job_id),
+                    )
+                    outcomes[job_id] = J.COMPLETED_UNHEALTH
+                elif now < end_time:
+                    # healthy so far; keep watching until endTime (fail-fast
+                    # rule); continuous jobs loop here forever. A judged cycle
+                    # refreshes the job's warm stale-serving state.
+                    if st.judged_any:
+                        self._stale_state[job_id] = now
+                    if prov_on and st.judged_any:
+                        path, detail = _vpath(job_id)
+                        self.provenance.record(  # lint: disable=trace-registry -- path from _vpath (registered constants only)
+                            job_id, path, status=J.INITIAL, detail=detail,
+                            families=fam_entries.get(job_id), fetch=st.fetch)
+                    if st.judged_any:
+                        # "healthy so far" is a verdict too: the monitor fleet's
+                        # steady-state latency is exactly this path
+                        self._observe_latency(st, now)
+                    self.store.requeue(job_id, worker=worker)
+                    outcomes[job_id] = J.INITIAL
+                elif st.judged_any:
+                    self._stale_state.pop(job_id, None)
+                    if prov_on:
+                        path, detail = _vpath(job_id)
+                        self.provenance.record(  # lint: disable=trace-registry -- path from _vpath (registered constants only)
+                            job_id, path, status=J.COMPLETED_HEALTH,
+                            detail=detail, families=fam_entries.get(job_id),
+                            fetch=st.fetch)
+                    self._observe_latency(st, now)
+                    self.store.transition(
+                        job_id, J.COMPLETED_HEALTH, worker=worker,
                         processing_content=self._prov_content(job_id))
-                    outcomes[job_id] = J.ABORT
-                continue
-            # scored cleanly: full quarantine reset (consecutive = 0)
-            self._quarantine.pop(job_id, None)
-            if doc.strategy == STRATEGY_HPA:
-                res = hpa_res.get(job_id)
-                outcomes[job_id] = self._finish_hpa(
-                    st, res, worker, now,
-                    path_info=_vpath(job_id) if prov_on else None)
-                if res is not None:
-                    # a scored hpa cycle IS the detection; annotates the
-                    # record _finish_hpa just wrote
-                    self._observe_latency(st, now)
-                continue
-            try:
-                end_time = from_rfc3339(doc.end_time)
-            except (ValueError, TypeError):
-                # continuous jobs carry END_TIME placeholders: never expire
-                end_time = float("inf") if doc.strategy in CONTINUOUS_STRATEGIES else now
-            if st.unhealthy:
-                metrics = ", ".join(dict.fromkeys(m for m, _, _ in st.unhealthy))
-                reason = "; ".join(f"{m}: {d}" for m, d, _ in st.unhealthy)
-                anomaly = {m: pairs for m, _, pairs in st.unhealthy if pairs}
-                self._stale_state.pop(job_id, None)
-                reason = f"anomaly detected on {metrics} :: {reason}"
-                if prov_on:
-                    path, detail = _vpath(job_id)
-                    self.provenance.record(  # lint: disable=trace-registry -- path from _vpath (registered constants only)
-                        job_id, path, status=J.COMPLETED_UNHEALTH,
-                        detail=detail, reason=reason,
-                        families=fam_entries.get(job_id),
+                    outcomes[job_id] = J.COMPLETED_HEALTH
+                else:
+                    # no judgeable data at endTime: a warm job re-serves its
+                    # last fresh verdict (zero UNKNOWN flips during a bounded
+                    # source blackout); cold jobs keep the reference semantics
+                    served = self._serve_stale(
+                        doc, "insufficient data points to judge", worker, now,
+                        in_postprocess=True)
+                    if served is not None:
+                        outcomes[job_id] = served
+                        continue
+                    self.provenance.record(
+                        job_id, prov.PATH_NO_DATA, status=J.COMPLETED_UNKNOWN,
+                        reason="insufficient data points to judge",
                         fetch=st.fetch)
-                # observed between record and transition: the latency
-                # annotation must land before the summary is attached
-                self._observe_latency(st, now)
-                self.store.transition(
-                    job_id, J.COMPLETED_UNHEALTH,
-                    reason=reason,
-                    anomaly=anomaly, worker=worker,
-                    processing_content=self._prov_content(job_id),
-                )
-                outcomes[job_id] = J.COMPLETED_UNHEALTH
-            elif now < end_time:
-                # healthy so far; keep watching until endTime (fail-fast
-                # rule); continuous jobs loop here forever. A judged cycle
-                # refreshes the job's warm stale-serving state.
-                if st.judged_any:
-                    self._stale_state[job_id] = now
-                if prov_on and st.judged_any:
-                    path, detail = _vpath(job_id)
-                    self.provenance.record(  # lint: disable=trace-registry -- path from _vpath (registered constants only)
-                        job_id, path, status=J.INITIAL, detail=detail,
-                        families=fam_entries.get(job_id), fetch=st.fetch)
-                if st.judged_any:
-                    # "healthy so far" is a verdict too: the monitor fleet's
-                    # steady-state latency is exactly this path
-                    self._observe_latency(st, now)
-                self.store.requeue(job_id, worker=worker)
-                outcomes[job_id] = J.INITIAL
-            elif st.judged_any:
-                self._stale_state.pop(job_id, None)
-                if prov_on:
-                    path, detail = _vpath(job_id)
-                    self.provenance.record(  # lint: disable=trace-registry -- path from _vpath (registered constants only)
-                        job_id, path, status=J.COMPLETED_HEALTH,
-                        detail=detail, families=fam_entries.get(job_id),
-                        fetch=st.fetch)
-                self._observe_latency(st, now)
-                self.store.transition(
-                    job_id, J.COMPLETED_HEALTH, worker=worker,
-                    processing_content=self._prov_content(job_id))
-                outcomes[job_id] = J.COMPLETED_HEALTH
-            else:
-                # no judgeable data at endTime: a warm job re-serves its
-                # last fresh verdict (zero UNKNOWN flips during a bounded
-                # source blackout); cold jobs keep the reference semantics
-                served = self._serve_stale(
-                    doc, "insufficient data points to judge", worker, now,
-                    in_postprocess=True)
-                if served is not None:
-                    outcomes[job_id] = served
-                    continue
-                self.provenance.record(
-                    job_id, prov.PATH_NO_DATA, status=J.COMPLETED_UNKNOWN,
-                    reason="insufficient data points to judge",
-                    fetch=st.fetch)
-                self.store.transition(
-                    job_id, J.COMPLETED_UNKNOWN,
-                    reason="insufficient data points to judge", worker=worker,
-                    processing_content=self._prov_content(job_id),
-                )
-                outcomes[job_id] = J.COMPLETED_UNKNOWN
-        stages["fold"] = time.perf_counter() - t_fold
-        # per-stage observability: tracer stats (foremast_trace_* on
-        # /metrics, bench decomposition) + foremastbrain gauges + /status
-        for name, secs in stages.items():
-            tracing.tracer.add_timing(tracing.STAGE_SPANS[name], secs)
-        self.exporter.record_cycle_stages(stages, fam_seconds)
-        triage_cycle = None
-        if triage_gate is not None and triage_gate.active:
-            tg = triage_gate
-            tracing.tracer.add_timing(tracing.SPAN_ENGINE_TRIAGE, tg.seconds)
-            screened = sum(tg.screened.values())
-            cleared = sum(tg.cleared.values())
-            escalated = sum(tg.escalated.values())
-            for fam in sorted(set(tg.screened) | set(tg.cleared)
-                              | set(tg.escalated)):
-                self.triage_screened_total[fam] = (
-                    self.triage_screened_total.get(fam, 0)
-                    + tg.screened.get(fam, 0))
-                self.triage_cleared_total[fam] = (
-                    self.triage_cleared_total.get(fam, 0)
-                    + tg.cleared.get(fam, 0))
-                self.triage_escalated_total[fam] = (
-                    self.triage_escalated_total.get(fam, 0)
-                    + tg.escalated.get(fam, 0))
-                self.exporter.record_triage(
-                    fam, tg.screened.get(fam, 0), tg.cleared.get(fam, 0),
-                    tg.escalated.get(fam, 0))
-            self.triage_launches_total += tg.launches
-            # recorded even when this cycle screened 0 rows (everything
-            # memo-hit): the "(last cycle)" gauge must not go stale at the
-            # previous cycle's ratio while triage_seconds keeps updating
-            self.exporter.record_gauge(
-                "foremastbrain:triage_escalation_ratio", {},
-                round(escalated / screened, 6) if screened else 0.0,
-                help="Fraction of screened rows escalated to the "
-                     "full scorers (last cycle).")
-            self.exporter.record_gauge(
-                "foremastbrain:triage_seconds", {},
-                round(tg.seconds, 6),
-                help="Tier-0 triage screen stage seconds (last cycle).")
-            triage_cycle = {
-                "screened": screened,
-                "cleared": cleared,
-                "escalated": escalated,
-                "escalation_ratio": (round(escalated / screened, 6)
-                                     if screened else 0.0),
-                "launches": tg.launches,
-                "seconds": round(tg.seconds, 6),
+                    self.store.transition(
+                        job_id, J.COMPLETED_UNKNOWN,
+                        reason="insufficient data points to judge", worker=worker,
+                        processing_content=self._prov_content(job_id),
+                    )
+                    outcomes[job_id] = J.COMPLETED_UNKNOWN
+            stages["fold"] = time.perf_counter() - t_fold
+        with tracing.span(tracing.SPAN_ENGINE_PUBLISH) as publish_sp:
+            # per-stage observability: tracer stats (foremast_trace_* on
+            # /metrics, bench decomposition) + foremastbrain gauges + /status
+            for name, secs in stages.items():
+                tracing.tracer.add_timing(tracing.STAGE_SPANS[name], secs)
+            self.exporter.record_cycle_stages(stages, fam_seconds)
+            triage_cycle = None
+            if triage_gate is not None and triage_gate.active:
+                tg = triage_gate
+                tracing.tracer.add_timing(tracing.SPAN_ENGINE_TRIAGE, tg.seconds)
+                screened = sum(tg.screened.values())
+                cleared = sum(tg.cleared.values())
+                escalated = sum(tg.escalated.values())
+                for fam in sorted(set(tg.screened) | set(tg.cleared)
+                                  | set(tg.escalated)):
+                    self.triage_screened_total[fam] = (
+                        self.triage_screened_total.get(fam, 0)
+                        + tg.screened.get(fam, 0))
+                    self.triage_cleared_total[fam] = (
+                        self.triage_cleared_total.get(fam, 0)
+                        + tg.cleared.get(fam, 0))
+                    self.triage_escalated_total[fam] = (
+                        self.triage_escalated_total.get(fam, 0)
+                        + tg.escalated.get(fam, 0))
+                    self.exporter.record_triage(
+                        fam, tg.screened.get(fam, 0), tg.cleared.get(fam, 0),
+                        tg.escalated.get(fam, 0))
+                self.triage_launches_total += tg.launches
+                # recorded even when this cycle screened 0 rows (everything
+                # memo-hit): the "(last cycle)" gauge must not go stale at the
+                # previous cycle's ratio while triage_seconds keeps updating
+                self.exporter.record_gauge(
+                    "foremastbrain:triage_escalation_ratio", {},
+                    round(escalated / screened, 6) if screened else 0.0,
+                    help="Fraction of screened rows escalated to the "
+                         "full scorers (last cycle).")
+                self.exporter.record_gauge(
+                    "foremastbrain:triage_seconds", {},
+                    round(tg.seconds, 6),
+                    help="Tier-0 triage screen stage seconds (last cycle).")
+                triage_cycle = {
+                    "screened": screened,
+                    "cleared": cleared,
+                    "escalated": escalated,
+                    "escalation_ratio": (round(escalated / screened, 6)
+                                         if screened else 0.0),
+                    "launches": tg.launches,
+                    "seconds": round(tg.seconds, 6),
+                }
+            mega_cycle = None
+            if self.config.megabatch:
+                real = self.megabatch_real_rows_total - mega_r0
+                padded = self.megabatch_pad_rows_total - mega_p0
+                mega_launches = self.megabatch_launches_total - mega_l0
+                waste = round(padded / real, 6) if real else 0.0
+                mega_cycle = {
+                    "launches": mega_launches,
+                    "real_rows": real,
+                    "padded_rows": padded,
+                    # the packing-efficiency signal: padding rows added per
+                    # real row this cycle (0 = every launch landed exactly
+                    # on its padding class)
+                    "padding_waste_ratio": waste,
+                }
+                self.exporter.record_gauge(
+                    "foremastbrain:megabatch_padding_waste_ratio", {}, waste,
+                    help="Mega-batch padding rows per real row (last cycle).")
+                if mega_launches:
+                    self.exporter.record_counter(
+                        "foremastbrain:megabatch_launches_total", {},
+                        inc=mega_launches,
+                        help="device launches through the single-dispatch "
+                             "mega-batch path (MEGABATCH)")
+                    self.exporter.record_counter(
+                        "foremastbrain:megabatch_real_rows_total", {},
+                        inc=real,
+                        help="real rows carried by mega-batch launches")
+                    self.exporter.record_counter(
+                        "foremastbrain:megabatch_padded_rows_total", {},
+                        inc=padded,
+                        help="padding rows added to reach mega padding "
+                             "classes (waste = padded/real)")
+            self.provenance.finish_cycle(
+                stage_seconds=stages,
+                device_launches=self.device_launches - launches0,
+                jobs=len(claimed))
+            self.last_cycle_stages = stats = {
+                "cycle_id": self.current_cycle_id,
+                "jobs": len(claimed),
+                "partial": partial,
+                "pipelined": pipe is not None,
+                "stage_seconds": {k: round(v, 6) for k, v in stages.items()},
+                "family_score_seconds": {
+                    k: round(v, 6) for k, v in fam_seconds.items()},
+                # steady-state memo observability: launches actually fired
+                # this cycle and verdicts served straight from fingerprints
+                "device_launches": self.device_launches - launches0,
+                # per-family launch counts (pipelined cycles): the dispatch-
+                # collapse observability the mega-batch A/B reads — but
+                # recorded for the rung path too, so the two are comparable
+                "family_launches": dict(pipe.family_launches)
+                if pipe is not None else {},
+                "score_memo_hits": dict(pipe.memo_hits) if pipe is not None
+                else {},
+                # tier-0 triage: this cycle's screened/cleared/escalated rows,
+                # escalation ratio, fused screen launches, and stage seconds
+                # (None when the gate is off or inactive)
+                "triage": triage_cycle,
+                # single-dispatch mega-batching: launches / real vs padded
+                # rows / per-family launch counts (None when MEGABATCH=0)
+                "megabatch": mega_cycle,
+                "lstm_rescore_skips": self.lstm_rescore_skips - rescore_skips0,
+                # degraded-mode signals (cumulative totals live on /metrics;
+                # these are this cycle's contribution + the live park count)
+                "jobs_shed": self.jobs_shed_total - shed_cycle0,
+                "stale_verdicts_served":
+                self.stale_verdicts_served_total - stale_cycle0,
+                "watchdog_fires": self.watchdog_fires_total - wd_cycle0,
+                "quarantined_jobs": self.quarantined_count(now),
             }
-        mega_cycle = None
-        if self.config.megabatch:
-            real = self.megabatch_real_rows_total - mega_r0
-            padded = self.megabatch_pad_rows_total - mega_p0
-            mega_launches = self.megabatch_launches_total - mega_l0
-            waste = round(padded / real, 6) if real else 0.0
-            mega_cycle = {
-                "launches": mega_launches,
-                "real_rows": real,
-                "padded_rows": padded,
-                # the packing-efficiency signal: padding rows added per
-                # real row this cycle (0 = every launch landed exactly
-                # on its padding class)
-                "padding_waste_ratio": waste,
-            }
-            self.exporter.record_gauge(
-                "foremastbrain:megabatch_padding_waste_ratio", {}, waste,
-                help="Mega-batch padding rows per real row (last cycle).")
-            if mega_launches:
-                self.exporter.record_counter(
-                    "foremastbrain:megabatch_launches_total", {},
-                    inc=mega_launches,
-                    help="device launches through the single-dispatch "
-                         "mega-batch path (MEGABATCH)")
-                self.exporter.record_counter(
-                    "foremastbrain:megabatch_real_rows_total", {},
-                    inc=real,
-                    help="real rows carried by mega-batch launches")
-                self.exporter.record_counter(
-                    "foremastbrain:megabatch_padded_rows_total", {},
-                    inc=padded,
-                    help="padding rows added to reach mega padding "
-                         "classes (waste = padded/real)")
-        self.provenance.finish_cycle(
-            stage_seconds=stages,
-            device_launches=self.device_launches - launches0,
-            jobs=len(claimed))
-        self.last_cycle_stages = {
-            "cycle_id": self.current_cycle_id,
-            "jobs": len(claimed),
-            "partial": partial,
-            "pipelined": pipe is not None,
-            "stage_seconds": {k: round(v, 6) for k, v in stages.items()},
-            "family_score_seconds": {
-                k: round(v, 6) for k, v in fam_seconds.items()},
-            # steady-state memo observability: launches actually fired
-            # this cycle and verdicts served straight from fingerprints
-            "device_launches": self.device_launches - launches0,
-            # per-family launch counts (pipelined cycles): the dispatch-
-            # collapse observability the mega-batch A/B reads — but
-            # recorded for the rung path too, so the two are comparable
-            "family_launches": dict(pipe.family_launches)
-            if pipe is not None else {},
-            "score_memo_hits": dict(pipe.memo_hits) if pipe is not None
-            else {},
-            # tier-0 triage: this cycle's screened/cleared/escalated rows,
-            # escalation ratio, fused screen launches, and stage seconds
-            # (None when the gate is off or inactive)
-            "triage": triage_cycle,
-            # single-dispatch mega-batching: launches / real vs padded
-            # rows / per-family launch counts (None when MEGABATCH=0)
-            "megabatch": mega_cycle,
-            "lstm_rescore_skips": self.lstm_rescore_skips - rescore_skips0,
-            # degraded-mode signals (cumulative totals live on /metrics;
-            # these are this cycle's contribution + the live park count)
-            "jobs_shed": self.jobs_shed_total - shed_cycle0,
-            "stale_verdicts_served":
-            self.stale_verdicts_served_total - stale_cycle0,
-            "watchdog_fires": self.watchdog_fires_total - wd_cycle0,
-            "quarantined_jobs": self.quarantined_count(now),
-        }
-        self._prune_degraded_state(outcomes, orphan_sweep=not partial)
-        self.store.put_state("breath", self.breath.export())
-        self.store.flush()
+            self._prune_degraded_state(outcomes, orphan_sweep=not partial)
+            self.store.put_state("breath", self.breath.export())
+            self.store.flush()
+        # the whole cycle by name, in cycle order. `wait`, `dispatch`,
+        # `collect` and `fold` are the four stage counters; `uncovered` is
+        # what is left of the cycle's wall time, so the seconds sum to it
+        seconds = {
+            "claim": claim_sp.duration, "wait": part["wait"],
+            "route": part["route"], "memo_fp": part["memo_fp"],
+            "triage": triage_gate.seconds if triage_gate is not None else 0.0,
+            "advance": advance_sp.duration, "dispatch": stages["dispatch"],
+            "collect": stages["collect"], "fold": stages["fold"],
+            "publish": publish_sp.duration}
+        seconds["uncovered"] = (time.monotonic() - self._cycle_mono0
+                                - sum(seconds.values()))
+        self.last_cycle_stages = {**stats, "partition": {
+            "seconds": {k: round(v, 6) for k, v in seconds.items()},
+            "route_cpu_seconds": round(part["route_cpu"], 6),
+            # summed over the fetch pool's threads, not wall seconds
+            "pool": {k: round(v, 6) for k, v in pool.items()},
+            "counters": {**counters, **memo_counts}}}
         return outcomes
 
     def _prune_degraded_state(self, outcomes: dict,

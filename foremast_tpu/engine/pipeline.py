@@ -125,12 +125,31 @@ class CyclePipeline:
         # verdict to the memo-hit path instead of a fresh device score
         self.memo_job_hits: dict = {}
         self._fps: dict = {}       # (family, result_key) -> fingerprint
+        # the cycle's partition (Analyzer._run_cycle): wall seconds inside
+        # _memo_check with its lookups and the bytes it fingerprinted, and
+        # the thread-CPU seconds of streamed fires and screens, which the
+        # route piece's CPU leaves out. The memo check itself reads no CPU
+        # clock: `time.thread_time` is a system call (6 us on the chip's
+        # host), and two a check cost a measurable share of the cycle.
+        self.memo_seconds = 0.0
+        self.memo_lookups = 0
+        self.memo_fp_bytes = 0
+        self.fired_cpu_seconds = 0.0
 
     def _memo_check(self, family: str, entry, T: int) -> bool:
         """True when this entry's verdict was served from the memo."""
         if self.memo is None:
             return False
-        key, fp = self.an._memo_key_fp(family, entry, T)
+        t0 = time.perf_counter()
+        try:
+            return self._memo_lookup(family, entry, T)
+        finally:
+            self.memo_seconds += time.perf_counter() - t0
+
+    def _memo_lookup(self, family: str, entry, T: int) -> bool:
+        key, fp, nbytes = self.an._memo_key_fp(family, entry, T)
+        self.memo_lookups += 1
+        self.memo_fp_bytes += nbytes
         hit = self.memo.get((family, key))
         if hit is not None and hit[0] == fp:
             self.memo.move_to_end((family, key))
@@ -222,21 +241,25 @@ class CyclePipeline:
             self._fire(family, T, bucket)
 
     def _fire(self, family: str, T: int, entries: list):
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.thread_time()
         d0 = self.an.device_launches
-        try:
-            if family == "pair":
-                st = self.an._launch_pairs(entries, T)
-            elif family == "band":
-                st = self.an._launch_bands(entries, T)
-            elif family == "bivariate":
-                st = self.an._launch_bivariate(entries, T)
-            else:
-                st = self.an._launch_hpa(entries, T)
-            self.pending.append((family, entries, st))
-        except Exception:  # noqa: BLE001 - blast radius: retry per job later
-            self.failed.append((family, entries))
+        # its self time is the pack; engine.launch under it is the call
+        with tracing.span(tracing.SPAN_ENGINE_DISPATCH, family=family, T=T,
+                          rows=len(entries)):
+            try:
+                if family == "pair":
+                    st = self.an._launch_pairs(entries, T)
+                elif family == "band":
+                    st = self.an._launch_bands(entries, T)
+                elif family == "bivariate":
+                    st = self.an._launch_bivariate(entries, T)
+                else:
+                    st = self.an._launch_hpa(entries, T)
+                self.pending.append((family, entries, st))
+            except Exception:  # noqa: BLE001 - blast radius: retry per job
+                self.failed.append((family, entries))
         dt = time.perf_counter() - t0
+        self.fired_cpu_seconds += time.thread_time() - c0
         self.stage_seconds["dispatch"] += dt
         self.family_seconds[family] = self.family_seconds.get(family, 0.0) + dt
         self.launches += 1
@@ -304,14 +327,19 @@ class CyclePipeline:
         # business; claim-order folding happens downstream off keyed dicts
         for family, entries, st in self.pending:
             t1 = time.perf_counter()
-            try:
-                if wedged():
-                    raise WatchdogTimeout(
-                        "device wedged (2+ watchdog timeouts this cycle); "
-                        "bucket skipped")
-                results[family].update(an._watchdog_call(collect[family], st))
-            except Exception:  # noqa: BLE001 - deferred device error
-                self.failed.append((family, entries))
+            # its self time is the per-row Python of the family's collect;
+            # engine.materialize under it is the wait and the copy back
+            with tracing.span(tracing.SPAN_ENGINE_COLLECT, family=family,
+                              rows=len(entries)):
+                try:
+                    if wedged():
+                        raise WatchdogTimeout(
+                            "device wedged (2+ watchdog timeouts this "
+                            "cycle); bucket skipped")
+                    results[family].update(
+                        an._watchdog_call(collect[family], st))
+                except Exception:  # noqa: BLE001 - deferred device error
+                    self.failed.append((family, entries))
             dt = time.perf_counter() - t1
             self.family_seconds[family] = (
                 self.family_seconds.get(family, 0.0) + dt)
@@ -547,7 +575,10 @@ def prewarm(config=None,
         log.info("prewarm %s rung=%d T=%d: %.1fs", family, rung, T,
                  time.perf_counter() - t1)
 
-    with CompileCounter() as cc:
+    # one root for the grid's engine.launch / engine.materialize spans:
+    # outside a cycle each would finish as a root trace of its own
+    with CompileCounter() as cc, \
+            tracing.span(tracing.SPAN_ENGINE_SCORE, prewarm=True):
         for T in t_buckets:
             n_c = max(T // 4, 8)
             n_h = T - n_c

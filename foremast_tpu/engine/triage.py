@@ -176,7 +176,7 @@ class TriageGate:
 
     # --------------------------------------------------------------- firing
     def _fire(self, T: int, units: list, pipe) -> None:
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.thread_time()
         rows = [(u, r) for u in units for r in u["rows"]]
         try:
             outs = self._screen(T, rows)
@@ -202,6 +202,7 @@ class TriageGate:
         # dispatch time belongs to the pipeline's dispatch stage — booking
         # it here would double-count it into foremastbrain:triage_seconds
         self.seconds += time.perf_counter() - t0
+        pipe.fired_cpu_seconds += time.thread_time() - c0
         for u in suspects:
             self._escalate(u, pipe)
 
@@ -232,14 +233,14 @@ class TriageGate:
             mg = np.full(R, self.margin, np.float32)
             self.an.device_launches += 1
             self.launches += 1
-            st = triage_ops.screen_rows(xv, xm, reg, thr, bnd, mlb, mg,
-                                        window)
+            st = self.an._call(triage_ops.screen_rows, xv, xm, reg, thr,
+                               bnd, mlb, mg, window)
             # materialize straight to Python lists, real rows only: the
             # per-row classification below touches every field of every
             # row, and 10k+ boxed numpy scalar reads per cycle cost more
             # host time than the screen saves in launches
             out = self.an._watchdog_call(
-                lambda s=st, m=n: {k: np.asarray(v)[:m].tolist()
+                lambda s=st, m=n: {k: self.an._host(v)[:m].tolist()
                                    for k, v in s.items()})
             out_rows += [{k: out[k][j] for k in out} for j in range(n)]
         return out_rows

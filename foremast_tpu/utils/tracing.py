@@ -4,9 +4,9 @@ The reference implements no tracing at all (SURVEY.md §5: Jaeger is
 name-dropped in its README, nothing consumes traces). This module gives
 the runtime an always-on, zero-dependency tracer:
 
-  * `span(SPAN_FETCH, url=...)` context manager records spans with
-    attributes; spans nest (thread-local stack) into one trace tree per
-    top-level span. Durations are measured on `time.monotonic()` (wall
+  * `span(SPAN_ENGINE_CLAIM, jobs=...)` context manager records spans
+    with attributes; spans nest (thread-local stack) into one trace tree
+    per top-level span. Durations are measured on `time.monotonic()` (wall
     steps cannot produce negative or inflated spans); each span keeps an
     epoch `start` timestamp for display only.
   * **trace context**: `bind(cycle_id=..., job_id=...)` stamps
@@ -48,9 +48,9 @@ the runtime an always-on, zero-dependency tracer:
     attached.
 
 Span names are REGISTERED constants (`SPAN_NAMES` below, plus the
-`SCORE_SPANS`/`STAGE_SPANS` derived maps): the devtools trace-registry
-lint rule rejects inline f-string names, so the name set stays a stable,
-greppable inventory.
+`SCORE_SPANS`/`STAGE_SPANS`/`POOL_SPANS` derived maps): the devtools
+trace-registry lint rule rejects inline f-string names, so the name set
+stays a stable, greppable inventory.
 """
 from __future__ import annotations
 
@@ -70,6 +70,7 @@ except Exception:  # pragma: no cover - jax always present in this build
 __all__ = [
     "Tracer", "TraceContext", "TraceContextFilter", "tracer", "span",
     "install_log_filter", "SPAN_NAMES", "SCORE_SPANS", "STAGE_SPANS",
+    "POOL_SPANS",
     "W3CContext", "parse_traceparent", "mint_trace_id", "mint_span_id",
     "TRACEPARENT_HEADER",
 ]
@@ -87,7 +88,22 @@ SPAN_ENGINE_SCORE = "engine.score"
 SPAN_ENGINE_LSTM_TRAIN = "engine.lstm_train"
 SPAN_ENGINE_TRIAGE = "engine.triage"
 SPAN_ENGINE_VERDICT = "engine.verdict"
-SPAN_DATAPLANE_FETCH = "dataplane.fetch"
+# the cycle's partition (engine.cycle's children, in cycle order): claim,
+# preprocess, advance, score (dispatch > launch, collect > materialize),
+# fold, publish
+SPAN_ENGINE_ADVANCE = "engine.advance"
+SPAN_ENGINE_DISPATCH = "engine.dispatch"
+SPAN_ENGINE_LAUNCH = "engine.launch"
+SPAN_ENGINE_COLLECT = "engine.collect"
+SPAN_ENGINE_MATERIALIZE = "engine.materialize"
+SPAN_ENGINE_FOLD = "engine.fold"
+SPAN_ENGINE_PUBLISH = "engine.publish"
+# pieces that interleave per job on the cycle thread inside
+# engine.preprocess: add_timing only, totals on that span's attrs
+# (route.cpu: the thread's CPU over the stream, fingerprint included)
+SPAN_ENGINE_ROUTE = "engine.route"
+SPAN_ENGINE_ROUTE_CPU = "engine.route.cpu"
+SPAN_ENGINE_MEMO_FP = "engine.memo_fp"
 SPAN_INGEST_RECEIVE = "ingest.receive"
 SPAN_INGEST_FORWARD = "ingest.forward"
 SPAN_INGEST_WAL = "ingest.wal_append"
@@ -102,6 +118,17 @@ SCORE_SPANS = {
     "hpa": "engine.score.hpa",
 }
 
+# the fetch pool's per-job notes, summed over its threads: THREAD-seconds
+# (16 threads can book 16 s in one wall second), add_timing only — no span
+# is ever opened on a pool thread. `lock_held` is serial by construction,
+# so its thread-seconds are wall seconds.
+POOL_SPANS = {
+    "prep_thread_seconds": "engine.pool.prep",
+    "source_thread_seconds": "engine.pool.source",
+    "lock_wait_thread_seconds": "engine.pool.lock_wait",
+    "lock_held_seconds": "engine.pool.lock_held",
+}
+
 # per-stage cycle timing accumulators (engine.stage.<stage>)
 STAGE_SPANS = {
     "preprocess": "engine.stage.preprocess",
@@ -113,10 +140,13 @@ STAGE_SPANS = {
 SPAN_NAMES = frozenset({
     SPAN_ENGINE_CYCLE, SPAN_ENGINE_CLAIM, SPAN_ENGINE_PREPROCESS,
     SPAN_ENGINE_SCORE, SPAN_ENGINE_LSTM_TRAIN, SPAN_ENGINE_TRIAGE,
-    SPAN_ENGINE_VERDICT, SPAN_DATAPLANE_FETCH,
+    SPAN_ENGINE_VERDICT, SPAN_ENGINE_ADVANCE, SPAN_ENGINE_DISPATCH,
+    SPAN_ENGINE_LAUNCH, SPAN_ENGINE_COLLECT, SPAN_ENGINE_MATERIALIZE,
+    SPAN_ENGINE_FOLD, SPAN_ENGINE_PUBLISH, SPAN_ENGINE_ROUTE,
+    SPAN_ENGINE_ROUTE_CPU, SPAN_ENGINE_MEMO_FP,
     SPAN_INGEST_RECEIVE, SPAN_INGEST_FORWARD, SPAN_INGEST_WAL,
     SPAN_INGEST_SPLICE,
-    *SCORE_SPANS.values(), *STAGE_SPANS.values(),
+    *SCORE_SPANS.values(), *STAGE_SPANS.values(), *POOL_SPANS.values(),
 })
 
 # bound on stored children per span: a span past it counts drops instead

@@ -265,6 +265,44 @@ def test_delta_bytes_saved_accounting():
     assert snap["hit_ratio"] == 0.5
 
 
+@pytest.mark.parametrize("path", ["full", "delta"])
+def test_fetch_notes_where_its_seconds_went(path):
+    """Under an open per-job note accumulator (the engine's fetch pool)
+    a fetch says how long it queued for the splice lock, held it, and sat
+    in the inner source's call; with none open it notes nothing."""
+    import time
+
+    from foremast_tpu.utils import tracing
+
+    be = _Backend()
+    be.series["a"] = [(T0 + i * STEP, float(i)) for i in range(200)]
+    inner = be.source()
+    slow = inner.fetch_series
+
+    def fetch_series(url):
+        time.sleep(0.01)
+        return slow(url)
+
+    inner.fetch_series = fetch_series
+    dsrc = DeltaWindowSource(inner)
+    end = T0 + 199 * STEP
+    if path == "delta":
+        dsrc.fetch_window(_url("a", T0, end))  # no notes open: a no-op
+        be.series["a"].append((end + STEP, 1.0))
+        end += STEP
+    tracing.tracer.begin_notes()
+    t0 = time.perf_counter()
+    dsrc.fetch_window(_url("a", T0, end))
+    elapsed = time.perf_counter() - t0
+    notes = tracing.tracer.take_notes()
+    assert notes["fetch_" + path] == 1
+    assert notes["source_thread_seconds"] >= 0.01
+    assert notes["lock_held_seconds"] > 0
+    assert notes["lock_wait_thread_seconds"] >= 0
+    assert (notes["source_thread_seconds"] + notes["lock_held_seconds"]
+            + notes["lock_wait_thread_seconds"]) <= elapsed
+
+
 # ---------------------------------------------------------- engine identity
 def _stream_fleet(be: _Backend, n_pair=6, n_band=4, n_bi=2, n_lstm=2,
                   n_hpa=2, W=40):

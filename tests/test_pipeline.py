@@ -25,6 +25,7 @@ from foremast_tpu.engine import (
 from foremast_tpu.engine import jobs as J
 from foremast_tpu.engine.pipeline import CompileCounter, CyclePipeline, prewarm
 from foremast_tpu.ops.windowing import Window
+from foremast_tpu.utils import tracing
 from foremast_tpu.utils.timeutils import to_rfc3339
 
 STEP = 60
@@ -373,6 +374,192 @@ def test_cycle_stage_gauges_and_status_surface():
     assert set(cyc["stage_seconds"]) == {"preprocess", "dispatch",
                                          "collect", "fold"}
     assert cyc["family_score_seconds"]["pair"] > 0
+
+
+# ------------------------------------------- the cycle's partition (ISSUE 26)
+def _cycle_root(eng):
+    """The finished engine.cycle span of the analyzer's last cycle."""
+    cid = eng.last_cycle_stages["cycle_id"]
+    return next(t for t in reversed(tracing.tracer.snapshot(limit=256))
+                if t["name"] == tracing.SPAN_ENGINE_CYCLE
+                and t["attrs"]["cycle_id"] == cid)
+
+
+def _spans(span):
+    yield span
+    for c in span.get("children", ()):
+        yield from _spans(c)
+
+
+@pytest.mark.parametrize("pipelined", [True, False],
+                         ids=["pipelined", "barriered"])
+def test_cycle_partition_sums_to_the_cycle_span(pipelined):
+    store, fixtures = _mixed_fleet(n_pair=6, n_band=4, n_bi=2, n_lstm=0,
+                                   n_hpa=2)
+    eng = Analyzer(EngineConfig(score_pipeline=pipelined),
+                   FixtureDataSource(fixtures), store)
+    outcomes = eng.run_cycle(now=1000.0)
+    st = eng.last_cycle_stages
+    part = st["partition"]
+    root = _cycle_root(eng)
+    total = root["duration_ms"] * 1e-3
+    assert sum(part["seconds"].values()) == pytest.approx(
+        total, abs=max(0.02 * total, 0.005))
+    assert part["seconds"]["uncovered"] >= -1e-4
+    # the four stage counters keep their keys and are the partition's
+    assert set(st["stage_seconds"]) == {"preprocess", "dispatch", "collect",
+                                        "fold"}
+    for stage, piece in (("preprocess", "wait"), ("dispatch", "dispatch"),
+                         ("collect", "collect"), ("fold", "fold")):
+        assert part["seconds"][piece] == pytest.approx(
+            st["stage_seconds"][stage], abs=2e-6)
+    by_name = {}
+    for sp in _spans(root):
+        by_name.setdefault(sp["name"], []).append(sp)
+    assert set(by_name) <= tracing.SPAN_NAMES
+    for name in (tracing.SPAN_ENGINE_CLAIM, tracing.SPAN_ENGINE_PREPROCESS,
+                 tracing.SPAN_ENGINE_ADVANCE, tracing.SPAN_ENGINE_SCORE,
+                 tracing.SPAN_ENGINE_LAUNCH, tracing.SPAN_ENGINE_MATERIALIZE,
+                 tracing.SPAN_ENGINE_FOLD, tracing.SPAN_ENGINE_PUBLISH):
+        assert name in by_name, name
+    assert by_name[tracing.SPAN_ENGINE_FOLD][0]["duration_ms"] * 1e-3 == \
+        pytest.approx(part["seconds"]["fold"], abs=5e-3)
+    if pipelined:
+        dispatch = by_name[tracing.SPAN_ENGINE_DISPATCH]
+        assert sum(d["duration_ms"] for d in dispatch) * 1e-3 == \
+            pytest.approx(part["seconds"]["dispatch"], abs=5e-3)
+        assert {d["attrs"]["family"] for d in dispatch} == {
+            "pair", "band", "bivariate", "hpa"}
+        assert len(by_name[tracing.SPAN_ENGINE_COLLECT]) == len(dispatch)
+        assert part["counters"]["memo_lookups"] > 0
+    # the counters ride the spans: per launch, and summed on engine.score
+    score = by_name[tracing.SPAN_ENGINE_SCORE][0]["attrs"]
+    assert score["h2d_bytes"] == part["counters"]["h2d_bytes"] == sum(
+        sp["attrs"]["h2d_bytes"]
+        for sp in by_name[tracing.SPAN_ENGINE_LAUNCH])
+    assert score["d2h_bytes"] == part["counters"]["d2h_bytes"] >= sum(
+        sp["attrs"]["d2h_bytes"]
+        for sp in by_name[tracing.SPAN_ENGINE_MATERIALIZE]) > 0
+    assert 0 < score["pack_real_elems"] < score["pack_total_elems"]
+    # the pool's notes: thread-seconds summed per chunk, and per job on
+    # the fetch record that explain serves
+    prep = by_name[tracing.SPAN_ENGINE_PREPROCESS][0]["attrs"]
+    per_job = sum(eng.provenance.get(j)["fetch"]["prep_thread_seconds"]
+                  for j in outcomes)
+    assert part["pool"]["prep_thread_seconds"] == pytest.approx(
+        per_job, abs=1e-4)
+    assert prep["pool_prep_thread_seconds"] == pytest.approx(
+        part["pool"]["prep_thread_seconds"], abs=2e-6)
+    assert prep["route_s"] == pytest.approx(part["seconds"]["route"],
+                                            abs=2e-6)
+    # served where the rest is served: /status and foremast_trace_*
+    from foremast_tpu.service.api import ForemastService
+
+    _, payload = ForemastService(store, analyzer=eng).status_summary()
+    assert payload["cycle"]["partition"] == part
+    metrics = tracing.tracer.render_metrics()
+    for name in (tracing.SPAN_ENGINE_ROUTE, tracing.SPAN_ENGINE_ROUTE_CPU,
+                 tracing.SPAN_ENGINE_MEMO_FP, tracing.SPAN_ENGINE_LAUNCH,
+                 *tracing.POOL_SPANS.values()):
+        assert f'foremast_trace_seconds_total{{span="{name}"}}' in metrics
+
+
+def _two_windows(rng, n_a, n_b, start=0):
+    def win(n):
+        return Window(rng.normal(10.0, 1.0, n).astype(np.float32),
+                      np.ones(n, bool), start)
+    return win(n_a), win(n_b)
+
+
+@pytest.mark.parametrize("family", ["pair", "band"])
+def test_transfer_and_pack_counters_equal_hand_counts(family):
+    """A two-row launch pads to the 16-row rung: every byte handed to a
+    jitted program and brought back, and the pack's fill, by hand."""
+    import jax
+
+    from foremast_tpu.engine.analyzer import _BandItem, _PairItem
+    from foremast_tpu.ops import forecast as fc
+    from foremast_tpu.parallel import fleet as fl
+
+    rng = np.random.default_rng(5)
+    eng = Analyzer(EngineConfig(), None, JobStore())
+    policy = eng.config.policy_for("latency")
+    R = 16
+
+    def out_bytes(fn, *args):
+        return sum(int(np.prod(o.shape)) * o.dtype.itemsize
+                   for o in jax.tree_util.tree_leaves(
+                       jax.eval_shape(fn, *args)))
+
+    if family == "pair":
+        T, lens = 32, [(30, 20), (25, 28)]
+        items = [_PairItem(f"j{i}", "latency", *_two_windows(rng, b, c),
+                           policy) for i, (b, c) in enumerate(lens)]
+        eng._score_pairs(items)
+        spec = fl.pair_arg_spec(R, T)
+        h2d = sum(a.nbytes for a in spec)
+        d2h = out_bytes(fl.score_pairs, *spec)
+        real, total = sum(b + c for b, c in lens), 2 * R * T
+    else:
+        T, lens = 512, [(300, 25), (290, 20)]
+        items = [_BandItem(f"j{i}", "latency", *_two_windows(rng, h, c),
+                           policy) for i, (h, c) in enumerate(lens)]
+        eng._score_bands(items)
+        f32, b1, row = R * T * 4, R * T, R * 4
+        # _predict (values, history mask), residual_sigma (values,
+        # predictions, history mask, judged mask), band_anomalies (values,
+        # mask, region, predictions, sigma and three per-row policies)
+        h2d = (f32 + b1) + (2 * f32 + 2 * b1) + (2 * f32 + 2 * b1 + 4 * row)
+        z, m = np.zeros((R, T), np.float32), np.zeros((R, T), bool)
+        r4 = np.zeros(R, np.float32)
+        d2h = f32 + row + out_bytes(
+            fc.band_anomalies, z, m, m, z, r4, r4, np.ones(R, np.int32), r4)
+        real, total = sum(h + c for h, c in lens), R * T
+    assert eng.device_launches == 1
+    assert eng.h2d_bytes_total == h2d
+    assert eng.d2h_bytes_total == d2h
+    assert eng.pack_real_elems_total == real
+    assert eng.pack_total_elems_total == total
+
+
+def test_no_span_opens_on_a_fetch_pool_thread(monkeypatch):
+    """A span on a pool thread would be the innermost span over the
+    device's long idle gap and rename it after a chunk of the pool: the
+    pool reports through notes. (The watchdog's sacrificial thread runs a
+    collect under attach and may open engine.materialize.)"""
+    import threading
+
+    opened = []
+    real_span = tracing.tracer.span
+
+    def recording_span(name, *a, **kw):
+        opened.append((name, threading.current_thread().name))
+        return real_span(name, *a, **kw)
+
+    monkeypatch.setattr(tracing.tracer, "span", recording_span)
+    monkeypatch.setattr(tracing, "span", recording_span)
+    store, fixtures = _mixed_fleet(n_pair=40, n_band=8, n_bi=0, n_lstm=0,
+                                   n_hpa=0)
+    pool_threads = set()
+
+    class Source(FixtureDataSource):
+        def fetch(self, url):
+            pool_threads.add(threading.current_thread().name)
+            return super().fetch(url)
+
+    eng = Analyzer(EngineConfig(fetch_concurrency=4, watchdog_seconds=30.0),
+                   Source(fixtures), store)
+    eng.run_cycle(now=1000.0)
+    me = threading.current_thread().name
+    assert len(pool_threads) > 1 and me not in pool_threads
+    assert {n for n, _ in opened} >= {tracing.SPAN_ENGINE_LAUNCH,
+                                      tracing.SPAN_ENGINE_MATERIALIZE}
+    for name, thread in opened:
+        assert thread not in pool_threads, (name, thread)
+        assert thread == me or (thread == "collect-watchdog"
+                                and name == tracing.SPAN_ENGINE_MATERIALIZE)
+    assert eng.last_cycle_stages["partition"]["pool"][
+        "prep_thread_seconds"] > 0
 
 
 # -------------------------------------------------- compile-count gates
