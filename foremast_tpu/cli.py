@@ -376,13 +376,12 @@ def _render_explain(payload: dict) -> str:
         parts = []
         if fetch.get("fetches"):
             parts.append(f"{int(fetch['fetches'])} fetch(es)")
-        mode = []
-        if fetch.get("fetch_delta"):
-            mode.append(f"{int(fetch['fetch_delta'])} delta")
-        if fetch.get("fetch_full"):
-            mode.append(f"{int(fetch['fetch_full'])} full")
-        if fetch.get("fetch_cached"):
-            mode.append(f"{int(fetch['fetch_cached'])} cached")
+        # how each window was got: the notes of dataplane/delta.py's
+        # fetch_window and of the TTL cache above it
+        mode = [f"{int(fetch[note])} {label}" for note, label in (
+            ("fetch_delta", "delta"), ("fetch_unmoved", "unmoved"),
+            ("fetch_ingest", "pushed"), ("fetch_full", "full"),
+            ("fetch_cached", "cached")) if fetch.get(note)]
         if mode:
             parts.append("/".join(mode))
         if fetch.get("points"):

@@ -36,6 +36,14 @@ OLDER than the overlap window are immutable. Rewrites inside the overlap
 are detected (-> full refetch); rewrites beyond it are invisible until
 the entry is evicted — the same staleness contract as the TTL cache, but
 with a self-checking seam.
+
+The closed-range rule follows from the same assumption: a request for
+exactly the range the entry already holds, whose tail is full and whose
+end is older than the overlap window, can only be answered with the
+window the entry holds, so ``fetch_window`` returns it without a backend
+query, a splice or the splice lock (``_unmoved``; counted as
+``unmoved_hits``). A fixed baseline or historical range of a canary is
+one query in its life, not one a cycle.
 """
 from __future__ import annotations
 
@@ -258,6 +266,7 @@ class DeltaWindowSource:
         self._cpu_lock = make_lock("dataplane.delta.splice_cpu")
         # observability (served on /metrics and /status)
         self.delta_hits = 0        # spliced windows
+        self.unmoved_hits = 0      # closed, unmoved ranges served as cached
         self.full_fetches = 0      # misses + fallbacks + non-capable URLs
         self.fallbacks: dict[str, int] = {}  # reason -> count
         self.bytes_delta = 0       # bytes actually fetched on delta queries
@@ -285,16 +294,16 @@ class DeltaWindowSource:
 
     def snapshot(self) -> dict:
         """Live view for /status."""
-        total = self.delta_hits + self.full_fetches + self.ingest_hits
+        hits = self.delta_hits + self.ingest_hits + self.unmoved_hits
+        total = hits + self.full_fetches
         with self._lock:
             entries = len(self._cache)
         return {
             "entries": entries,
             "delta_hits": self.delta_hits,
+            "unmoved_hits": self.unmoved_hits,
             "full_fetches": self.full_fetches,
-            "hit_ratio": round(
-                (self.delta_hits + self.ingest_hits) / total, 4)
-            if total else 0.0,
+            "hit_ratio": round(hits / total, 4) if total else 0.0,
             "bytes_saved": self.bytes_saved,
             "points_saved": self.points_saved,
             "fallbacks": dict(self.fallbacks),
@@ -792,10 +801,23 @@ class DeltaWindowSource:
         # trailing windows (constant span) and for fixed-start/growing-
         # end windows (one extra miss per span doubling).
         key = self._cache_key(url, rng)
+        # read before the lock: the clock is the caller's own function
+        closed_before = float(self.clock()) - self.overlap_steps * self.step
+        win = None
         with self._lock:
             entry = self._cache.get(key)
             if entry is not None:
                 self._cache.move_to_end(key)
+                if self._unmoved(entry, rng, closed_before):
+                    # the stored object itself, as the splice path returns
+                    # the object it stores: no caller writes into a Window
+                    win = entry.win
+                    self.unmoved_hits += 1
+                    self.points_saved += entry.full_points
+                    self.bytes_saved += entry.full_bytes
+        if win is not None:
+            tracing.tracer.add_note("fetch_unmoved")
+            return win
         if entry is None:
             # warm tier first: a spilled/recovered entry promotes back to
             # the hot LRU and serves through the normal pushed/delta
@@ -828,6 +850,37 @@ class DeltaWindowSource:
         if win is not None:
             return win
         return self._full(url, key, rng)
+
+    def _unmoved(self, entry, rng, closed_before: float) -> bool:
+        """The closed-range rule (caller holds ``_lock``, under which every
+        refresh of an entry is written): True when the cached window IS
+        what a splice of this request would rebuild, so it can be returned
+        as it stands, with no backend query and no splice. All four hold:
+
+          1. the entry is poll-fed, trusted and whole: no pushed horizon
+             (that path keeps ``_try_ingest_serve``), no resync latch, no
+             span clip at the head;
+          2. the range has not moved since the entry was last refreshed;
+          3. the tail is full: the next on-grid slot after the newest
+             sample (valid or NaN-valued: the window's last slot, since
+             entries are exact-grid) lies beyond the range's end, so a
+             backend that only appends has nothing to add;
+          4. the range is closed: its end is at least ``overlap_steps``
+             old by the source's clock, the age below which a splice
+             re-reads samples and above which it never does.
+        """
+        qstart, qend, url_step = rng
+        w = entry.win
+        n = w.values.shape[0]
+        if (entry.pushed_until != 0 or entry.push_blocked
+                or n >= MAX_WINDOW_STEPS):
+            return False
+        if (url_step != entry.url_step or qstart != entry.qstart
+                or qend != entry.qend):
+            return False
+        last_end = w.start + (n - 1) * w.step
+        return qstart <= last_end and last_end + w.step > qend \
+            and qend <= closed_before
 
     def _full(self, url: str, key, rng) -> Window:
         """Full refetch; (re)prime the cache entry when the response is

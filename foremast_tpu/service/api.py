@@ -641,6 +641,11 @@ class ForemastService:
             snap = self.delta_source.snapshot()
             lines.append(
                 f"foremastbrain:delta_fetch_hits_total {snap['delta_hits']}")
+            # closed, unmoved ranges answered from the window cache with
+            # no backend query (a canary's fixed baseline and history)
+            lines.append(
+                "foremastbrain:delta_fetch_unmoved_total "
+                f"{snap['unmoved_hits']}")
             lines.append(
                 "foremastbrain:delta_fetch_full_total "
                 f"{snap['full_fetches']}")

@@ -37,6 +37,7 @@ from foremast_tpu.engine import (
     JobStore,
     MetricQueries,
 )
+from foremast_tpu.utils import tracing
 from foremast_tpu.utils.timeutils import to_rfc3339
 
 STEP = 60
@@ -272,8 +273,6 @@ def test_fetch_notes_where_its_seconds_went(path):
     in the inner source's call; with none open it notes nothing."""
     import time
 
-    from foremast_tpu.utils import tracing
-
     be = _Backend()
     be.series["a"] = [(T0 + i * STEP, float(i)) for i in range(200)]
     inner = be.source()
@@ -301,6 +300,146 @@ def test_fetch_notes_where_its_seconds_went(path):
     assert notes["lock_wait_thread_seconds"] >= 0
     assert (notes["source_thread_seconds"] + notes["lock_held_seconds"]
             + notes["lock_wait_thread_seconds"]) <= elapsed
+
+
+# ------------------------------------------------- the closed-range rule
+class _Unmoved:
+    """One backend, a delta source on an injected clock and a full-refetch
+    source beside it. `fetch` holds every window to the full refetch's
+    and returns how many backend requests the delta source made for it."""
+
+    END = T0 + 29 * STEP  # newest of the 30 samples the backend starts with
+
+    def __init__(self):
+        self.be = _Backend()
+        self.be.series["a"] = [(T0 + i * STEP, float(i)) for i in range(30)]
+        self.now = float(self.END + 1000 * STEP)
+        self.inner = self.be.source()
+        self.dsrc = DeltaWindowSource(self.inner, clock=lambda: self.now)
+        self.fsrc = self.be.source()
+        self.url = _url("a", T0, self.END)
+        self.notes = {}
+
+    def fetch(self, url=None) -> int:
+        url = url or self.url
+        before = self.inner.request_count
+        tracing.tracer.begin_notes()
+        win = self.dsrc.fetch_window(url)
+        for k, v in tracing.tracer.take_notes().items():
+            self.notes[k] = self.notes.get(k, 0) + v
+        _assert_windows_equal(win, self.fsrc.fetch_window(url), url)
+        return self.inner.request_count - before
+
+    def counts(self):
+        d = self.dsrc
+        return (d.unmoved_hits, d.delta_hits, d.ingest_hits, d.full_fetches)
+
+
+def _unmoved_closed(h):
+    """(a) a closed fixed range fetched four times is one backend request,
+    also with its end exactly `overlap_steps` old."""
+    h.now = float(h.END + h.dsrc.overlap_steps * STEP)
+    assert [h.fetch() for _ in range(4)] == [1, 0, 0, 0]
+    assert h.counts() == (3, 0, 0, 1)
+    assert h.inner.requests == [h.url]
+    snap = h.dsrc.snapshot()
+    assert snap["unmoved_hits"] == 3 and snap["hit_ratio"] == 0.75
+    assert snap["points_saved"] == 3 * 30
+    assert h.notes["fetch_unmoved"] == 3 and h.notes["fetch_full"] == 1
+    # the served window is the one the cache holds, and it dirties nothing
+    entry = next(iter(h.dsrc._cache.values()))
+    assert h.dsrc.fetch_window(h.url) is entry.win
+
+
+def _unmoved_inside_overlap(h):
+    """(b) the same range with its end inside `overlap_steps` of the clock
+    is spliced as today, and a rewrite of its newest sample is seen."""
+    h.now = float(h.END + h.dsrc.overlap_steps * STEP - 1)
+    assert h.fetch() == 1
+    h.be.series["a"][-1] = (h.END, 99.5)
+    assert h.fetch() == 1
+    assert h.inner.requests[-1] == _url("a", h.END - 5 * STEP, h.END)
+    assert h.counts() == (0, 1, 0, 1)
+    assert h.dsrc.fetch_window(h.url).values[-1] == np.float32(99.5)
+    assert "fetch_unmoved" not in h.notes and h.notes["fetch_delta"] == 1
+
+
+def _unmoved_short_tail(h):
+    """(c) a range whose tail is short of its end is spliced, and a late
+    sample appears in the next fetch; only then is the range served."""
+    del h.be.series["a"][-1]
+    assert [h.fetch(), h.fetch()] == [1, 1]
+    assert h.counts() == (0, 1, 0, 1)
+    h.be.series["a"].append((h.END, 5.0))
+    assert h.fetch() == 1
+    assert h.dsrc.fetch_window(h.url).values.shape[0] == 30
+    assert h.counts()[:2] == (1, 2)  # the look above was served
+
+
+def _unmoved_resync(h):
+    """(d) `force_resync()` and `push_blocked` end in a full refetch."""
+    assert [h.fetch(), h.fetch()] == [1, 0]
+    h.dsrc.force_resync()
+    assert h.fetch() == 1 and h.inner.requests[-1] == h.url
+    assert h.dsrc.fallbacks == {"resync": 1}
+    assert h.fetch() == 0  # the refetch re-primed a clean entry
+    h.dsrc.ingest_block(h.url)
+    assert h.fetch() == 1 and h.inner.requests[-1] == h.url
+    assert h.dsrc.fallbacks == {"resync": 2}
+    assert h.counts() == (2, 0, 0, 3)
+
+
+def _unmoved_moved(h):
+    """(e) the range moved by one step splices and does not count."""
+    assert h.fetch() == 1
+    h.be.series["a"].append((h.END + STEP, 1.0))
+    assert h.fetch(_url("a", T0 + STEP, h.END + STEP)) == 1
+    assert h.counts() == (0, 1, 0, 1)
+    # neither does a start that moved alone, forwards or back
+    assert h.fetch(_url("a", T0 + 2 * STEP, h.END + STEP)) == 1
+    assert h.fetch(_url("a", T0 + STEP, h.END + STEP)) == 1
+    assert h.counts() == (0, 2, 0, 2)
+    assert h.dsrc.fallbacks == {"range_extended": 1}
+
+
+def _unmoved_pushed(h):
+    """(f) a pushed entry (`pushed_until > 0`) is left to
+    `_try_ingest_serve`, which cuts the window to the range asked for."""
+    assert h.fetch() == 1
+    h.be.series["a"].append((h.END + STEP, 1.0))
+    out = h.dsrc.ingest_append(h.url, [h.END + STEP], [1.0])
+    assert out["spliced"] == 1
+    assert h.fetch() == 0
+    assert h.counts() == (0, 0, 1, 1)
+    assert h.notes["fetch_ingest"] == 1 and "fetch_unmoved" not in h.notes
+    entry = next(iter(h.dsrc._cache.values()))
+    assert entry.win.values.shape[0] == 31  # not what the range holds
+
+
+def _unmoved_span_clipped(h):
+    """(g) a span-clipped window is not served: its head was cut, so the
+    cache alone cannot vouch for the range."""
+    from foremast_tpu.ops.windowing import MAX_WINDOW_STEPS
+
+    n = MAX_WINDOW_STEPS + 10
+    h.be.series["a"] = [(T0 + i * STEP, float(i % 11)) for i in range(n)]
+    h.url = _url("a", T0, T0 + (n - 1) * STEP)
+    h.now = float(T0 + (n + 1000) * STEP)
+    assert [h.fetch(), h.fetch()] == [1, 1]
+    assert h.dsrc.fetch_window(h.url).values.shape[0] == MAX_WINDOW_STEPS
+    assert h.dsrc.unmoved_hits == 0 and h.dsrc.full_fetches == 1
+
+
+@pytest.mark.parametrize("case", [
+    _unmoved_closed, _unmoved_inside_overlap, _unmoved_short_tail,
+    _unmoved_resync, _unmoved_moved, _unmoved_pushed, _unmoved_span_clipped,
+], ids=lambda f: f.__name__[len("_unmoved_"):])
+def test_closed_unmoved_range_is_served_from_cache(case):
+    """A request whose range the entry already holds whole, and which is
+    closed, is answered with the cached Window and no backend query; any
+    other request takes the splice or full-refetch path untouched. Every
+    window is held to a full refetch's on the same backend."""
+    case(_Unmoved())
 
 
 # ---------------------------------------------------------- engine identity
@@ -419,6 +558,86 @@ def test_delta_memo_cycle_identical_to_full_refetch():
     # and the incremental machinery actually engaged
     assert src_on.delta_hits > 0
     assert sum(eng_on.score_memo_hits.values()) > 0
+
+
+def _run_rolling(delta: bool, jobs=5, cycles=4, W=20, H=200):
+    """`rollingUpdate` jobs as barrelman builds them: a current window
+    that grows by one sample a cycle, and a baseline and a history whose
+    ranges are fixed timestamps in the past. Returns per-cycle verdict
+    snapshots (store state and every record's scores), backend requests
+    per cycle, and each job's fetch record."""
+    be = _Backend()
+    rng = np.random.default_rng(11)
+    store = JobStore()
+    cur0 = T0 + H * STEP
+    far = T0 + 4000 * STEP
+    for i in range(jobs):
+        be.series[f"r{i}"] = [
+            (T0 + k * STEP, round(float(v), 4))
+            for k, v in enumerate(10.0 + rng.normal(0, 1.0, H + W))]
+        store.create(Document(
+            id=f"roll{i}", app_name=f"app-{i}", namespace="px",
+            strategy="rollingUpdate", start_time=to_rfc3339(float(T0)),
+            end_time=to_rfc3339(float(far)),
+            metrics={"error4xx": MetricQueries(
+                current=_url(f"r{i}", cur0, far),
+                baseline=_url(f"r{i}", cur0 - 2 * W * STEP,
+                              cur0 - W * STEP),
+                historical=_url(f"r{i}", T0, cur0 - STEP))}))
+    clock = [float(cur0 + W * STEP)]
+    inner = be.source()
+    source = DeltaWindowSource(inner, clock=lambda: clock[0]) \
+        if delta else inner
+    eng = Analyzer(EngineConfig(delta_fetch=delta, score_memo=delta),
+                   source, store, VerdictExporter())
+    snaps, requests, fetches = [], [], []
+    for _ in range(cycles):
+        newest = int(clock[0])
+        for samples in be.series.values():
+            samples.append(
+                (newest, round(float(10.0 + rng.normal(0, 1.0)), 4)))
+        clock[0] += 5.0
+        before = inner.request_count
+        eng.run_cycle(now=clock[0])
+        requests.append(inner.request_count - before)
+        recs = {f"roll{i}": eng.provenance.get(f"roll{i}")
+                for i in range(jobs)}
+        snaps.append((_snapshot(store),
+                      {j: r["families"] for j, r in recs.items()}))
+        fetches.append({j: r["fetch"] for j, r in recs.items()})
+        clock[0] += STEP - 5.0
+    return snaps, requests, fetches, source
+
+
+def test_rolling_update_fixed_ranges_one_backend_query_a_cycle():
+    """A live `rollingUpdate` job costs three backend queries in its first
+    cycle and one in every later cycle: its baseline and history are
+    closed and do not move, so the window cache answers for them. Verdict
+    state and the points each job's record counts equal the
+    `DELTA_FETCH=0` run's, cycle by cycle."""
+    jobs = 5
+    snaps_on, req_on, fetch_on, src = _run_rolling(delta=True, jobs=jobs)
+    snaps_off, req_off, fetch_off, _ = _run_rolling(delta=False, jobs=jobs)
+    assert snaps_on == snaps_off
+    assert all(len(fams) == 2 for fams in snaps_on[-1][1].values())
+    assert req_on == [3 * jobs, jobs, jobs, jobs]
+    assert req_off == [3 * jobs] * 4
+    for on, off in zip(fetch_on, fetch_off):
+        assert ({j: f["points"] for j, f in on.items()}
+                == {j: f["points"] for j, f in off.items()})
+        assert all(f["fetches"] == 3 for f in on.values())
+    assert all(f["fetch_full"] == 3 for f in fetch_on[0].values())
+    for cyc in fetch_on[1:]:  # steady state
+        for f in cyc.values():
+            assert f["fetch_delta"] == 1 and f["fetch_unmoved"] == 2
+            assert "fetch_full" not in f
+    assert src.unmoved_hits == 2 * jobs * 3 and src.delta_hits == jobs * 3
+    # and `foremast explain` says how each window was got
+    from foremast_tpu.cli import _render_explain
+
+    text = _render_explain(
+        {"provenance": {"path": "scored", "fetch": fetch_on[-1]["roll0"]}})
+    assert "3 fetch(es), 1 delta/2 unmoved, 245 points" in text
 
 
 def test_memo_changed_single_row_rescores_only_its_bucket():
@@ -571,9 +790,10 @@ def test_window_cache_counters_exported():
     cache.fetch("u")
     cache.fetch("u")  # hit
     be = _Backend()
-    be.series["a"] = [(T0, 1.0)]
+    be.series["a"] = [(T0, 1.0), (T0 + STEP, 2.0)]
     dsrc = DeltaWindowSource(be.source())
     dsrc.fetch_window(_url("a", T0, T0 + STEP))
+    dsrc.fetch_window(_url("a", T0, T0 + STEP))  # closed, unmoved: served
     svc = ForemastService(JobStore(), exporter=VerdictExporter(),
                           cache_source=cache, delta_source=dsrc)
     _, text = svc.metrics()
@@ -581,11 +801,15 @@ def test_window_cache_counters_exported():
     assert "foremastbrain:window_cache_misses_total 1" in text
     assert "foremastbrain:window_cache_single_flight_waits_total 0" in text
     assert "foremastbrain:delta_fetch_full_total 1" in text
+    assert "foremastbrain:delta_fetch_hits_total 0" in text
+    assert "foremastbrain:delta_fetch_unmoved_total 1" in text
+    assert "foremastbrain:delta_fetch_hit_ratio 0.5" in text
     status, payload = svc.status_summary()
     assert status == 200
     assert payload["window_cache"] == {
         "hits": 1, "misses": 1, "single_flight_waits": 0}
     assert payload["delta_fetch"]["full_fetches"] == 1
+    assert payload["delta_fetch"]["unmoved_hits"] == 1
 
 
 # ------------------------------------------------------- lstm train memo
