@@ -8,20 +8,18 @@ configuration's `check` block, or the limit itself):
   band_count_out  jobs whose anomalous-point count is outside what a band
                   within the limit of the reference's could count
 """
-import numpy as np
-
 from lib import reference
 
 NUMBERS = (("band_gap", "max", "band_gap_sigmas"),
            ("band_count_out", "sum", 0))
 
 
-def reference_rows(fleet, jobs: list, k_now: int, limits: dict,
-                   precision: str = "float64") -> dict:
-    hist = np.stack([fleet.served(j, 0, fleet.hist_lo, fleet.hist_hi)
-                     for j in jobs])
-    cur = np.stack([fleet.served(j, 0, fleet.hist_hi, k_now) for j in jobs])
-    return reference.band_rows(hist, cur, fleet.cls(jobs[0])["metric"],
+def reference_rows(fleet, jobs: list, slots: tuple, k_now: int,
+                   limits: dict, precision: str = "float64") -> dict:
+    (slot,) = slots
+    hist = fleet.served_rows(jobs, slot, fleet.hist_lo, fleet.hist_hi)
+    cur = fleet.served_rows(jobs, slot, fleet.hist_hi, k_now)
+    return reference.band_rows(hist, cur, fleet.metrics_of(jobs[0])[slot],
                                float(limits["band_gap_sigmas"]), precision)
 
 

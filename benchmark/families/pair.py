@@ -6,20 +6,18 @@ Number compared:
   pair_p_gap  widest gap between the program's and the reference's
               smallest p-value
 """
-import numpy as np
-
 from lib import reference
 
 NUMBERS = (("pair_p_gap", "max", "pair_p_gap"),)
 
 
-def reference_rows(fleet, jobs: list, k_now: int, limits: dict,
-                   precision: str = "float64") -> dict:
-    base = np.stack([fleet.served(
-        j, 0, fleet.base_lo, fleet.base_lo + fleet.window_steps)
-        for j in jobs])
-    cur = np.stack([fleet.served(j, 0, fleet.hist_hi, k_now) for j in jobs])
-    return reference.pair_rows(base, cur, fleet.cls(jobs[0])["metric"],
+def reference_rows(fleet, jobs: list, slots: tuple, k_now: int,
+                   limits: dict, precision: str = "float64") -> dict:
+    (slot,) = slots
+    base = fleet.served_rows(jobs, slot, fleet.base_lo,
+                             fleet.base_lo + fleet.window_steps)
+    cur = fleet.served_rows(jobs, slot, fleet.hist_hi, k_now)
+    return reference.pair_rows(base, cur, fleet.metrics_of(jobs[0])[slot],
                                float(limits["band_gap_sigmas"]), precision)
 
 

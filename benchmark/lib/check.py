@@ -11,6 +11,14 @@ reference and comparison are `benchmark/families/<family>.py`, found by
 the name the configuration's class lists; each number compared has a
 limit (PERF.md gives the readings they were set from).
 
+A job's results are (family, metric) entries, as the program records
+them: a family gives one a metric of the job, or, where its file sets
+`JOINS`, one for the job's metrics together, named by joining their
+aliases with that string (`"error4xx&latency"`). The bounds the program
+exports for a job's app (`foremastbrain:<metric>_upper`, `_lower`) ride
+on every entry under `exported`; only a class whose jobs have an app
+each (`apps`) can be held to them.
+
 Numbers of every cell, beside the families' own:
   verdict_miss  jobs with no verdict from the last cycle, or whose stored
                 verdict the reference contradicts
@@ -42,17 +50,51 @@ def family(name: str):
     return _FAMILIES[name]
 
 
+def expected(fleet, job: int) -> list:
+    """[(family, metric key, series slots)]: the results a cycle owes the
+    job, one a metric or one for the metrics together, as each family's
+    file says."""
+    metrics = fleet.metrics_of(job)
+    out = []
+    for f in fleet.families_of(job):
+        joins = getattr(family(f), "JOINS", None)
+        if joins is None:
+            out += [(f, m, (slot,)) for slot, m in enumerate(metrics)]
+        else:
+            out.append((f, joins.join(metrics), tuple(range(len(metrics)))))
+    return out
+
+
+def _exported(analyzer) -> dict:
+    """{(app, metric): [lower, upper]} the program's exporter holds."""
+    out: dict = {}
+    for name, labels, value in analyzer.exporter.samples():
+        for i, side in enumerate(("_lower", "_upper")):
+            if name.startswith("foremastbrain:") and name.endswith(side):
+                metric = name[len("foremastbrain:"):-len(side)]
+                out.setdefault((labels.get("app"), metric),
+                               [None, None])[i] = value
+    return out
+
+
 def program_answers(analyzer, store, fleet, last_cycle: dict) -> dict:
     """{job index: answer} for every job of the last cycle. An answer is
-    None where the cycle left no verdict for the job."""
+    None where the cycle left no verdict for the job, or not every
+    result it owes it."""
     answers = {}
+    exported = _exported(analyzer)
     for jid in last_cycle["outcomes"]:
         j = fleet.job_index(jid)
         rec = analyzer.provenance.get(jid)
         doc = store.get(jid)
-        fams = {f["family"]: f for f in (rec or {}).get("families") or []}
-        if (rec is None or doc is None
-                or sorted(fams) != sorted(fleet.families_of(j))
+        bounds = {m: exported.get((fleet.app_name(j), m))
+                  for m in fleet.metrics_of(j)}
+        entries = (rec or {}).get("families") or []
+        fams = {(f["family"], f["metric"]): dict(f, exported=bounds)
+                for f in entries}
+        if (rec is None or doc is None or len(fams) != len(entries)
+                or sorted(fams) != sorted(
+                    (f, key) for f, key, _ in expected(fleet, j))
                 or rec["cycle"].get("cycle_id") != last_cycle["cycle_id"]):
             answers[j] = None
             continue
@@ -66,8 +108,8 @@ def program_answers(analyzer, store, fleet, last_cycle: dict) -> dict:
 
 
 def _blocks(fleet, jobs) -> list:
-    """Blocks of jobs of one class, so a block has one metric and one
-    list of families."""
+    """Blocks of jobs of one class, so a block has one list of metrics
+    and one of families."""
     by_class: dict = {}
     for j in sorted(jobs):
         by_class.setdefault(int(fleet.class_of[j]), []).append(j)
@@ -83,16 +125,23 @@ def reference_answers(fleet, jobs: list, now_slot: int, lag_s: float,
     limits = fleet.config["check"]
     out = {}
     for block in _blocks(fleet, jobs):
-        refs = {f: family(f).reference_rows(fleet, block, now_slot, limits,
-                                            precision)
-                for f in fleet.families_of(block[0])}
+        refs = _reference(fleet, block, now_slot, limits, precision)
         for i, j in enumerate(block):
-            fams = {f: family(f).answer(ref, i) for f, ref in refs.items()}
+            fams = {e: family(e[0]).answer(ref, i)
+                    for e, ref in refs.items()}
             bad = any(e["unhealthy"] for e in fams.values())
             out[j] = {"status": UNHEALTHY if bad else HEALTHY[0],
                       "families": fams, "lag_s": lag_s,
                       "points": fleet.points_fetched(j, now_slot)}
     return {"jobs": out, "now_slot": now_slot, "lag_s": lag_s}
+
+
+def _reference(fleet, block: list, k_now: int, limits: dict,
+               precision: str = "float64") -> dict:
+    """{(family, metric key): reference rows} of one block of jobs."""
+    return {(f, key): family(f).reference_rows(
+        fleet, block, slots, k_now, limits, precision)
+        for f, key, slots in expected(fleet, block[0])}
 
 
 def compare(fleet, answers: dict) -> list:
@@ -110,14 +159,13 @@ def compare(fleet, answers: dict) -> list:
     miss = sum(1 for a in got.values() if a is None)
     stale = 0
     for block in _blocks(fleet, [j for j, a in got.items() if a is not None]):
-        refs = {f: family(f).reference_rows(fleet, block, k_now, limits)
-                for f in fleet.families_of(block[0])}
+        refs = _reference(fleet, block, k_now, limits)
         for i, j in enumerate(block):
             a = got[j]
             must_bad, must_good = False, True
-            for f, ref in refs.items():
-                readings, bad, good = family(f).judge(
-                    a["families"][f], ref, i, limits)
+            for e, ref in refs.items():
+                readings, bad, good = family(e[0]).judge(
+                    a["families"][e], ref, i, limits)
                 must_bad, must_good = must_bad or bad, must_good and good
                 for name, v in readings.items():
                     value[name] = max(value[name], v) \

@@ -37,6 +37,27 @@ def pair(rows: int, base_points: int, cur_points: int) -> dict:
             "ops": rows * (n * math.log2(n) + 12 * n)}
 
 
+def bivariate(rows: int, points: int, judged: int) -> dict:
+    """One bivariate launch over `rows` jobs of two series of `points`
+    samples each, the last `judged` of them the judged window.
+
+    bytes: read both values (float32), their validity and the judged
+    mark (one byte each), write the anomaly flag (one byte): 12 per
+    sample. Per row, read the radius, the two floors and the two bound
+    masks (20) and write the count, the first index, the points checked
+    and the four marginal bounds, which are constant along a row (28).
+    The distances and a bound for every sample, which today's program
+    also writes, are not needed.
+    operations, per history sample: the three sums of the means and the
+    count (3), the two deviations (2), their three products and sums (6):
+    11. Per judged sample: the two deviations (2), the three products
+    (3), their weighting by the inverse covariance and sum (5), the
+    comparison with the radius (1), the two signs and their gate (3):
+    14."""
+    return {"bytes": rows * (12 * points + 48),
+            "ops": rows * (11 * (points - judged) + 14 * judged)}
+
+
 def least_seconds(cost: dict, peaks: dict) -> tuple[float, str]:
     """The least time the chip could take, and which peak bounds it."""
     by_bytes = cost["bytes"] / peaks["bytes_per_s"]

@@ -16,7 +16,9 @@ Grid: slot k is the sample at `t0 + k * step`. A job's windows are
   baseline    slots [H, H + W]              (the current window's start
                                              one diurnal period earlier,
                                              same phase)
-where lead is one diurnal period in steps.
+where lead is one diurnal period in steps. A job that watches several
+metrics (`metrics` of its class) has these windows of each, on the same
+slots; metric i is series slot i of the job, and the anomaly is on slot 0.
 """
 from __future__ import annotations
 
@@ -97,6 +99,11 @@ class Fleet:
         """The series as the metric store serves it: four decimals."""
         return np.round(self.series(job, slot, k_lo, k_hi), 4)
 
+    def served_rows(self, jobs: list, slot: int, k_lo: int,
+                    k_hi: int) -> np.ndarray:
+        """(len(jobs), k_hi - k_lo + 1): one served series a job."""
+        return np.stack([self.served(j, slot, k_lo, k_hi) for j in jobs])
+
     def clip(self, qstart: float, qend: float) -> tuple[int, int]:
         """Grid slots a range query [qstart, qend] returns: whole steps
         inside the range, nothing newer than the simulated clock."""
@@ -144,6 +151,20 @@ class Fleet:
         """The scoring families the job's windows route it to."""
         return self.cls(job)["families"]
 
+    def metrics_of(self, job: int) -> list:
+        """The aliases of the metrics a job watches; a metric's series
+        slot is its position. A class lists `metrics`, or names its one
+        `metric`."""
+        cls = self.cls(job)
+        return list(cls["metrics"]) if "metrics" in cls else [cls["metric"]]
+
+    def app_name(self, job: int) -> str:
+        """The app a job's document names: jobs share `apps` names (256
+        unless the class says otherwise). The program exports a job's
+        bounds under its app, so a class whose bounds are compared gives
+        every job an app of its own."""
+        return f"app-{job % int(self.cls(job).get('apps', 256))}"
+
     def window_slots(self, role: str) -> tuple[str, int, int]:
         """(URL tag, first slot, last slot) of a window role. The three
         roles are the job API's own; a class lists the ones its jobs
@@ -157,20 +178,22 @@ class Fleet:
         raise ValueError(f"unknown window role {role!r}")
 
     def queries(self, job: int) -> dict:
-        """{metric: {role: url}} for one job, by its class's windows."""
-        cls = self.cls(job)
-        return {cls["metric"]: {
-            role: self.url(job, 0, *self.window_slots(role))
-            for role in ("current", *cls["windows"])}}
+        """{metric: {role: url}} for one job, by its class's metrics and
+        windows."""
+        roles = ("current", *self.cls(job)["windows"])
+        return {metric: {role: self.url(job, slot, *self.window_slots(role))
+                         for role in roles}
+                for slot, metric in enumerate(self.metrics_of(job))}
 
     def points_fetched(self, job: int, k_now: int) -> int:
         """Samples the job's windows hold when the clock is at slot
-        `k_now`: what a cycle has to have fetched or spliced for it."""
+        `k_now`, over all its metrics: what a cycle has to have fetched
+        or spliced for it."""
         n = 0
         for role in ("current", *self.cls(job)["windows"]):
             _, lo, hi = self.window_slots(role)
             n += min(hi, k_now) - lo + 1
-        return n
+        return n * len(self.metrics_of(job))
 
     def window_span(self) -> tuple[str, str]:
         """(start, end) RFC 3339 of every job's analysis: it outlasts the

@@ -1,4 +1,4 @@
-"""Plain reference of the two scoring families, in numpy and float64.
+"""Plain reference of the scoring families, in numpy and float64.
 
 Imports nothing of the program and takes nothing it has made: the inputs
 are the fleet's own series (four decimals, as the store serves them), the
@@ -20,6 +20,19 @@ published judgment rules:
     corrections, judged at `pairwise_threshold`, beside a moving-average
     band of the baseline that condemns when over 30% of current points
     leave it.
+  bivariate (two metrics a job, foremast design.md:53-88, "Bivariate
+    Normal Distribution"): the joint mean and covariance of the two
+    histories over the slots both hold; a judged point is anomalous when
+    its squared Mahalanobis distance passes the square of the stricter of
+    the two metrics' thresholds, and only where it left the mean in a
+    direction one of the two policies' bounds watches; unhealthy by the
+    band's gate. The published rule is the menu entry alone, so three
+    things are this project's and are noted, not published: a ridge of
+    RIDGE times the larger variance (at least 1) on both variances, which
+    keeps the inverse defined for a constant or perfectly correlated
+    history; the covariance over n, not n - 1; and marginal bounds
+    (mean -+ radius x sigma, the lower one floored) drawn for both metrics
+    at the pair's one radius, not at each metric's own.
 
 `precision` is the control: "bfloat16" rounds the served samples to
 bfloat16 before anything is computed, the step a later change would be
@@ -34,13 +47,15 @@ import numpy as np
 
 # policies of the configuration's source (foremast-brain.yaml:34-73):
 # metric -> (band half-width in sigmas, bound bitmask, lower floor)
-POLICIES = {"error5xx": (2.0, 1, 0.0), "error4xx": (3.0, 1, 0.0)}
+POLICIES = {"error5xx": (2.0, 1, 0.0), "error4xx": (3.0, 1, 0.0),
+            "latency": (10.0, 3, 0.0)}
 MA_WINDOW = 30
 BAND_MIN_POINTS = 2
 BAND_VIOLATION_FRACTION = 0.1
 PAIR_ALPHA = 0.01
 PAIR_BAND_FRACTION = 0.3
 MIN_MANN_WHITNEY_POINTS = 20
+RIDGE = 1e-6
 
 
 def _quantize(x: np.ndarray, precision: str) -> np.ndarray:
@@ -156,3 +171,52 @@ def pair_rows(base: np.ndarray, cur: np.ndarray, metric: str,
     frac = PAIR_BAND_FRACTION * cur.shape[1]
     return {"min_p": p, "band_min": _count(b, slack) > frac,
             "band_max": _count(b, -slack) > frac}
+
+
+def _watched(dev: np.ndarray, bound: int) -> np.ndarray:
+    """Where a deviation from the mean lies on a side the bound watches
+    (bit 1 above, bit 2 below, 0 both)."""
+    bound = bound or 3
+    return ((dev > 0) & bool(bound & 1)) | ((dev < 0) & bool(bound & 2))
+
+
+def bivariate_rows(hists: tuple, curs: tuple, metrics: tuple, slack: float,
+                   precision: str = "float64") -> dict:
+    """Reference results for two-metric jobs: `hists` two (B, H) and
+    `curs` two (B, C) blocks of served samples, every sample present.
+    Returns per row the four marginal bounds and sigmas, the count of
+    anomalous judged points, and the counts with the ellipse's radius
+    moved out and in by `slack` (in units of the distance itself): the
+    bracket of any count whose ellipse is within that of the
+    reference's."""
+    (h1, h2), (c1, c2) = ([_quantize(x, precision) for x in pair]
+                          for pair in (hists, curs))
+    (k1, b1, f1), (k2, b2, f2) = (POLICIES[m] for m in metrics)
+    radius = min(k1, k2)
+    n_h = h1.shape[1]
+    if n_h < 2 or c1.shape[1] < 1:
+        raise ValueError("a joint fit needs history and a judged window")
+    mu1, mu2 = h1.mean(axis=1), h2.mean(axis=1)
+    d1, d2 = h1 - mu1[:, None], h2 - mu2[:, None]
+    var1, var2 = (d1 * d1).sum(axis=1) / n_h, (d2 * d2).sum(axis=1) / n_h
+    cov = (d1 * d2).sum(axis=1) / n_h
+    ridge = RIDGE * np.maximum(np.maximum(var1, var2), 1.0)
+    var1, var2 = var1 + ridge, var2 + ridge
+    det = var1 * var2 - cov * cov
+    a, b = c1 - mu1[:, None], c2 - mu2[:, None]
+    dist = np.sqrt((var2[:, None] * a * a - 2.0 * cov[:, None] * a * b
+                    + var1[:, None] * b * b) / det[:, None])
+    watched = _watched(a, b1) | _watched(b, b2)
+    s1, s2 = np.sqrt(var1), np.sqrt(var2)
+
+    def count(r):
+        return ((dist > r) & watched).sum(axis=1)
+
+    checked = c1.shape[1]
+    return {
+        "bounds": ((np.maximum(mu1 - radius * s1, f1), mu1 + radius * s1),
+                   (np.maximum(mu2 - radius * s2, f2), mu2 + radius * s2)),
+        "sigma": (s1, s2), "count": count(radius),
+        "count_min": count(radius + slack), "count_max": count(radius - slack),
+        "gate": max(BAND_MIN_POINTS, BAND_VIOLATION_FRACTION * checked),
+    }

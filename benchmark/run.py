@@ -26,6 +26,7 @@ import time
 _T0 = time.perf_counter()  # process start, for setup_s
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -115,7 +116,7 @@ class Engine:
         start, end = fl.window_span()
         for job in range(fl.jobs):
             self.store.create(J.Document(
-                id=fl.job_id(job), app_name=f"app-{job % 256}",
+                id=fl.job_id(job), app_name=fl.app_name(job),
                 namespace="bench", strategy=fl.cls(job)["strategy"],
                 start_time=start, end_time=end,
                 metrics={m: J.MetricQueries(**q)
@@ -126,6 +127,11 @@ class Engine:
         # the latest-record table is sized for the fleet, as the window
         # cache is: the check reads the last cycle's records from it
         self.analyzer.provenance.max_jobs = 2 * fl.jobs
+        # rows a job of each class gives each scoring family: one a result
+        first = fl.class_of.tolist().index
+        self.rows_of = [
+            collections.Counter(f for f, _, _ in check.expected(fl, first(c)))
+            for c in range(len(fl.classes))]
         self.compiles = CompileCounter().start()
         self.cycles_run = 0
 
@@ -152,8 +158,9 @@ class Engine:
         failed = bad + unjudged + sum(int(st[k]) for k in CYCLE_COUNTERS)
         rows = {}  # rows each scoring family was given
         for jid in outcomes:
-            for fam in fl.families_of(fl.job_index(jid)):
-                rows[fam] = rows.get(fam, 0) + 1
+            cls = fl.class_of[fl.job_index(jid)]
+            for fam, n in self.rows_of[cls].items():
+                rows[fam] = rows.get(fam, 0) + n
         k_now = fl.now_slot()
         return {
             "seconds": seconds, "offered": offered, "failed": failed,
@@ -284,7 +291,8 @@ def run(args) -> dict:
                       for k, v in values.items() if k in units}
     out["device"] = device
     out["cycles"] = [{k: c[k] for k in ("seconds", "offered", "failed",
-                                        "stage_seconds", "launches", "rows")}
+                                        "stage_seconds", "launches", "fetches",
+                                        "rows")}
                      for c in cycles]
     out["warm_cycles"] = [{"seconds": c["seconds"], "compiles": c["compiles"]}
                           for c in warm]

@@ -100,6 +100,10 @@ def test_costs_against_hand_counts():
     # 128 * log2(128) = 896 comparisons and 12 * 128 band ops a row
     assert costs.pair(4, 64, 64) == {"bytes": 4 * (640 + 20),
                                      "ops": 4 * (896 + 1536)}
+    # 10 jobs of 100 samples a metric, 20 of them judged: 12 bytes a
+    # sample and 48 a row; 11 ops a history sample and 14 a judged one
+    assert costs.bivariate(10, 100, 20) == {
+        "bytes": 10 * (1200 + 48), "ops": 10 * (11 * 80 + 14 * 20)}
     pk = peaks.for_kind("TPU v5 lite")
     secs, bound = costs.least_seconds({"bytes": 819e9, "ops": 1.0}, pk)
     assert (secs, bound) == (pytest.approx(1.0), "bandwidth")
@@ -110,33 +114,99 @@ def test_costs_against_hand_counts():
 
 
 # ---------------------------------------------------------------- a tiny run
-@pytest.mark.parametrize("trace", [0, 1])
-def test_tiny_run_prints_the_contract_line(trace):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+def _cli(workload, seed, seconds, trace=0):
+    """One `--tiny` run as the driver starts it: a process of its own."""
     p = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
-         "rollout7d_polled", "--seed", "4000000007", "--seconds", "0.2",
+         workload, "--seed", str(seed), "--seconds", str(seconds),
          "--trace", str(trace), "--tiny"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+        cwd=ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-2000:]
+    return p
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+@pytest.mark.parametrize("workload", ["rollout7d_polled",
+                                      "rollout7d_2m_polled"])
+def test_tiny_run_prints_the_contract_line(workload, trace):
+    p = _cli(workload, 4000000007, 0.2, trace)
     line = json.loads(p.stdout.strip().splitlines()[-1])
     for key in ("correct", "attempted", "failed", "metrics", "device"):
         assert key in line
     assert list(line)[-1] == "compared"
     assert line["correct"] is True and line["failed"] == 0
-    assert line["attempted"] >= 2 * 48 - 6
+    assert line["attempted"] >= 2 * 24
     assert line["device"]["platform"] == "cpu"
     manifest = fleet_mod.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     if trace == 0:
         assert set(line["metrics"]) == {m["name"]
                                         for m in manifest["end_to_end"]}
     else:
+        # every reader that is not the device's reports in every cell;
         # no device metric is ever reported from a CPU run
-        by_source = {m["name"]: m["source"] for m in manifest["per_layer"]}
-        assert line["metrics"] and all(
-            by_source[n] != "device_trace" for n in line["metrics"])
+        assert set(line["metrics"]) == {
+            m["name"] for m in manifest["per_layer"]
+            if m["source"] != "device_trace"}
         assert "busy_s" not in line["device"]
     assert p.stderr.strip().splitlines()[-1].startswith("compared ")
+
+
+# what `rollout7d_polled` read at --tiny before a job's metrics and its
+# results' keys came from the configuration (PR 27's tree, a process of
+# its own, --seconds 0: two warm-up cycles and two in the window): the
+# seeded draw and every count are what they were
+_PINNED = {
+    3: {"attempted": 88, "live": 44, "pair_p_gap": 1.1170633013035669e-07,
+        "band_gap": 5.611738713581807e-05},
+    4000000007: {"attempted": 92, "live": 46,
+                 "pair_p_gap": 1.1820182332922258e-07,
+                 "band_gap": 6.159403098935036e-05},
+}
+
+
+@pytest.mark.parametrize("seed", sorted(_PINNED))
+def test_one_metric_cell_reads_what_it_read(seed):
+    out = json.loads(_cli("rollout7d_polled", seed, 0)
+                     .stdout.strip().splitlines()[-1])
+    pin = _PINNED[seed]
+    live = pin["live"]
+    assert (out["correct"], out["attempted"], out["failed"]) == (
+        True, pin["attempted"], 0)
+    assert [{k: c[k] for k in ("offered", "launches", "fetches", "rows")}
+            for c in out["cycles"]] == 2 * [{
+                "offered": live, "launches": 2, "fetches": live,
+                "rows": {"pair": live, "band": live}}]
+    assert out["compared"] == {
+        "pair_p_gap": {"value": pin["pair_p_gap"], "limit": 0.0003},
+        "band_gap": {"value": pin["band_gap"], "limit": 0.004},
+        "band_count_out": {"value": 0, "limit": 0},
+        "verdict_miss": {"value": 0, "limit": 0},
+        "stale_jobs": {"value": 0, "limit": 0},
+        "compiles_in_window": {"value": 0, "limit": 0}}
+
+
+def test_a_jobs_results_follow_its_metrics_and_families():
+    one, two = (fleet_mod.Fleet(fleet_mod.load_json(os.path.join(
+        BENCH, "configs", name + ".json")), 1, tiny=True)
+        for name in ("rollout7d", "rollout7d_2m"))
+    assert check.expected(one, 0) == [("pair", "error4xx", (0,)),
+                                      ("band", "error4xx", (0,))]
+    assert check.expected(two, 0) == [
+        ("pair", "error4xx", (0,)), ("pair", "latency", (1,)),
+        ("bivariate", "error4xx&latency", (0, 1))]
+    q = two.queries(5)
+    assert list(q) == ["error4xx", "latency"]
+    assert "&m=0&w=cur&" in q["error4xx"]["current"]
+    assert "&m=1&w=hist&" in q["latency"]["historical"]
+    assert two.points_fetched(5, two.now_slot()) \
+        == 2 * one.points_fetched(5, one.now_slot())
+    # the seeded draw does not know how many metrics a job watches
+    np.testing.assert_array_equal(one.base, two.base)
+    assert one.anomalous == two.anomalous
+    full = fleet_mod.Fleet(one.config | {"classes": [
+        dict(one.classes[0], jobs=300)]}, 1)
+    assert (full.app_name(299), two.app_name(47)) == ("app-43", "app-47")
 
 
 def test_no_accelerator_means_no_result():
@@ -150,21 +220,27 @@ def test_no_accelerator_means_no_result():
 
 # -------------------------------------------------------------- the control
 @pytest.mark.parametrize("seed", [1, 2, 4000000007])
-def test_control_in_bfloat16_is_not_correct(seed):
-    cfg = fleet_mod.load_json(os.path.join(BENCH, "configs", "rollout7d.json"))
+@pytest.mark.parametrize("config,names,failing", [
+    ("rollout7d", ["pair_p_gap", "band_gap", "band_count_out"],
+     ["band_gap", "pair_p_gap"]),
+    ("rollout7d_2m", ["pair_p_gap", "bi_bound_gap", "bi_count_out"],
+     ["bi_bound_gap", "pair_p_gap"]),
+])
+def test_control_in_bfloat16_is_not_correct(config, names, failing, seed):
+    cfg = fleet_mod.load_json(os.path.join(BENCH, "configs",
+                                           config + ".json"))
     fl = fleet_mod.Fleet(cfg, seed, tiny=True)
     jobs = [j for j in range(fl.jobs) if j not in fl.anomalous]
     k_now = fl.now_slot() + 3
     sound = check.reference_answers(fl, jobs, k_now, 5.0, "float64")
     numbers = check.compare(fl, sound)
-    assert [n for n, _, _ in numbers] == [
-        "pair_p_gap", "band_gap", "band_count_out", "verdict_miss",
-        "stale_jobs"]
+    assert [n for n, _, _ in numbers] == names + ["verdict_miss",
+                                                  "stale_jobs"]
     assert all(v <= lim for _, v, lim in numbers), numbers
     control = check.reference_answers(fl, jobs, k_now, 5.0, "bfloat16")
     numbers = {n: (v, lim) for n, v, lim in check.compare(fl, control)}
-    assert numbers["band_gap"][0] > numbers["band_gap"][1]
-    assert numbers["pair_p_gap"][0] > numbers["pair_p_gap"][1]
+    for name in failing:
+        assert numbers[name][0] > numbers[name][1], numbers
 
 
 # --------------------------------------------------------------- the faults
@@ -225,20 +301,94 @@ def _stale_newest(monkeypatch):
     monkeypatch.setattr(FleetSource, "fetch_series", short)
 
 
-@pytest.mark.parametrize("fault,failing", [
-    (None, None),
-    (_broken_answer, "band_gap"),
-    (_half_the_batch, "verdict_miss"),
-    (_pair_answer, "pair_p_gap"),
-    (_stale_newest, "stale_jobs"),
+def _one_pair_result_lost(monkeypatch):
+    """One metric's pair result dropped from the record: the rank test of
+    `latency` comes back with nothing (the job keeps its other two
+    results, so only `correct` sees it)."""
+    from foremast_tpu.engine.analyzer import Analyzer
+
+    real = Analyzer._collect_pairs
+
+    def lossy(self, state):
+        return {k: v for k, v in real(self, state).items()
+                if k[1] != "latency"}
+
+    monkeypatch.setattr(Analyzer, "_collect_pairs", lossy)
+
+
+def _metrics_swapped(monkeypatch):
+    """The two metrics swapped where the joint grid is built: each is
+    fitted, bounded and gated as the other."""
+    from foremast_tpu.engine import analyzer as an
+
+    real = an._joint_grid
+
+    def swapped(hists, curs):
+        x, m, n_h, n_c = real(hists, curs)
+        return x[::-1], m[::-1], n_h, n_c
+
+    monkeypatch.setattr(an, "_joint_grid", swapped)
+
+
+def _joint_counts(monkeypatch):
+    """The bivariate counts altered where they are produced: every job
+    has one anomalous point more."""
+    from foremast_tpu.engine.analyzer import Analyzer
+
+    real = Analyzer._collect_bivariate
+
+    def altered(self, state):
+        res = real(self, state)
+        for r in res.values():
+            r["count"] += 1
+        return res
+
+    monkeypatch.setattr(Analyzer, "_collect_bivariate", altered)
+
+
+def _stale_latency(monkeypatch):
+    """The newest `latency` sample dropped: the second metric's tails end
+    one scrape early, the first's do not."""
+    real = FleetSource.fetch_series
+
+    def short(self, url):
+        ts, vals, n = real(self, url)
+        return (ts[:-1], vals[:-1], n) if "&m=1&w=cur&" in url \
+            else (ts, vals, n)
+
+    monkeypatch.setattr(FleetSource, "fetch_series", short)
+
+
+@pytest.mark.parametrize("workload,fault,failing", [
+    ("rollout7d_polled", None, None),
+    ("rollout7d_polled", _broken_answer, "band_gap"),
+    ("rollout7d_polled", _half_the_batch, "verdict_miss"),
+    ("rollout7d_polled", _pair_answer, "pair_p_gap"),
+    ("rollout7d_polled", _stale_newest, "stale_jobs"),
+    ("rollout7d_2m_polled", None, None),
+    ("rollout7d_2m_polled", _pair_answer, "pair_p_gap"),
+    ("rollout7d_2m_polled", _one_pair_result_lost, "verdict_miss"),
+    ("rollout7d_2m_polled", _metrics_swapped, "bi_bound_gap"),
+    ("rollout7d_2m_polled", _joint_counts, "bi_count_out"),
+    ("rollout7d_2m_polled", _stale_latency, "stale_jobs"),
 ])
-def test_a_broken_timed_path_is_not_correct(monkeypatch, fault, failing):
+def test_a_broken_timed_path_is_not_correct(monkeypatch, workload, fault,
+                                            failing):
     if fault is not None:
         fault(monkeypatch)
-    out = harness.run(_args("rollout7d_polled"))
+    out = harness.run(_args(workload))
     compared = out["compared"]
     if fault is None:
         assert out["correct"] is True and out["failed"] == 0
         return
     assert out["correct"] is False
     assert compared[failing]["value"] > compared[failing]["limit"]
+
+
+@pytest.mark.parametrize("seed", [1, 2, 4000000007])
+def test_two_metric_cell_is_correct_at_tiny(seed):
+    out = harness.run(_args("rollout7d_2m_polled", seed))
+    assert out["correct"] is True and out["failed"] == 0, out["compared"]
+    live = out["cycles"][-1]["offered"]
+    assert out["cycles"][-1]["rows"] == {"pair": 2 * live, "bivariate": live}
+    assert out["cycles"][-1]["fetches"] == 2 * live
