@@ -73,6 +73,33 @@ class WatchdogTimeout(Exception):
 # stamps into job reasons) by identity, never shown to users directly
 _SHED = "__cycle_deadline_shed__"
 
+# the fetch pool's probe (_stream_prep): a cycle's first jobs are fetched
+# alone on the cycle thread until they have used this much of its CPU or
+# of the wall clock (under POOL_PROBE_MIN_CPU_S nothing was measured),
+# and a fleet under this many jobs a thread of the cap is not probed. A
+# reading wider than 1 is taken again over the next jobs and the narrower
+# holds: one preemption of the cycle thread on a host that shares its
+# cores reads as waiting (it did in 1 cycle of 63 on the v5e's host, whose
+# thread clock ticks in 10 ms steps: PERF.md section 6), two in a row do
+# not. Not env knobs: FETCH_CONCURRENCY is the operator's control, the
+# most threads a cycle may use.
+POOL_PROBE_CPU_S = 0.020
+POOL_PROBE_WALL_S = 0.040
+POOL_PROBE_MIN_CPU_S = 0.001
+POOL_PROBE_READINGS = 2
+POOL_PROBE_MIN_JOBS_PER_THREAD = 8
+
+
+def pool_width(wall: float, cpu: float, cap: int) -> int:
+    """Threads that keep one interpreter busy while the others wait on
+    the store: the probe's wall seconds over its CPU seconds, at most
+    `cap`. A probe that used under a millisecond of CPU measured nothing
+    and gets `cap`."""
+    if cpu < POOL_PROBE_MIN_CPU_S:
+        return cap
+    return min(cap, max(1, round(wall / cpu)))
+
+
 # poison-job quarantine re-admission backoff: first parking sits out
 # QUARANTINE_BASE_S, doubling per subsequent parking up to the cap. Not
 # env knobs — QUARANTINE_AFTER is the operator-facing control; the
@@ -2237,7 +2264,8 @@ class Analyzer:
         `(doc.id, None, _SHED, {})`. `pool` collects the POOL_SPANS
         seconds of those notes, summed per chunk on the pool's own
         threads (thread-seconds: no span is opened there, it would name
-        the device's long idle gap after a chunk of the pool).
+        the device's long idle gap after a chunk of the pool), and the
+        `width` the cycle chose with the `probe_ratio` it chose it from.
 
         Per-job fetches overlap on a bounded pool: fetch is network-bound
         in production (and the native parser releases the GIL during its C
@@ -2249,6 +2277,22 @@ class Analyzer:
         packing and verdict folding — stays deterministic; consuming it
         incrementally is what lets the pipeline dispatch bucket N while
         bucket N+1 is still fetching.
+
+        The pool is as wide as the source's waiting justifies, and
+        `fetch_concurrency` is the most it may be. A thread that waits on
+        a socket uses no CPU while it waits; a source that never leaves
+        the interpreter (an in-process store, ingest-served windows, the
+        delta cache's unmoved ranges) gains nothing from threads and pays
+        for every hand-over of the interpreter lock, the cycle thread's
+        routing and fingerprinting included. So every cycle of a fleet
+        large enough to convoy fetches its first jobs alone on the cycle
+        thread (POOL_PROBE_*), and `pool_width` sizes the pool from their
+        wall-to-CPU ratio (the narrower of two readings, where the first
+        says more than 1); at width 1 there is no pool and the chunks run
+        on the cycle thread. Nothing is remembered: a store that slows
+        down, or a fleet that goes from polled to pushed, is followed
+        within one cycle. Other busy Python threads in the process read as
+        waiting and widen the pool, which is what it was before the probe.
 
         `deadline` is the cycle budget (CYCLE_DEADLINE_S): once expired,
         STEADY-STATE jobs (continuous/hpa) not yet fetched yield the
@@ -2308,14 +2352,48 @@ class Analyzer:
                     pool[k] = pool.get(k, 0.0) + v
             return out
 
-        workers = min(max(self.config.fetch_concurrency, 1), len(claimed) or 1)
-        if workers <= 1:
-            yield from merged(prep_many(claimed))
-            return
-        step = max(1, -(-len(claimed) // (workers * 8)))
+        cap = min(max(self.config.fetch_concurrency, 1), len(claimed) or 1)
+        step = max(1, -(-len(claimed) // (cap * 8)))
+        width, probed = cap, 0
+        if cap > 1 and len(claimed) >= cap * POOL_PROBE_MIN_JOBS_PER_THREAD:
+            # nothing else runs yet, so this thread's wall seconds less
+            # its CPU seconds are what these jobs waited on their store
+            results, ratio = [], float("inf")
+            for _ in range(POOL_PROBE_READINGS):
+                first = probed
+                c0, t0 = time.thread_time(), time.perf_counter()
+                cpu = wall = 0.0
+                while (probed < step and cpu < POOL_PROBE_CPU_S
+                       and wall < POOL_PROBE_WALL_S):
+                    results += merged(prep_many(claimed[probed:probed + 1]))
+                    probed += 1
+                    cpu = time.thread_time() - c0
+                    wall = time.perf_counter() - t0
+                if all(items is None for _, items, _, _ in results[first:]):
+                    break  # nothing fetched (or no job left): no reading
+                width = min(width, pool_width(wall, cpu, cap))
+                if cpu >= POOL_PROBE_MIN_CPU_S:
+                    ratio = min(ratio, wall / cpu)
+                if width == 1:
+                    break
+            if pool is not None and ratio < float("inf"):
+                pool["probe_ratio"] = ratio
+            yield from results
+        if pool is not None:
+            pool["width"] = width
         chunks = [claimed[i:i + step]
                   for i in range(0, len(claimed), step)]
-        with ThreadPoolExecutor(max_workers=workers) as ex:
+        if probed:
+            # the probe's jobs come off the first chunk, so the boundaries
+            # do not depend on how far it went
+            chunks[0] = chunks[0][probed:]
+        if width <= 1:
+            # no pool: one chunk after another on this thread, each yielded
+            # as it completes, so full rungs still launch between fetches
+            for chunk in chunks:
+                yield from merged(prep_many(chunk))
+            return
+        with ThreadPoolExecutor(max_workers=width) as ex:
             for result in ex.map(prep_many, chunks):
                 yield from merged(result)
 
@@ -2324,15 +2402,16 @@ class Analyzer:
                      busy_cpu: float, pool: dict):
         """Name what interleaved per job inside engine.preprocess: the
         seconds (wait, route, route_cpu, memo_fp, triage) and the memo's
-        counts, written on the span's attrs with the pool's thread-seconds
-        and folded into the per-name stats. `busy` is the cycle thread
-        between two results of the stream; what `feed` booked under a
-        name of its own comes off, and the rest is route. `busy_cpu` is
-        the thread's CPU over the same stream; streamed fires and screens
-        come off, the fingerprint's CPU stays in (no clock reads it), so
-        `route + memo_fp - route_cpu` is the wait for the interpreter
-        lock. (With one fetch worker the preprocess itself runs on this
-        thread and is in `busy_cpu`.)"""
+        counts, written on the span's attrs with the pool's thread-seconds,
+        width and probe ratio, and folded into the per-name stats. `busy`
+        is the cycle thread between two results of the stream; what `feed`
+        booked under a name of its own comes off, and the rest is route.
+        `busy_cpu` is the thread's CPU over the same stream; streamed
+        fires and screens come off, the fingerprint's CPU stays in (no
+        clock reads it), so `route + memo_fp - route_cpu` is the wait for
+        the interpreter lock. (At pool width 1 the preprocess itself runs
+        on this thread: its wall seconds are `wait`, and its CPU is in
+        `busy_cpu`.)"""
         part = {"wait": wait, "memo_fp": 0.0, "triage": 0.0,
                 "route": busy, "route_cpu": busy_cpu}
         memo_counts = {"memo_lookups": 0, "memo_hits": 0, "memo_fp_bytes": 0}
@@ -2876,6 +2955,10 @@ class Analyzer:
             for name, secs in stages.items():
                 tracing.tracer.add_timing(tracing.STAGE_SPANS[name], secs)
             self.exporter.record_cycle_stages(stages, fam_seconds)
+            self.exporter.record_gauge(
+                "foremastbrain:fetch_pool_width", {}, pool["width"],
+                help="Fetch pool threads the last cycle used: sized from "
+                     "its probe, at most FETCH_CONCURRENCY (1 = no pool).")
             triage_cycle = None
             if triage_gate is not None and triage_gate.active:
                 tg = triage_gate
@@ -2982,6 +3065,8 @@ class Analyzer:
                 # rows / per-family launch counts (None when MEGABATCH=0)
                 "megabatch": mega_cycle,
                 "lstm_rescore_skips": self.lstm_rescore_skips - rescore_skips0,
+                # threads the fetch stream used (1: the cycle thread alone)
+                "fetch_pool_width": pool["width"],
                 # degraded-mode signals (cumulative totals live on /metrics;
                 # these are this cycle's contribution + the live park count)
                 "jobs_shed": self.jobs_shed_total - shed_cycle0,
@@ -3008,7 +3093,8 @@ class Analyzer:
         self.last_cycle_stages = {**stats, "partition": {
             "seconds": {k: round(v, 6) for k, v in seconds.items()},
             "route_cpu_seconds": round(part["route_cpu"], 6),
-            # summed over the fetch pool's threads, not wall seconds
+            # summed over the fetch pool's threads, not wall seconds;
+            # and the pool's `width` with the `probe_ratio` behind it
             "pool": {k: round(v, 6) for k, v in pool.items()},
             "counters": {**counters, **memo_counts}}}
         return outcomes

@@ -10,11 +10,14 @@ import json
 import os
 import subprocess
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
 
 from foremast_tpu.dataplane import FixtureDataSource, VerdictExporter
+from foremast_tpu.dataplane.fetch import FetchError
 from foremast_tpu.engine import (
     Analyzer,
     Document,
@@ -22,6 +25,7 @@ from foremast_tpu.engine import (
     JobStore,
     MetricQueries,
 )
+from foremast_tpu.engine import analyzer as analyzer_mod
 from foremast_tpu.engine import jobs as J
 from foremast_tpu.engine.pipeline import CompileCounter, CyclePipeline, prewarm
 from foremast_tpu.ops.windowing import Window
@@ -538,7 +542,9 @@ def test_no_span_opens_on_a_fetch_pool_thread(monkeypatch):
 
     monkeypatch.setattr(tracing.tracer, "span", recording_span)
     monkeypatch.setattr(tracing, "span", recording_span)
-    store, fixtures = _mixed_fleet(n_pair=40, n_band=8, n_bi=0, n_lstm=0,
+    # 30 jobs at a cap of 4: under the probe's threshold, so every fetch
+    # is a pool thread's
+    store, fixtures = _mixed_fleet(n_pair=24, n_band=6, n_bi=0, n_lstm=0,
                                    n_hpa=0)
     pool_threads = set()
 
@@ -560,6 +566,293 @@ def test_no_span_opens_on_a_fetch_pool_thread(monkeypatch):
                                 and name == tracing.SPAN_ENGINE_MATERIALIZE)
     assert eng.last_cycle_stages["partition"]["pool"][
         "prep_thread_seconds"] > 0
+
+
+# ------------------------------------- the fetch pool's width (ISSUE 32)
+# No case here depends on the machine's load: the rule is held to numbers
+# as a pure function, and a case that drives a cycle either patches the
+# rule's result or uses a store that sleeps (or burns CPU) on purpose.
+@pytest.mark.parametrize("wall, cpu, cap, width", [
+    (0.020, 0.020, 16, 1),      # an in-process store
+    (0.028, 0.020, 16, 1),      # and one preemption on a shared host
+    (0.032, 0.020, 16, 2),
+    (0.040, 0.040 / 13, 16, 13),
+    (0.040, 0.001, 16, 16),     # ratio 40: the cap
+    (0.040, 0.0002, 16, 16),    # no CPU to speak of: nothing measured
+    (0.0, 0.0, 4, 4),
+], ids=["1.0", "1.4", "1.6", "13", "40", "no-cpu", "nothing"])
+def test_pool_width_rule(wall, cpu, cap, width):
+    assert analyzer_mod.pool_width(wall, cpu, cap) == width
+
+
+class _ThreadSource(FixtureDataSource):
+    """Records the thread of every fetch, and the launches fired so far;
+    `sleep` seconds of waiting and `burn` seconds of CPU a fetch, or with
+    `fail` a FetchError."""
+
+    def __init__(self, fixtures, sleep=0.0, burn=0.0, fail=False):
+        super().__init__(fixtures)
+        self.sleep, self.burn, self.fail = sleep, burn, fail
+        self.seen = []  # (thread name, device launches at that moment)
+        self.eng = None
+
+    def fetch(self, url):
+        self.seen.append((threading.current_thread().name,
+                          self.eng.device_launches if self.eng else 0))
+        if self.fail:
+            raise FetchError(f"blackout: {url}")
+        if self.sleep:
+            time.sleep(self.sleep)
+        c0 = time.thread_time()
+        while time.thread_time() - c0 < self.burn:
+            pass
+        return super().fetch(url)
+
+
+def _probed_engine(monkeypatch, forced=None, fleet_kw=None, source_kw=None,
+                   **cfg_kw):
+    """(eng, store, source, stream order) over a fleet large enough to be
+    probed at a cap of 4 (42 jobs; the threshold is 32). `forced` patches
+    the rule's result: a width, or "cap"."""
+    fleet_kw = fleet_kw or dict(n_pair=30, n_band=8, n_bi=2, n_lstm=0,
+                                n_hpa=2)
+    store, fixtures = _mixed_fleet(**fleet_kw)
+    src = _ThreadSource(fixtures, **(source_kw or {}))
+    cfg_kw.setdefault("fetch_concurrency", 4)
+    eng = Analyzer(EngineConfig(pairwise_threshold=1e-4, score_batch=16,
+                                **cfg_kw), src, store)
+    src.eng = eng
+    if forced is not None:
+        monkeypatch.setattr(
+            analyzer_mod, "pool_width",
+            lambda wall, cpu, cap: cap if forced == "cap" else forced)
+    order = []
+    inner = eng._stream_prep
+
+    def recording(*a, **kw):
+        for result in inner(*a, **kw):
+            order.append((result[0], result[2]))
+            yield result
+
+    eng._stream_prep = recording
+    return eng, store, src, order
+
+
+def _records(eng, outcomes):
+    """What a cycle recorded of each job, less its clocks."""
+    out = {}
+    for job in outcomes:
+        rec = eng.provenance.get(job)
+        out[job] = {
+            **{k: rec.get(k) for k in ("path", "status", "reason", "detail",
+                                       "families")},
+            "launches": rec["cycle"]["device_launches"],
+            "fetch": {k: rec.get("fetch", {}).get(k)
+                      for k in ("points", "fetches")}}
+    return out
+
+
+def test_width_one_starts_no_pool_and_changes_nothing(monkeypatch):
+    """Forced to 1 on a probed fleet, the stream starts no thread, and
+    outcomes, provenance records and the order of results are those of
+    `fetch_concurrency=1` and of the forced cap, job for job."""
+    runs = {}
+    for name, forced, cfg in (("one", 1, {}), ("cap", "cap", {}),
+                              ("serial", None, {"fetch_concurrency": 1})):
+        with monkeypatch.context() as mp:
+            eng, store, src, order = _probed_engine(mp, forced, **cfg)
+            if name != "cap":
+                def no_pool(*a, **kw):
+                    raise AssertionError("a pool was started")
+                mp.setattr(analyzer_mod, "ThreadPoolExecutor", no_pool)
+            outcomes = eng.run_cycle(now=1000.0)
+            runs[name] = (outcomes, _records(eng, outcomes), order,
+                          _snapshot(store))
+            threads = {t for t, _ in src.seen}
+            me = threading.current_thread().name
+            if name == "cap":
+                assert len(threads - {me}) > 1
+                assert eng.last_cycle_stages["fetch_pool_width"] == 4
+            else:
+                assert threads == {me}
+                assert eng.last_cycle_stages["fetch_pool_width"] == 1
+    assert len(runs["one"][0]) == 42
+    assert runs["one"] == runs["serial"] == runs["cap"]
+
+
+def test_width_one_is_still_a_stream(monkeypatch):
+    """At width 1 the chunks are yielded one by one: a full rung is
+    launched before the last chunk is fetched."""
+    eng, _, src, _ = _probed_engine(monkeypatch, forced=1)
+    eng.run_cycle(now=1000.0)
+    launched = [n for _, n in src.seen]
+    assert launched[0] == 0 and launched[-1] >= 1
+    assert launched == sorted(launched)
+
+
+def _waiting_fleet(n, seed=5):
+    """(store, fixtures): n canaries of three windows (three queries)."""
+    rng = np.random.default_rng(seed)
+    store, fixtures = JobStore(), {}
+    for i in range(n):
+        urls = [f"u/w{i}/{role}" for role in ("c", "b", "h")]
+        for url, n in zip(urls, (30, 30, 300)):
+            fixtures[url] = _series(rng, 0.5, n)
+        store.create(Document(
+            id=f"w{i}", app_name=f"app-w{i}", namespace="px",
+            strategy="canary", start_time=to_rfc3339(0.0),
+            end_time=to_rfc3339(5_000_000.0),
+            metrics={"error5xx": MetricQueries(*urls)}))
+    return store, fixtures
+
+
+def test_source_that_waits_keeps_the_pool_at_the_cap():
+    """A store that sleeps 5 ms a query, three queries a job: each of the
+    probe's two readings ends at the wall limit within three jobs (of a
+    chunk of eight), and the pool is as wide as `fetch_concurrency`
+    allows."""
+    store, fixtures = _waiting_fleet(256)
+    src = _ThreadSource(fixtures, sleep=0.005)
+    eng = Analyzer(EngineConfig(fetch_concurrency=4), src, store)
+    outcomes = eng.run_cycle(now=1000.0)
+    assert len(outcomes) == 256
+    me = threading.current_thread().name
+    probed = sum(t == me for t, _ in src.seen) / 3
+    assert probed in (2, 3, 4, 5, 6)
+    assert len({t for t, _ in src.seen} - {me}) == 4
+    st = eng.last_cycle_stages
+    assert st["fetch_pool_width"] == st["partition"]["pool"]["width"] == 4
+    # 0.015 s of waiting a job against a few hundred microseconds of CPU
+    assert st["partition"]["pool"].get("probe_ratio", 4.0) >= 3.5
+
+
+@pytest.mark.parametrize("readings, width", [
+    ([1], 1), ([2, 1], 1), ([2, 2], 2), ([1, 2], 1), ([2, 2, 1], 2),
+], ids=["narrow", "one-preemption", "waits", "narrow-first", "two-only"])
+def test_wide_reading_is_taken_again_and_the_narrower_holds(
+        monkeypatch, readings, width):
+    """One preemption of the cycle thread reads as waiting: a reading over
+    1 is taken once more over the next jobs. The rule is scripted; the
+    store sleeps, so each reading ends at the wall limit within three jobs
+    of a chunk of eight."""
+    asked = []
+
+    def rule(wall, cpu, cap):
+        asked.append(cap)
+        return readings[len(asked) - 1]
+
+    monkeypatch.setattr(analyzer_mod, "pool_width", rule)
+    store, fixtures = _waiting_fleet(128)
+    src = _ThreadSource(fixtures, sleep=0.007)
+    eng = Analyzer(EngineConfig(fetch_concurrency=2), src, store)
+    assert len(eng.run_cycle(now=1000.0)) == 128
+    assert asked == [2] * min(len(readings), 2 if readings[0] > 1 else 1)
+    assert eng.last_cycle_stages["fetch_pool_width"] == width
+    me = threading.current_thread().name
+    probed = sum(t == me for t, _ in src.seen) / 3
+    if width == 1:
+        assert probed == 128
+    else:
+        assert 2 <= probed <= 6
+
+
+def test_probe_that_fetches_nothing_gives_the_cap(monkeypatch):
+    """Every probed job fails with FetchError (a shed probe cannot be
+    driven: the cycle's first job is exempt by class or is the floor): the
+    rule is not asked, the pool has the cap, outcomes are the serial
+    cycle's."""
+    def rule(wall, cpu, cap):
+        raise AssertionError("the rule was asked about an empty probe")
+
+    monkeypatch.setattr(analyzer_mod, "pool_width", rule)
+    blackout = dict(fail=True)
+    eng, store, src, order = _probed_engine(monkeypatch, source_kw=blackout)
+    outcomes = eng.run_cycle(now=1000.0)
+    pool = eng.last_cycle_stages["partition"]["pool"]
+    assert pool["width"] == 4 and "probe_ratio" not in pool
+    assert len({t for t, _ in src.seen}) > 1
+    ser, ser_store, _, ser_order = _probed_engine(
+        monkeypatch, source_kw=blackout, fetch_concurrency=1)
+    assert ser.run_cycle(now=1000.0) == outcomes
+    assert set(outcomes.values()) == {J.PREPROCESS_FAILED, J.INITIAL}
+    assert ser_order == order and _snapshot(ser_store) == _snapshot(store)
+
+
+def test_deadline_sheds_the_same_jobs_at_width_one_as_at_the_cap(
+        monkeypatch):
+    """tests/test_degraded.py's fleet, large enough to be probed: under a
+    spent cycle budget the canaries and the first monitor score and every
+    other monitor is shed without a fetch, at width 1 as at the cap."""
+    def run(forced):
+        rng = np.random.default_rng(7)
+        store, fixtures = JobStore(), {}
+        for i in range(40):
+            job = f"canary{i}" if i % 5 == 0 else f"watch{i}"
+            urls = [f"u/{job}/{role}" for role in ("c", "b", "h")]
+            for url, n in zip(urls, (30, 30, 600)):
+                fixtures[url] = _series(rng, 0.5, n)
+            store.create(Document(
+                id=job, app_name=f"app-{job}", namespace="deg",
+                strategy="canary" if i % 5 == 0 else "continuous",
+                start_time=to_rfc3339(0.0),
+                end_time=to_rfc3339(1e7) if i % 5 == 0 else "",
+                metrics={"error5xx": MetricQueries(*urls)}))
+        src = _ThreadSource(fixtures)
+        eng = Analyzer(EngineConfig(fetch_concurrency=4,
+                                    cycle_deadline_seconds=1e-9,
+                                    max_stuck_seconds=1e9), src, store)
+        with monkeypatch.context() as mp:
+            mp.setattr(analyzer_mod, "pool_width",
+                       lambda wall, cpu, cap: forced)
+            outcomes = eng.run_cycle(worker="w", now=100.0)
+        assert eng.last_cycle_stages["fetch_pool_width"] == forced
+        shed = sorted(j for j in outcomes if "shed" in store.get(j).reason)
+        return outcomes, shed, len(src.seen), dict(eng._shed_streak)
+
+    one, cap = run(1), run(4)
+    assert one == cap
+    # 8 canaries and the floor fetched their three windows; 31 shed
+    assert len(one[1]) == 31 and one[2] == 27
+    assert "watch1" not in one[1]
+
+
+def test_fleet_under_the_threshold_is_never_probed(monkeypatch):
+    """31 jobs at a cap of 4 (under 8 a thread): no fetch on the cycle
+    thread, the rule is not asked, the pool has the cap."""
+    def rule(wall, cpu, cap):
+        raise AssertionError("probed")
+
+    monkeypatch.setattr(analyzer_mod, "pool_width", rule)
+    eng, _, src, _ = _probed_engine(
+        monkeypatch, fleet_kw=dict(n_pair=21, n_band=8, n_bi=2, n_lstm=0,
+                                   n_hpa=0))
+    assert len(eng.run_cycle(now=1000.0)) == 31
+    assert threading.current_thread().name not in {t for t, _ in src.seen}
+    pool = eng.last_cycle_stages["partition"]["pool"]
+    assert pool["width"] == 4 and "probe_ratio" not in pool
+
+
+def test_probe_width_and_ratio_are_served(monkeypatch):
+    """What the probe chose is on the engine.preprocess span, in
+    `last_cycle_stages` and on /metrics. Every fetch burns 2 ms of CPU, so
+    the probe has CPU to divide by on any host."""
+    exporter = VerdictExporter()
+    store, fixtures = _mixed_fleet(n_pair=40, n_band=0, n_bi=0, n_lstm=0,
+                                   n_hpa=0)
+    eng = Analyzer(EngineConfig(fetch_concurrency=4),
+                   _ThreadSource(fixtures, burn=0.002), store, exporter)
+    eng.run_cycle(now=1000.0)
+    st = eng.last_cycle_stages
+    prep = next(sp for sp in _spans(_cycle_root(eng))
+                if sp["name"] == tracing.SPAN_ENGINE_PREPROCESS)["attrs"]
+    width, ratio = prep["pool_width"], prep["pool_probe_ratio"]
+    assert ratio >= 0.9  # wall over CPU of one thread
+    assert width == analyzer_mod.pool_width(ratio, 1.0, 4)
+    assert st["fetch_pool_width"] == width
+    assert st["partition"]["pool"]["width"] == width
+    assert st["partition"]["pool"]["probe_ratio"] == ratio
+    assert f"foremastbrain:fetch_pool_width {float(width)}" in \
+        exporter.render()
 
 
 # -------------------------------------------------- compile-count gates
