@@ -167,14 +167,11 @@ def run(n_jobs: int = 10_000, cycles: int = 2, window_steps: int = 128,
         store = J.JobStore(snapshot_path=os.path.join(tmp, "jobs.json"))
         for d in docs:
             store.create(d)
-        # pinned EngineConfig defaults for run-over-run comparability;
-        # SCORE_PIPELINE passes through so the driver can A/B the
-        # pipelined vs. barriered cycle on identical fleets
+        # pinned EngineConfig defaults for run-over-run comparability
         from .engine.config import _env_bool as _eb
 
         engine = Analyzer(
-            EngineConfig(score_pipeline=_eb(os.environ, "SCORE_PIPELINE",
-                                            True),
+            EngineConfig(
                          # mega-batch passthrough so the legacy mixed
                          # bench can A/B the single-dispatch path too
                          megabatch=_eb(os.environ, "MEGABATCH", False),

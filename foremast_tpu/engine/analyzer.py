@@ -477,36 +477,12 @@ class Analyzer:
         while len(table) > bound:
             table.popitem(last=False)
 
-    def _memo_key_fp(self, family: str, entry, T: int):
+    def _memo_key_fp(self, fam, entry, T: int):
         """(result_key, fingerprint, window bytes hashed) for one routed
-        accumulator entry.
-
-        The fingerprint covers everything the family's launch+collect
-        reads from the entry: every window's full identity, the policy,
-        and the T bucket (the band kernel gate is a function of T).
-        Config is deliberately absent — it is frozen for the analyzer's
-        lifetime, and the memo dies with the analyzer."""
-        if family == "pair":
-            it = entry
-            key = (it.job_id, it.metric, "pair")
-            parts = (b"pair", T, it.metric, it.baseline, it.current,
-                     it.policy)
-        elif family == "band":
-            it = entry
-            key = (it.job_id, it.metric, "band")
-            parts = (b"band", T, it.metric, it.historical, it.current,
-                     it.policy)
-        elif family == "bivariate":
-            it = entry[0]  # (item, joint-grid prep)
-            key = (it.job_id, "&".join(it.metrics), "bivariate")
-            parts = (b"bi", T, it.metrics, *it.hist, *it.cur, *it.policies)
-        else:
-            key, t, s = entry  # hpa row
-            parts = (b"hpa", T, t.metric, t.historical, t.current,
-                     t.is_increase, t.priority, t.is_absolute, t.pod_window,
-                     s.metric, s.historical, s.current, s.is_increase,
-                     s.priority, s.is_absolute)
-        return key, _fp(*parts), sum(
+        accumulator entry of family `fam` (engine/families.py says what
+        the fingerprint covers)."""
+        parts = fam.fp_parts(entry, T)
+        return fam.entry_key(entry), _fp(*parts), sum(
             p.values.nbytes + p.mask.nbytes for p in parts
             if isinstance(p, Window))
 
@@ -524,7 +500,6 @@ class Analyzer:
                 "watchdog_seconds": cfg.watchdog_seconds,
                 "fetch_cycle_deadline_seconds":
                     cfg.fetch_cycle_deadline_seconds,
-                "score_pipeline": cfg.score_pipeline,
                 "score_memo": cfg.score_memo,
                 "delta_fetch": cfg.delta_fetch,
                 "provenance": cfg.provenance,
@@ -611,8 +586,8 @@ class Analyzer:
                 help="Per-window metric fetch latency (seconds).")
 
     def _preprocess(self, doc: J.Document, now: float):
-        """Fetch all windows for a job; returns (pair, band, bi, multi, hpa)
-        item lists. Band candidates route by the configured model family and
+        """Fetch all windows for a job; returns {family name: items}. Band
+        candidates route by the configured model family and
         metric count (design.md:53-88): bivariate_normal pairs 2-metric jobs,
         lstm_autoencoder pools 3+-metric jobs; everything else (and any job
         not matching its family's metric count) scores univariate bands."""
@@ -675,7 +650,8 @@ class Analyzer:
         else:
             for name, hist, cur, policy in candidates:
                 bands.append(_BandItem(doc.id, name, hist, cur, policy))
-        return pairs, bands, bis, multis, hpas
+        return {"pair": pairs, "band": bands, "bivariate": bis,
+                "hpa": hpas, "lstm": multis}
 
     # ------------------------------------------------------------- scoring
     def _isolate(self, score_fn, items):
@@ -684,7 +660,7 @@ class Analyzer:
         Scorers batch many jobs into one device program, so one poisoned
         item would otherwise fail the whole cycle for everyone — and the
         stuck-job takeover would re-claim and re-crash it forever. On batch
-        failure, retry per JOB (not per item: _score_hpa scores a job's
+        failure, retry per JOB (not per item: hpa scores a job's
         metrics jointly — splitting them would misassign tps/sla roles) and
         report {job_id: error} for the offenders only.
         """
@@ -785,25 +761,6 @@ class Analyzer:
             "foremastbrain:watchdog_fires_total", {},
             help="device materializations timed out by the collect "
                  "watchdog (WATCHDOG_S)")
-
-    @staticmethod
-    def _newest_sample_ts(items) -> float:
-        """Newest VALID sample timestamp across a job's judged current
-        windows — the moment the job's window last ADVANCED, on the
-        data's own clock. 0.0 when nothing is judgeable."""
-        pairs, bands, bis, multis, hpas = items
-        curs = ([it.current for it in pairs]
-                + [it.current for it in bands]
-                + [w for it in bis for w in it.cur]
-                + [w for it in multis for w in it.cur]
-                + [it.current for it in hpas])
-        newest = 0.0
-        for w in curs:
-            if w is None or w.n_valid == 0:
-                continue
-            idx = int(np.flatnonzero(w.mask)[-1])
-            newest = max(newest, float(w.start + idx * w.step))
-        return newest
 
     def _observe_latency(self, st: _JobState, now: float):
         """One window-advance -> verdict detection-latency observation
@@ -1016,8 +973,8 @@ class Analyzer:
             # accumulated batch (chunked only at the memory-aware cap),
             # padded to the fine mega class instead of rung-chunked.
             # Row-wise scorers make the launch boundary verdict-neutral
-            # (the same argument the streamed-vs-barriered determinism
-            # test pins), so this changes launch count, never results.
+            # (the same argument the streamed-vs-flushed determinism
+            # tests pin), so this changes launch count, never results.
             T = max((a.shape[1] for a in arrays if a.ndim > 1),
                     default=1024)
             C = self._mega_cap(T)
@@ -1141,24 +1098,17 @@ class Analyzer:
     # Each batch family (pair, band, bivariate, hpa) is split into a
     # `_launch_*` half (pack + async device dispatch; returns an opaque
     # state tuple whose [0] is the claim-ordered entry list) and a
-    # `_collect_*` half (materialize + per-item postprocess). The
-    # synchronous `_score_*` entry points — the pre-pipeline contract, and
-    # the per-job retry path of the `_isolate` blast-radius fallback — are
-    # launch + immediate collect over the same code, so the two paths
-    # cannot drift.
+    # `_collect_*` half (materialize + per-item postprocess), with the
+    # rule that gives an item its T bucket beside them. The table in
+    # engine/families.py delegates to these by name at call time; the
+    # per-job retry and prewarm (`Family.score`) are launch + immediate
+    # collect over the same code, so the paths cannot drift.
 
     @staticmethod
     def _pair_T(it: _PairItem) -> int:
         return bucket_length(
             max(it.baseline.values.shape[0], it.current.values.shape[0])
         )
-
-    @staticmethod
-    def _by_bucket(items, key) -> dict:
-        by: dict[int, list] = {}
-        for it in items:
-            by.setdefault(key(it), []).append(it)
-        return by
 
     def _launch_pairs(self, group: list, T: int):
         cfg = self.config
@@ -1219,13 +1169,6 @@ class Analyzer:
             }
         return results
 
-    def _score_pairs(self, items: list[_PairItem]):
-        """Batch all pairwise items (bucketed by window length)."""
-        results = {}
-        for T, group in self._by_bucket(items, self._pair_T).items():
-            results.update(self._collect_pairs(self._launch_pairs(group, T)))
-        return results
-
     def _needs_period(self) -> bool:
         return self.config.algorithm.startswith(
             ("holt_winters", "seasonal_trend", "prophet")
@@ -1267,7 +1210,7 @@ class Analyzer:
         `data_steps` steers the long-window kernel gate; the band path
         passes its bucket T so the choice is a pure function of the
         compiled bucket — identical for every chunking of the same
-        bucket (streamed vs. barriered launches must agree bit-for-bit).
+        bucket (streamed and flushed launches must agree bit-for-bit).
         `period_override` carries a detected seasonal period (already
         support-gated against the series length by detect_period);
         without it the static HW_PERIOD config is clamped to the window.
@@ -1336,7 +1279,7 @@ class Analyzer:
             # real length in the chunk) would make a row's smoother choice
             # depend on its chunk-mates, so streamed launches (different
             # chunk boundaries) could flip a borderline band verdict vs.
-            # the barriered path. T is already what the program compiles
+            # a flush at stream end. T is already what the program compiles
             # on; buckets only reach 4096 when their members are >2048
             # points, where the assoc scan is the right kernel anyway.
             preds, hist_mask = self._predict(
@@ -1389,12 +1332,6 @@ class Analyzer:
                 "lower": float(np.mean(lowers[i][region_sel])),
                 "anomaly_pairs": anomaly_pairs,
             }
-        return results
-
-    def _score_bands(self, items: list[_BandItem]):
-        results = {}
-        for T, group in self._by_bucket(items, self._band_T).items():
-            results.update(self._collect_bands(self._launch_bands(group, T)))
         return results
 
     def _gate(self, checked) -> float:
@@ -1482,18 +1419,6 @@ class Analyzer:
                     ),
                 },
             }
-        return results
-
-    def _score_bivariate(self, items: list[_BiItem]):
-        """Joint 2-metric scoring: one bivariate-normal program per bucket."""
-        results = {}
-        by_bucket: dict[int, list] = {}
-        for it in items:
-            pre, T = self._bi_prep(it)
-            by_bucket.setdefault(T, []).append((it, pre))
-        for T, entries in by_bucket.items():
-            results.update(
-                self._collect_bivariate(self._launch_bivariate(entries, T)))
         return results
 
     def _lstm_model(self, F: int, unroll: int = 8):
@@ -1955,15 +1880,6 @@ class Analyzer:
             for it in (row[1], row[2])
         )
 
-    def _score_hpa(self, items: list[_HpaItem]):
-        out = {}
-        by_bucket: dict[int, list] = {}
-        for row in self._hpa_rows(items):
-            by_bucket.setdefault(self._hpa_row_T(row), []).append(row)
-        for T, bucket_rows in by_bucket.items():
-            out.update(self._collect_hpa(self._launch_hpa(bucket_rows, T)))
-        return out
-
     def _launch_hpa(self, rows, T: int):
         """Pack + launch one pack-length bucket of HPA jobs."""
 
@@ -2412,19 +2328,15 @@ class Analyzer:
         the interpreter lock. (At pool width 1 the preprocess itself runs
         on this thread: its wall seconds are `wait`, and its CPU is in
         `busy_cpu`.)"""
-        part = {"wait": wait, "memo_fp": 0.0, "triage": 0.0,
-                "route": busy, "route_cpu": busy_cpu}
-        memo_counts = {"memo_lookups": 0, "memo_hits": 0, "memo_fp_bytes": 0}
-        if pipe is not None:
-            # streamed screens and fires so far (finish() adds its own)
-            screens = pipe.triage.seconds if pipe.triage else 0.0
-            named = pipe.stage_seconds["dispatch"] + pipe.memo_seconds + screens
-            part.update(memo_fp=pipe.memo_seconds, triage=screens,
-                        route=busy - named,
-                        route_cpu=busy_cpu - pipe.fired_cpu_seconds)
-            memo_counts = {"memo_lookups": pipe.memo_lookups,
-                           "memo_hits": sum(pipe.memo_hits.values()),
-                           "memo_fp_bytes": pipe.memo_fp_bytes}
+        # streamed screens and fires so far (finish() adds its own)
+        screens = pipe.triage.seconds if pipe.triage else 0.0
+        named = pipe.stage_seconds["dispatch"] + pipe.memo_seconds + screens
+        part = {"wait": wait, "memo_fp": pipe.memo_seconds,
+                "triage": screens, "route": busy - named,
+                "route_cpu": busy_cpu - pipe.fired_cpu_seconds}
+        memo_counts = {"memo_lookups": pipe.memo_lookups,
+                       "memo_hits": sum(pipe.memo_hits.values()),
+                       "memo_fp_bytes": pipe.memo_fp_bytes}
         prep_sp.attrs.update(
             {k + "_s": round(v, 6) for k, v in part.items()},
             **memo_counts,
@@ -2443,6 +2355,7 @@ class Analyzer:
     def _run_cycle(self, worker: str, now: float,
                    cycle_dl: Deadline | None = None, job_ids=None,
                    partial: bool = False) -> dict:
+        from .families import newest_sample_ts
         from .pipeline import CyclePipeline
 
         with tracing.span(tracing.SPAN_ENGINE_CLAIM) as claim_sp:
@@ -2480,11 +2393,6 @@ class Analyzer:
         if cycle_dl is not None:
             claimed.sort(key=self._job_priority)
         states: dict[str, _JobState] = {}
-        all_pairs: list[_PairItem] = []
-        all_bands: list[_BandItem] = []
-        all_bis: list[_BiItem] = []
-        all_multis: list[_MultiItem] = []
-        all_hpas: list[_HpaItem] = []
         self._lstm_trained_this_cycle = 0
         self._lstm_budget_skipped_ids = set()
         self._lstm_memo_jobs = set()
@@ -2498,7 +2406,7 @@ class Analyzer:
         shed_cycle0 = self.jobs_shed_total
         stale_cycle0 = self.stale_verdicts_served_total
         wd_cycle0 = self.watchdog_fires_total
-        pipe = CyclePipeline(self) if self.config.score_pipeline else None
+        pipe = CyclePipeline(self)
         stages = {"preprocess": 0.0, "dispatch": 0.0, "collect": 0.0,
                   "fold": 0.0}
         # the cycle thread between two results of the fetch stream, in
@@ -2527,19 +2435,11 @@ class Analyzer:
                     # at the newest judged sample's own timestamp
                     # (engine/slo.py; _observe_latency)
                     states[doc_id].ingest_at = time.monotonic()
-                    states[doc_id].newest_ts = self._newest_sample_ts(items)
-                    pairs, bands, bis, multis, hpas = items
-                    all_pairs += pairs
-                    all_bands += bands
-                    all_bis += bis
-                    all_multis += multis
-                    all_hpas += hpas
-                    if pipe is not None:
-                        # streamed dispatch: full bucket rungs launch here,
-                        # overlapping the remaining fetches (the pipeline
-                        # accounts its own dispatch time)
-                        pipe.feed(pairs, bands, bis, multis, hpas,
-                                  strategy=states[doc_id].doc.strategy)
+                    states[doc_id].newest_ts = newest_sample_ts(items)
+                    # streamed dispatch: full bucket rungs launch here,
+                    # overlapping the remaining fetches (the pipeline
+                    # accounts its own dispatch time)
+                    pipe.feed(items, strategy=states[doc_id].doc.strategy)
                 t_wait = time.perf_counter()
                 busy += t_wait - t_got
             part, memo_counts = self._book_pieces(
@@ -2608,45 +2508,19 @@ class Analyzer:
                                          jobs=shed_ids[:16])
 
         live = {k: v for k, v in states.items() if not v.failed}
-        fam_seconds: dict[str, float] = {}
-        with tracing.span(tracing.SPAN_ENGINE_SCORE, pairs=len(all_pairs),
-                          bands=len(all_bands), bis=len(all_bis),
-                          multis=len(all_multis), hpas=len(all_hpas)) as score_sp:
-            if pipe is not None:
-                (pair_res, band_res, bi_res, multi_res, hpa_res,
-                 scoring_failed) = pipe.finish()
-                for k, v in pipe.stage_seconds.items():
-                    stages[k] += v
-                fam_seconds = pipe.family_seconds
-                # the bench's per-family decomposition reads these stats
-                # (engine.score.<fam>), span or not
-                for fam in ("pair", "band", "bivariate", "hpa"):
-                    tracing.tracer.add_timing(
-                        tracing.SCORE_SPANS[fam], fam_seconds.get(fam, 0.0))
-            else:
-                # barriered fallback (SCORE_PIPELINE=0): one child span per
-                # model family, families strictly sequential
-                def timed(fam, score_fn, items, attrs_fn=None):
-                    with tracing.span(tracing.SCORE_SPANS[fam],
-                                      n=len(items)) as sp:
-                        t0 = time.perf_counter()
-                        res = self._isolate(score_fn, items)
-                        fam_seconds[fam] = time.perf_counter() - t0
-                        if attrs_fn is not None:
-                            attrs_fn(sp)
-                        return res
-
-                pair_res, pair_bad = timed("pair", self._score_pairs, all_pairs)
-                band_res, band_bad = timed("band", self._score_bands, all_bands)
-                bi_res, bi_bad = timed("bivariate", self._score_bivariate, all_bis)
-                multi_res, multi_bad = timed(
-                    "lstm", self._score_multi, all_multis,
-                    attrs_fn=lambda sp: sp.attrs.__setitem__(
-                        "budget_skips", len(self._lstm_budget_skipped_ids)))
-                hpa_res, hpa_bad = timed("hpa", self._score_hpa, all_hpas)
-                scoring_failed = {**pair_bad, **band_bad, **bi_bad,
-                                  **multi_bad, **hpa_bad}
-                stages["collect"] += sum(fam_seconds.values())
+        table = pipe.fams
+        with tracing.span(tracing.SPAN_ENGINE_SCORE, **{
+                f.count_attr: len(pipe.items[f.name])
+                for f in table}) as score_sp:
+            results, scoring_failed = pipe.finish()
+            for k, v in pipe.stage_seconds.items():
+                stages[k] += v
+            fam_seconds = pipe.family_seconds
+            # the bench's per-family decomposition reads these stats
+            # (engine.score.<fam>), span or not
+            for f in pipe.streamed:
+                tracing.tracer.add_timing(
+                    tracing.SCORE_SPANS[f.name], fam_seconds.get(f.name, 0.0))
             self.lstm_budget_skips += len(self._lstm_budget_skipped_ids)
             # every launch of the cycle is collected by now, the streamed
             # ones too: what crossed to and from the chip, and the pack
@@ -2669,8 +2543,8 @@ class Analyzer:
             prov_on = self.provenance.enabled
             fam_entries: dict[str, list] = {}
             judged_items: dict[str, int] = {}
-            memo_job_hits = pipe.memo_job_hits if pipe is not None else {}
-            triage_gate = pipe.triage if pipe is not None else None
+            memo_job_hits = pipe.memo_job_hits
+            triage_gate = pipe.triage
             triage_job_hits = triage_gate.job_hits if triage_gate is not None \
                 else {}
             # per-result screen statistics for cleared rows, keyed by the
@@ -2706,118 +2580,42 @@ class Analyzer:
                     return scored_path, f"{n - m}/{n} fresh, {m} memo"
                 return scored_path, ""
 
-            # fold per-metric results into per-job verdicts
-            for it in all_pairs:
-                r = pair_res.get((it.job_id, it.metric, "pair"))
-                if r is None:
+            # fold per-metric results into per-job verdicts, in the table's
+            # order (it shows in a job's reason)
+            record_bounds = self.exporter.record_bounds
+            for fam in table:
+                res = results[fam.name]
+                if fam.provenance is None:
+                    # hpa results fold inside _finish_hpa; count them here
+                    # so the memo-vs-fresh classification sees them
+                    if prov_on:
+                        for job_id in res:
+                            if job_id in live:
+                                judged_items[job_id] = (
+                                    judged_items.get(job_id, 0) + 1)
                     continue
-                st = live[it.job_id]
-                st.judged_any = True
-                if prov_on:
-                    judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
-                    entry = {
-                        "family": "pair", "metric": it.metric,
-                        "min_p": round(r["min_p"], 8),
-                        "alpha": self.config.pairwise_threshold,
-                        "unhealthy": bool(r["unhealthy"])}
-                    entry.update(triage_stats.get(
-                        (it.job_id, it.metric, "pair"), {}))
-                    fam_entries.setdefault(it.job_id, []).append(entry)
-                if r["unhealthy"]:
-                    causes = []
-                    if r["pairwise_unhealthy"]:
-                        causes.append(f"pairwise rejection p={r['min_p']:.2e}")
-                    if r["band_unhealthy"]:
-                        causes.append(
-                            f"{r['band_count']} points outside the baseline band"
-                        )
-                    st.unhealthy.append((it.metric, "; ".join(causes), []))
-            for it in all_bands:
-                r = band_res.get((it.job_id, it.metric, "band"))
-                if r is None:
-                    continue
-                st = live[it.job_id]
-                st.judged_any = True
-                if prov_on:
-                    judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
-                    entry = {
-                        "family": "band", "metric": it.metric,
-                        "anomalous_points": int(r["count"]),
-                        "band": [round(r["lower"], 4), round(r["upper"], 4)],
-                        "unhealthy": bool(r["unhealthy"])}
-                    entry.update(triage_stats.get(
-                        (it.job_id, it.metric, "band"), {}))
-                    fam_entries.setdefault(it.job_id, []).append(entry)
-                self.exporter.record_bounds(
-                    st.doc.app_name, st.doc.namespace, it.metric,
-                    r["upper"], r["lower"], float(r["unhealthy"]),
-                )
-                if r["unhealthy"]:
-                    st.unhealthy.append(
-                        (
-                            it.metric,
-                            f"{r['count']} points outside "
-                            f"[{r['lower']:.4g},{r['upper']:.4g}] from ts {r['first_ts']:.0f}",
-                            r["anomaly_pairs"],
-                        )
-                    )
-            for it in all_bis:
-                r = bi_res.get((it.job_id, "&".join(it.metrics), "bivariate"))
-                if r is None:
-                    continue
-                st = live[it.job_id]
-                st.judged_any = True
-                if prov_on:
-                    judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
-                    entry = {
-                        "family": "bivariate", "metric": "&".join(it.metrics),
-                        "anomalous_points": int(r["count"]),
-                        "unhealthy": bool(r["unhealthy"])}
-                    entry.update(triage_stats.get(
-                        (it.job_id, "&".join(it.metrics), "bivariate"), {}))
-                    fam_entries.setdefault(it.job_id, []).append(entry)
-                for metric, (upper, lower) in r["bounds"].items():
-                    self.exporter.record_bounds(
-                        st.doc.app_name, st.doc.namespace, metric,
-                        upper, lower, float(r["unhealthy"]),
-                    )
-                if r["unhealthy"]:
-                    st.unhealthy.append(
-                        (
-                            "&".join(it.metrics),
-                            f"{r['count']} points outside the joint "
-                            f"bivariate-normal ellipse from ts {r['first_ts']:.0f}",
-                            r["anomaly_pairs"],
-                        )
-                    )
-            for it in all_multis:
-                r = multi_res.get((it.job_id, "+".join(it.metrics), "lstm"))
-                if r is None:
-                    continue
-                st = live[it.job_id]
-                st.judged_any = True
-                if prov_on:
-                    judged_items[it.job_id] = judged_items.get(it.job_id, 0) + 1
-                    fam_entries.setdefault(it.job_id, []).append({
-                        "family": "lstm", "metric": "+".join(it.metrics),
-                        "z": round(float(r["z"]), 4),
-                        "threshold": self.config.lstm_threshold,
-                        "unhealthy": bool(r["unhealthy"])})
-                if r["unhealthy"]:
-                    st.unhealthy.append(
-                        (
-                            "+".join(it.metrics),
-                            f"LSTM-AE reconstruction z={r['z']:.2f} exceeds "
-                            f"{self.config.lstm_threshold:.1f}",
-                            [],
-                        )
-                    )
-            if prov_on:
-                # hpa results fold inside _finish_hpa; count them here so the
-                # memo-vs-fresh classification sees them like every family
-                for job_id in hpa_res:
-                    if job_id in live:
-                        judged_items[job_id] = judged_items.get(job_id, 0) + 1
+                key_of, bounds_of = fam.key, fam.bounds
+                entry_of, cause_of = fam.provenance, fam.unhealthy
+                for it in pipe.items[fam.name]:
+                    key = key_of(it)
+                    r = res.get(key)
+                    if r is None:
+                        continue
+                    st = live[it.job_id]
+                    st.judged_any = True
+                    if prov_on:
+                        judged_items[it.job_id] = (
+                            judged_items.get(it.job_id, 0) + 1)
+                        entry = entry_of(self, it, r)
+                        entry.update(triage_stats.get(key, {}))
+                        fam_entries.setdefault(it.job_id, []).append(entry)
+                    if bounds_of is not None:
+                        for metric, upper, lower in bounds_of(it, r):
+                            record_bounds(
+                                st.doc.app_name, st.doc.namespace, metric,
+                                upper, lower, float(r["unhealthy"]))
+                    if r["unhealthy"]:
+                        st.unhealthy.append(cause_of(self, it, r))
 
             for job_id, st in live.items():
                 doc = st.doc
@@ -2861,7 +2659,7 @@ class Analyzer:
                 # scored cleanly: full quarantine reset (consecutive = 0)
                 self._quarantine.pop(job_id, None)
                 if doc.strategy == STRATEGY_HPA:
-                    res = hpa_res.get(job_id)
+                    res = results["hpa"].get(job_id)
                     outcomes[job_id] = self._finish_hpa(
                         st, res, worker, now,
                         path_info=_vpath(job_id) if prov_on else None)
@@ -3043,7 +2841,6 @@ class Analyzer:
                 "cycle_id": self.current_cycle_id,
                 "jobs": len(claimed),
                 "partial": partial,
-                "pipelined": pipe is not None,
                 "stage_seconds": {k: round(v, 6) for k, v in stages.items()},
                 "family_score_seconds": {
                     k: round(v, 6) for k, v in fam_seconds.items()},
@@ -3053,10 +2850,8 @@ class Analyzer:
                 # per-family launch counts (pipelined cycles): the dispatch-
                 # collapse observability the mega-batch A/B reads — but
                 # recorded for the rung path too, so the two are comparable
-                "family_launches": dict(pipe.family_launches)
-                if pipe is not None else {},
-                "score_memo_hits": dict(pipe.memo_hits) if pipe is not None
-                else {},
+                "family_launches": dict(pipe.family_launches),
+                "score_memo_hits": dict(pipe.memo_hits),
                 # tier-0 triage: this cycle's screened/cleared/escalated rows,
                 # escalation ratio, fused screen launches, and stage seconds
                 # (None when the gate is off or inactive)

@@ -61,7 +61,7 @@ class EngineConfig:
     # device-launch row chunk: the fleet-batched scorers (pairs, bands,
     # bivariate, hpa) split their packed batches into fixed rungs so XLA
     # compiles ONE program per (rung, T) bucket instead of re-specializing
-    # on every fleet size (analyzer._score_chunks; the LSTM path scores
+    # on every fleet size (analyzer._launch_chunks; the LSTM path scores
     # per job and has no fleet batch dimension to chunk)
     score_batch: int = 8192
     # per-job window fetches run on a bounded thread pool
@@ -69,12 +69,6 @@ class EngineConfig:
     # network-bound against the metric store, so overlap is the difference
     # between cycle time scaling with fleet size and with store latency.
     fetch_concurrency: int = 16
-    # streaming scoring pipeline (SCORE_PIPELINE; engine/pipeline.py):
-    # preprocess->dispatch overlap + async device launches collected in a
-    # final phase. Verdicts are byte-identical to the barriered path
-    # (enforced by tests/test_pipeline.py); 0 restores the full-barrier
-    # cycle for A/B or debugging.
-    score_pipeline: bool = True
     # streamed-launch fire threshold (PIPELINE_FIRE_ROWS): a family/T
     # accumulator launches as soon as it holds this many rows, overlapping
     # device execution with the remaining fetches. Clamped to
@@ -101,10 +95,10 @@ class EngineConfig:
     # hash each job's packed scorer inputs per (job, family, T-bucket) and
     # reuse the previous verdict when unchanged — the common steady-state
     # case for baseline/historical-driven families. Pipeline buckets then
-    # hold only changed rows and fire fewer, smaller programs. Effective
-    # with SCORE_PIPELINE=1 (the default); verdicts stay byte-identical
-    # (scorers are deterministic row-wise functions of the fingerprinted
-    # inputs — pinned by tests/test_delta.py's identity test).
+    # hold only changed rows and fire fewer, smaller programs. Verdicts
+    # stay byte-identical (scorers are deterministic row-wise functions of
+    # the fingerprinted inputs — pinned by tests/test_delta.py's identity
+    # test).
     score_memo: bool = True
     # tier-0 triage screen (TRIAGE; engine/triage.py + ops/triage.py):
     # before the family scorers launch, changed rows of steady-state
@@ -115,8 +109,7 @@ class EngineConfig:
     # engine/triage.py: shrunk-band dominance for the moving-average
     # band family; canary-class jobs, the hpa family, and
     # non-moving-average band algorithms always escalate) and by test
-    # (the escalation-threshold sweep in tests/test_triage.py). Effective
-    # with SCORE_PIPELINE=1 (the gate lives in the pipeline); 0 restores
+    # (the escalation-threshold sweep in tests/test_triage.py). 0 restores
     # the screen-free path exactly.
     triage: bool = True
     # robust z-band escalation guard (TRIAGE_Z): rows whose max
@@ -428,7 +421,6 @@ def from_env(env=None) -> EngineConfig:
         max_claim_per_cycle=_env_int(env, "MAX_CLAIM_PER_CYCLE", 100_000),
         score_batch=_env_int(env, "SCORE_BATCH", 8192),
         fetch_concurrency=_env_int(env, "FETCH_CONCURRENCY", 16),
-        score_pipeline=_env_bool(env, "SCORE_PIPELINE", True),
         pipeline_fire_rows=_env_int(env, "PIPELINE_FIRE_ROWS", 1024),
         delta_fetch=_env_bool(env, "DELTA_FETCH", True),
         window_cache_max=_env_int(env, "WINDOW_CACHE_MAX", 8192),

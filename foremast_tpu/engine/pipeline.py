@@ -1,10 +1,6 @@
 """Bucket-granular scoring pipeline: stream, dispatch, collect.
 
-The pre-pipeline cycle was a chain of full barriers — every job fetched
-and packed before ANY scoring started, the five model families scored
-strictly sequentially, and each chunk launch blocked on materialization
-before the next chunk was even packed. This module turns that chain into
-a pipeline at three levels:
+The cycle's one scoring path, a pipeline at three levels:
 
   1. **streaming preprocess -> dispatch** — `Analyzer._run_cycle` feeds
      each job's preprocessed items into `CyclePipeline` the moment its
@@ -16,7 +12,7 @@ a pipeline at three levels:
   2. **async dispatch** — launches go through the analyzer's
      `_launch_*` halves, which return JAX async-dispatch device values;
      nothing blocks until the final collect phase materializes them, so
-     the four batch families interleave freely on the device queue.
+     the batch families interleave freely on the device queue.
   3. **persistent compile cache + prewarm** — `enable_compile_cache`
      keeps XLA's persistent compilation cache where
      JAX_COMPILATION_CACHE_DIR says (a source checkout defaults to its
@@ -28,13 +24,14 @@ a pipeline at three levels:
 Two contracts are preserved exactly:
 
   * **deterministic folding** — accumulators fill in claim order, fire at
-    the same chunk boundaries the barriered `_score_chunks` would cut
-    (full rungs mid-stream, rung-padded partials at flush), and results
-    are keyed dicts folded in claim order, so verdicts are byte-identical
-    to the sequential path regardless of device completion order.
+    rung boundaries (full rungs mid-stream, rung-padded partials at
+    flush), and results are keyed dicts folded in claim order, so
+    verdicts do not depend on where a launch was cut or on device
+    completion order (a fire threshold at or above the fleet is the
+    barrier: nothing launches before `finish`).
   * **`_isolate` blast radius** — a launch- or collect-time failure
-    retries that group per JOB through the family's synchronous scorer;
-    only the offending jobs report errors, everyone else's results stand.
+    retries that group per JOB through the family's `score`; only the
+    offending jobs report errors, everyone else's results stand.
 """
 from __future__ import annotations
 
@@ -42,8 +39,11 @@ import logging
 import os
 import time
 from contextlib import contextmanager
+from functools import partial
 
 from ..utils import knobs, tracing
+from . import families as fam_table
+from .analyzer import WatchdogTimeout
 
 log = logging.getLogger("foremast_tpu.engine.pipeline")
 
@@ -55,18 +55,20 @@ __all__ = ["CyclePipeline", "CompileCounter", "compile_cache_dir",
 class CyclePipeline:
     """One engine cycle's streaming dispatch state. Not thread-safe by
     design: `feed` is called from the single consumer of the (ordered)
-    preprocess stream, which is what keeps launches deterministic."""
-
-    FAMILIES = ("pair", "band", "bivariate", "hpa")
+    preprocess stream, which is what keeps launches deterministic. What
+    a family is, it reads from `engine/families.py`: the table as it stands
+    when the cycle starts, in its order."""
 
     def __init__(self, analyzer):
         self.an = analyzer
+        self.fams = fam_table.FAMILIES
+        self.streamed = [f for f in self.fams if f.streams]
         # fire threshold: an accumulator launches the moment it holds a
         # full batch rung, so device execution overlaps the remaining
         # fetches. Snapped to the rung ladder (and capped at the chunk
         # size) so streamed launches hit the same compiled programs as the
         # flush; scorers are row-wise, so launch boundaries cannot change
-        # verdicts (the determinism test pins pipeline == barriered).
+        # verdicts (the determinism tests pin streamed == flushed at once).
         cap = max(16, analyzer.config.score_batch)
         fire = min(max(analyzer.config.pipeline_fire_rows, 16), cap)
         self.cap = analyzer._bucket_rows(fire)
@@ -85,10 +87,12 @@ class CyclePipeline:
         # verdicts are unchanged — only pack-time peak memory moves.
         self._mega = bool(analyzer.config.megabatch)
         self._mega_caps: dict = {}  # T -> analyzer._mega_cap(T)
-        self.acc: dict = {f: {} for f in self.FAMILIES}  # family -> T -> []
+        # every item routed, by family, in claim order: the fold walks
+        # them, and a family that does not stream is scored from them
+        self.items: dict = {f.name: [] for f in self.fams}
+        self.acc: dict = {f.name: {} for f in self.streamed}  # -> T -> []
         self.pending: list = []  # (family, entries, launch_state)
-        self.failed: list = []   # (family, entries) awaiting per-job retry
-        self.multis: list = []   # lstm items score at collect (train+cache)
+        self.failed: list = []   # (family, items) awaiting per-job retry
         self.stage_seconds = {"dispatch": 0.0, "collect": 0.0}
         self.family_seconds: dict = {}
         self.launches = 0
@@ -106,7 +110,7 @@ class CyclePipeline:
         # to the memo-off path.
         self.memo = analyzer._score_memo if analyzer.config.score_memo \
             else None
-        self.memo_results: dict = {f: {} for f in self.FAMILIES}
+        self.memo_results: dict = {f.name: {} for f in self.streamed}
         # tier-0 triage gate (TRIAGE; engine/triage.py): composes after
         # the memo check — memo skips unchanged rows, triage screens the
         # changed-but-unremarkable ones in one fused kernel and
@@ -136,18 +140,19 @@ class CyclePipeline:
         self.memo_fp_bytes = 0
         self.fired_cpu_seconds = 0.0
 
-    def _memo_check(self, family: str, entry, T: int) -> bool:
+    def _memo_check(self, fam, entry, T: int) -> bool:
         """True when this entry's verdict was served from the memo."""
         if self.memo is None:
             return False
         t0 = time.perf_counter()
         try:
-            return self._memo_lookup(family, entry, T)
+            return self._memo_lookup(fam, entry, T)
         finally:
             self.memo_seconds += time.perf_counter() - t0
 
-    def _memo_lookup(self, family: str, entry, T: int) -> bool:
-        key, fp, nbytes = self.an._memo_key_fp(family, entry, T)
+    def _memo_lookup(self, fam, entry, T: int) -> bool:
+        family = fam.name
+        key, fp, nbytes = self.an._memo_key_fp(fam, entry, T)
         self.memo_lookups += 1
         self.memo_fp_bytes += nbytes
         hit = self.memo.get((family, key))
@@ -166,9 +171,10 @@ class CyclePipeline:
         return False
 
     # ------------------------------------------------------------- feeding
-    def feed(self, pairs, bands, bis, multis, hpas, strategy: str = ""):
-        """Route one job's preprocessed items (claim order) into the
-        accumulators; launch any bucket that filled its rung.
+    def feed(self, routed: dict, strategy: str = ""):
+        """Route one job's preprocessed items (family name -> items, claim
+        order) into the accumulators; launch any bucket that filled its
+        rung. A family that does not stream only has its items kept.
 
         `strategy` is the owning job's strategy: the triage gate screens
         only steady-state (continuous/hpa-class) jobs — canary-class
@@ -182,53 +188,34 @@ class CyclePipeline:
         """
         an = self.an
         tg = self.triage
-        self.multis += multis
-        for it in pairs:
+        for fam in self.fams:
+            items = routed.get(fam.name)
+            if not items:
+                continue
+            self.items[fam.name] += items
+            route = fam.route
+            if route is None:
+                continue
             try:
-                T = an._pair_T(it)
-                if not self._memo_check("pair", it, T):
-                    if tg is not None and tg.accepts("pair", strategy):
-                        tg.add("pair", T, it, self)
-                    else:
-                        self._add("pair", T, it)
+                rows = fam.rows(an, items)
             except Exception:  # noqa: BLE001 - retried per job at collect
-                self.failed.append(("pair", [it]))
-        for it in bands:
-            try:
-                T = an._band_T(it)
-                if not self._memo_check("band", it, T):
-                    if tg is not None and tg.accepts("band", strategy):
-                        tg.add("band", T, it, self)
-                    else:
-                        self._add("band", T, it)
-            except Exception:  # noqa: BLE001
-                self.failed.append(("band", [it]))
-        for it in bis:
-            try:
-                pre, T = an._bi_prep(it)
-                if not self._memo_check("bivariate", (it, pre), T):
-                    if tg is not None and tg.accepts("bivariate", strategy):
-                        tg.add("bivariate", T, (it, pre), self)
-                    else:
-                        self._add("bivariate", T, (it, pre))
-            except Exception:  # noqa: BLE001
-                self.failed.append(("bivariate", [it]))
-        if hpas:
-            try:
-                rows = an._hpa_rows(hpas)
-            except Exception:  # noqa: BLE001
-                self.failed.append(("hpa", list(hpas)))
-                rows = []
+                self.failed.append((fam, list(items)))
+                continue
+            screened = tg is not None and tg.accepts(fam.name, strategy)
             for row in rows:
                 try:
-                    T = an._hpa_row_T(row)
-                    if not self._memo_check("hpa", row, T):
-                        self._add("hpa", T, row)
-                except Exception:  # noqa: BLE001
-                    self.failed.append(("hpa", [row]))
+                    entry, T = route(an, row)
+                    if not self._memo_check(fam, entry, T):
+                        if screened:
+                            tg.add(fam, T, entry, self)
+                        else:
+                            self._add(fam, T, entry)
+                except Exception:  # noqa: BLE001 - retried per job at collect
+                    self.failed.append((fam, fam.row_items(row)))
 
-    def _add(self, family: str, T: int, entry):
-        bucket = self.acc[family].setdefault(T, [])
+    def _add(self, fam, T: int, entry):
+        acc = self.acc[fam.name]
+        bucket = acc.setdefault(T, [])
         bucket.append(entry)
         if self._mega:
             cap = self._mega_caps.get(T)
@@ -237,27 +224,21 @@ class CyclePipeline:
         else:
             cap = self.cap
         if len(bucket) >= cap:
-            self.acc[family][T] = []
-            self._fire(family, T, bucket)
+            acc[T] = []
+            self._fire(fam, T, bucket)
 
-    def _fire(self, family: str, T: int, entries: list):
+    def _fire(self, fam, T: int, entries: list):
+        family = fam.name
         t0, c0 = time.perf_counter(), time.thread_time()
         d0 = self.an.device_launches
         # its self time is the pack; engine.launch under it is the call
         with tracing.span(tracing.SPAN_ENGINE_DISPATCH, family=family, T=T,
                           rows=len(entries)):
             try:
-                if family == "pair":
-                    st = self.an._launch_pairs(entries, T)
-                elif family == "band":
-                    st = self.an._launch_bands(entries, T)
-                elif family == "bivariate":
-                    st = self.an._launch_bivariate(entries, T)
-                else:
-                    st = self.an._launch_hpa(entries, T)
-                self.pending.append((family, entries, st))
+                self.pending.append(
+                    (fam, entries, fam.launch(self.an, entries, T)))
             except Exception:  # noqa: BLE001 - blast radius: retry per job
-                self.failed.append((family, entries))
+                self.failed.append((fam, fam.items_of(entries)))
         dt = time.perf_counter() - t0
         self.fired_cpu_seconds += time.thread_time() - c0
         self.stage_seconds["dispatch"] += dt
@@ -267,46 +248,25 @@ class CyclePipeline:
             self.family_launches.get(family, 0)
             + (self.an.device_launches - d0))
 
-    @staticmethod
-    def _entry_items(entries: list) -> list:
-        """Flatten accumulator entries back to scorer items (for the
-        per-job retry path): pair/band entries ARE items, bivariate
-        entries are (item, prep), hpa entries are (job_id, tps, sla)."""
-        items = []
-        for e in entries:
-            if hasattr(e, "job_id"):
-                items.append(e)
-            elif len(e) == 2:
-                items.append(e[0])
-            else:
-                items.append(e[1])
-                if e[2] is not e[1]:
-                    items.append(e[2])
-        return items
-
     # ----------------------------------------------------------- collecting
     def finish(self):
         """Flush partial buckets, materialize every launch, retry failures
-        per job, and score the lstm family. Returns
-        (pair_res, band_res, bi_res, multi_res, hpa_res, scoring_failed)."""
+        per job, and score the families that do not stream. Returns
+        (results, scoring_failed): family name -> {result key: result},
+        and job_id -> error for the jobs whose scoring failed."""
         an = self.an
         if self.triage is not None:
             # screen the remaining partial triage buckets FIRST: suspects
             # route into the family accumulators below and flush with
             # everyone else; cleared rows land in triage.results
             self.triage.flush(self)
-        for family in self.FAMILIES:
-            buckets, self.acc[family] = self.acc[family], {}
+        for fam in self.streamed:
+            buckets, self.acc[fam.name] = self.acc[fam.name], {}
             for T, bucket in buckets.items():
                 if bucket:
-                    self._fire(family, T, bucket)
-        results: dict = {f: {} for f in self.FAMILIES}
+                    self._fire(fam, T, bucket)
+        results: dict = {f.name: {} for f in self.fams}
         bad: dict = {}
-        collect = {"pair": an._collect_pairs, "band": an._collect_bands,
-                   "bivariate": an._collect_bivariate, "hpa": an._collect_hpa}
-        sync = {"pair": an._score_pairs, "band": an._score_bands,
-                "bivariate": an._score_bivariate, "hpa": an._score_hpa}
-        from .analyzer import WatchdogTimeout
 
         t0 = time.perf_counter()
         # Hung-launch watchdog budget: each materialization (and each
@@ -325,7 +285,8 @@ class CyclePipeline:
 
         # materialize in launch order: completion order is the device's
         # business; claim-order folding happens downstream off keyed dicts
-        for family, entries, st in self.pending:
+        for fam, entries, st in self.pending:
+            family = fam.name
             t1 = time.perf_counter()
             # its self time is the per-row Python of the family's collect;
             # engine.materialize under it is the wait and the copy back
@@ -337,18 +298,18 @@ class CyclePipeline:
                             "device wedged (2+ watchdog timeouts this "
                             "cycle); bucket skipped")
                     results[family].update(
-                        an._watchdog_call(collect[family], st))
+                        an._watchdog_call(fam.collect, an, st))
                 except Exception:  # noqa: BLE001 - deferred device error
-                    self.failed.append((family, entries))
+                    self.failed.append((fam, fam.items_of(entries)))
             dt = time.perf_counter() - t1
             self.family_seconds[family] = (
                 self.family_seconds.get(family, 0.0) + dt)
         # blast-radius fallback: a failed group retries per JOB through the
-        # family's synchronous scorer (same launch/collect code, barriered;
+        # family's `score` (same launch/collect code, at once;
         # watchdog-bounded under the same two-timeout cycle budget)
-        for family, entries in self.failed:
+        for fam, items in self.failed:
             by_job: dict[str, list] = {}
-            for it in self._entry_items(entries):
+            for it in items:
                 by_job.setdefault(it.job_id, []).append(it)
             for job_id, group in by_job.items():
                 if wedged():
@@ -357,8 +318,8 @@ class CyclePipeline:
                                    "retry skipped")
                     continue
                 try:
-                    results[family].update(
-                        an._watchdog_call(sync[family], group))
+                    results[fam.name].update(
+                        an._watchdog_call(fam.score, an, group))
                 except Exception as e:  # noqa: BLE001
                     bad[job_id] = f"{type(e).__name__}: {e}"
         if self.triage is not None:
@@ -371,27 +332,28 @@ class CyclePipeline:
         if self.memo is not None:
             # memoize every freshly scored verdict (collect + retries) for
             # the next cycle, then fold the memo-served ones back in
-            for family in self.FAMILIES:
+            for family, served in self.memo_results.items():
                 for key, res in results[family].items():
                     fp = self._fps.get((family, key))
                     if fp is not None:
                         an._memo_put(self.memo, (family, key), (fp, res))
-                results[family].update(self.memo_results[family])
-        # lstm scores here, not in the stream: training mutates the model
-        # cache under a per-cycle budget whose order must match claim order
-        with tracing.span(tracing.SCORE_SPANS["lstm"],
-                          n=len(self.multis)) as lsp:
-            t1 = time.perf_counter()
-            multi_res, multi_bad = an._isolate(an._score_multi, self.multis)
-            lsp.attrs["budget_skips"] = len(an._lstm_budget_skipped_ids)
-            self.family_seconds["lstm"] = time.perf_counter() - t1
+                results[family].update(served)
+        # a family that does not stream is scored here, over the cycle's
+        # items in claim order, under the same per-job blast radius
+        for fam in (f for f in self.fams if not f.streams):
+            items = self.items[fam.name]
+            with tracing.span(tracing.SCORE_SPANS[fam.name],
+                              n=len(items)) as sp:
+                t1 = time.perf_counter()
+                results[fam.name], fam_bad = an._isolate(
+                    partial(fam.score, an), items)
+                sp.attrs.update(fam.score_attrs(an))
+                self.family_seconds[fam.name] = time.perf_counter() - t1
+            bad.update(fam_bad)
         # collect = everything after the stream: device wait + merge +
-        # retries + the lstm family — the same work the barriered mode
-        # books under collect, so SCORE_PIPELINE A/Bs compare like stages
+        # retries + the families scored at finish
         self.stage_seconds["collect"] += time.perf_counter() - t0
-        bad.update(multi_bad)
-        return (results["pair"], results["band"], results["bivariate"],
-                multi_res, results["hpa"], bad)
+        return results, bad
 
 
 # ---------------------------------------------------------------- compiles
@@ -525,8 +487,8 @@ def prewarm(config=None,
             rungs=STANDARD_RUNGS, t_buckets=STANDARD_T_BUCKETS) -> dict:
     """Compile the (family x rung x T-bucket) scoring grid up front.
 
-    Drives the REAL production entry points — the analyzer's family
-    scorers on synthetic items — so the compiled signatures are exactly
+    Drives the REAL production entry points — each family's `score` on
+    the table's items for a rung — so the compiled signatures are exactly
     the ones steady-state cycles launch (dtype or packing drift would show
     up as a failed zero-recompile regression test, not a silent miss).
     With the persistent compile cache enabled the work is also banked for
@@ -538,8 +500,7 @@ def prewarm(config=None,
     from ..ops import hpa as hpa_ops
     from ..ops import triage as triage_ops
     from ..ops.windowing import Window, bucket_length
-    from ..parallel import fleet as fl
-    from .analyzer import Analyzer, _BandItem, _BiItem, _HpaItem
+    from .analyzer import Analyzer
     from .config import EngineConfig, from_env
     from .triage import screen_cap
 
@@ -556,6 +517,8 @@ def prewarm(config=None,
     rungs = sorted({an._bucket_rows(int(r)) for r in rungs})
     t_buckets = sorted({bucket_length(int(t)) for t in t_buckets})
     policy = cfg.policy_for("latency")
+    table = [f for f in fam_table.FAMILIES
+             if f.name in families and f.prewarm_items is not None]
 
     def win(T):
         return Window(rng.normal(10.0, 1.0, T).astype(np.float32),
@@ -599,36 +562,10 @@ def prewarm(config=None,
                             *triage_ops.triage_arg_spec(r, T),
                             cfg.ma_window)["count"])
             for r in rungs:
-                if "pair" in families:
-                    # the fused pairwise program straight at the kernel:
-                    # fleet.pair_arg_spec mirrors _launch_pairs' packing
-                    with program("pair", r, T):
-                        np.asarray(fl.score_pairs(*fl.pair_arg_spec(r, T))
-                                   ["unhealthy"])
-                if "band" in families:
-                    with program("band", r, T):
-                        an._score_bands([
-                            _BandItem(f"w{i}", "latency", win(n_h),
-                                      win(n_c), policy)
-                            for i in range(r)
-                        ])
-                if "bivariate" in families:
-                    with program("bivariate", r, T):
-                        an._score_bivariate([
-                            _BiItem(f"w{i}", ("latency", "cpu"),
-                                    (win(n_h), win(n_h)),
-                                    (win(n_c), win(n_c)), (policy, policy))
-                            for i in range(r)
-                        ])
-                if "hpa" in families:
-                    items = []
-                    for i in range(r):
-                        items.append(_HpaItem(f"w{i}", "tps", win(n_h),
-                                              win(n_c), True, 0))
-                        items.append(_HpaItem(f"w{i}", "latency", win(n_h),
-                                              win(n_c), True, 1))
-                    with program("hpa", r, T):
-                        an._score_hpa(items)
+                for fam in table:
+                    with program(fam.name, r, T):
+                        fam.score(an, fam.prewarm_items(
+                            r, n_h, n_c, win, policy))
     return {
         "families": list(families),
         "rungs": list(rungs),

@@ -62,14 +62,9 @@ import numpy as np
 from ..dataplane.promql import CONTINUOUS_STRATEGIES
 from ..ops import triage as triage_ops
 from ..ops.windowing import bucket_length
-from .analyzer import _concat_trimmed
+from . import families
 
-__all__ = ["TriageGate", "screen_cap", "SCREENABLE_FAMILIES"]
-
-# families the generic screen can represent as packed rows at all; hpa is
-# deliberately absent (see module docstring), lstm never enters the
-# accumulators in the first place
-SCREENABLE_FAMILIES = ("pair", "band", "bivariate")
+__all__ = ["TriageGate", "screen_cap"]
 
 # memory budget for one screen launch, in row-steps: the row cap scales
 # down for long T buckets so a 16k-row screen of 1k-step windows and a
@@ -91,19 +86,18 @@ class TriageGate:
     def __init__(self, analyzer):
         cfg = analyzer.config
         self.an = analyzer
-        fams = set(cfg.triage_families) & set(SCREENABLE_FAMILIES)
-        if not cfg.algorithm.startswith("moving_average"):
-            # the one-sided replica argument only covers the MA band;
-            # other forecasters' bands always take the full path
-            fams.discard("band")
-        self.families = frozenset(fams)
+        # the table says which families the screen can clear soundly under
+        # this configuration (hpa and lstm never: see module docstring)
+        self.families = frozenset(
+            f.name for f in families.FAMILIES
+            if f.name in cfg.triage_families and f.screens(cfg))
         self.z = float(cfg.triage_z)
         self.margin = float(cfg.triage_margin)
         self.min_points = int(cfg.triage_min_points)
         self.fire_rows = max(int(cfg.triage_fire_rows), 16)
         self.acc: dict[int, list] = {}        # screen T bucket -> [unit]
         self._rows_in: dict[int, int] = {}    # screen T bucket -> row count
-        self.results: dict[str, dict] = {f: {} for f in SCREENABLE_FAMILIES}
+        self.results: dict[str, dict] = {f: {} for f in self.families}
         self.stats: dict = {}                 # result key -> screen stats
         self.job_hits: dict[str, int] = {}    # job -> cleared results
         self.screened: dict[str, int] = {}    # per-family row counts
@@ -121,20 +115,23 @@ class TriageGate:
         return family in self.families and strategy in CONTINUOUS_STRATEGIES
 
     # --------------------------------------------------------------- feeding
-    def add(self, family: str, fam_T: int, entry, pipe) -> None:
+    def add(self, fam, fam_T: int, entry, pipe) -> None:
         """Route one accumulator entry into the screen; fire full buckets.
 
         Called inside `CyclePipeline.feed`'s per-item guard: a malformed
         entry raises out to the pipeline's per-job retry list, same blast
         radius as every scoring step."""
-        unit = self._unit(family, fam_T, entry)
-        T = unit["T"]
+        # one logical screen unit: 1 row (pair/band) or 2 channel rows
+        # (bivariate), in the exact packed layout the family scorer uses
+        rows = fam.screen_rows(entry)
+        T = bucket_length(rows[0][0].shape[0])
+        unit = {"fam": fam, "fam_T": fam_T, "entry": entry,
+                "key": fam.entry_key(entry), "rows": rows}
         self.acc.setdefault(T, []).append(unit)
-        self._rows_in[T] = self._rows_in.get(T, 0) + len(unit["rows"])
+        self._rows_in[T] = self._rows_in.get(T, 0) + len(rows)
         # counters are in ROWS (a bivariate unit is 2 channel rows) so the
         # exported "rows screened/cleared/escalated" totals stay honest
-        self.screened[family] = (self.screened.get(family, 0)
-                                 + len(unit["rows"]))
+        self.screened[fam.name] = self.screened.get(fam.name, 0) + len(rows)
         if self._rows_in[T] >= screen_cap(self.fire_rows, T):
             units = self.acc[T]
             self.acc[T] = []
@@ -148,31 +145,6 @@ class TriageGate:
         for T, units in buckets.items():
             if units:
                 self._fire(T, units, pipe)
-
-    def _unit(self, family: str, fam_T: int, entry) -> dict:
-        """One logical screen unit: 1 row (pair/band) or 2 channel rows
-        (bivariate), in the exact packed layout the family scorer uses.
-        `rows` entries are (values, mask, n_h, policy)."""
-        if family == "band":
-            it = entry
-            vals, mask, n_h = _concat_trimmed(it.historical, it.current)
-            rows = [(vals, mask, n_h, it.policy)]
-            key = (it.job_id, it.metric, "band")
-            T = fam_T  # _band_T buckets the same concat length
-        elif family == "pair":
-            it = entry
-            vals, mask, n_h = _concat_trimmed(it.baseline, it.current)
-            rows = [(vals, mask, n_h, it.policy)]
-            key = (it.job_id, it.metric, "pair")
-            T = bucket_length(vals.shape[0])
-        else:  # bivariate: entry is (item, joint-grid prep)
-            it, (x, m, n_h, _n_c) = entry
-            rows = [(x[0], m[0], n_h, it.policies[0]),
-                    (x[1], m[1], n_h, it.policies[1])]
-            key = (it.job_id, "&".join(it.metrics), "bivariate")
-            T = bucket_length(x.shape[1])
-        return {"family": family, "fam_T": fam_T, "entry": entry,
-                "key": key, "T": T, "rows": rows}
 
     # --------------------------------------------------------------- firing
     def _fire(self, T: int, units: list, pipe) -> None:
@@ -193,7 +165,7 @@ class TriageGate:
             for u in units:
                 u_outs = outs[i:i + len(u["rows"])]
                 i += len(u["rows"])
-                if all(self._row_clear(u["family"], o) for o in u_outs):
+                if all(self._row_clear(u["fam"], o) for o in u_outs):
                     self._clear(u, u_outs)
                 else:
                     suspects.append(u)
@@ -251,7 +223,7 @@ class TriageGate:
         return type(self.an)._rung_for(n, cap)
 
     # ------------------------------------------------------- classification
-    def _row_clear(self, family: str, o: dict) -> bool:
+    def _row_clear(self, fam, o: dict) -> bool:
         """CLEAR iff the full path provably returns healthy for this row.
 
         The load-bearing check is `shrunk_count` vs the family's verdict
@@ -267,20 +239,9 @@ class TriageGate:
         clear. The robust-z guard is escalation-only on top."""
         if int(o["n_hist"]) < self.min_points:
             return False  # too thin a floor: let the full path decide
-        shrunk = int(o["shrunk_count"])
-        checked = int(o["checked"])
-        if family == "pair":
-            # the pair kernel's internal band condemns at a fixed 0.3
-            # violation fraction (parallel/fleet.py _pair_verdict)
-            if shrunk > 0.3 * max(checked, 1):
-                return False
-        else:
-            # band/bivariate gate: count >= max(band_min_points,
-            # band_violation_fraction * checked) is unhealthy. A
-            # non-positive gate (operator forced band_min_points to 0 on
-            # an empty region) can never clear: 0 < 0 is false.
-            if not shrunk < self.an._gate(checked):
-                return False
+        if not fam.screen_clears(self.an, int(o["shrunk_count"]),
+                                 int(o["checked"])):
+            return False
         if float(o["robust_z"]) >= self.z:
             # defense-in-depth guard: suspicious, escalate. >= (not >) so
             # TRIAGE_Z=0 really does screen nothing — a constant series'
@@ -289,38 +250,15 @@ class TriageGate:
         return True
 
     def _escalate(self, u: dict, pipe) -> None:
-        self.escalated[u["family"]] = (self.escalated.get(u["family"], 0)
-                                       + len(u["rows"]))
-        pipe._add(u["family"], u["fam_T"], u["entry"])
+        fam = u["fam"]
+        self.escalated[fam.name] = (self.escalated.get(fam.name, 0)
+                                    + len(u["rows"]))
+        pipe._add(fam, u["fam_T"], u["entry"])
 
     def _clear(self, u: dict, outs: list[dict]) -> None:
-        family, key = u["family"], u["key"]
-        o = outs[0]
-        # synthesized healthy results: verdict-bearing fields (unhealthy,
-        # count vs gate, exported bounds) match the full path; sub-gate
-        # cosmetics the healthy fold never reads (first_ts/anomaly_pairs
-        # of tolerated outliers, pair p-values) are zeroed
-        if family == "pair":
-            res = {"unhealthy": False, "min_p": 1.0,
-                   "pairwise_unhealthy": False, "band_unhealthy": False,
-                   "band_count": int(o["count"])}
-        elif family == "band":
-            res = {"count": int(o["count"]), "unhealthy": False,
-                   "first_ts": -1.0,
-                   "upper": float(o["upper_mean"]),
-                   "lower": float(o["lower_mean"]),
-                   "anomaly_pairs": []}
-        else:
-            it = u["entry"][0]
-            res = {"count": 0, "unhealthy": False, "first_ts": -1.0,
-                   "anomaly_pairs": [],
-                   "bounds": {
-                       it.metrics[0]: (float(outs[0]["upper_mean"]),
-                                       float(outs[0]["lower_mean"])),
-                       it.metrics[1]: (float(outs[1]["upper_mean"]),
-                                       float(outs[1]["lower_mean"])),
-                   }}
-        self.results[family][key] = res
+        fam, key = u["fam"], u["key"]
+        # the healthy result the family's collect would have produced
+        self.results[fam.name][key] = fam.cleared_result(u["entry"], outs)
         self.stats[key] = {
             "triaged": True,
             "robust_z": round(max(float(x["robust_z"]) for x in outs), 4),
@@ -331,4 +269,5 @@ class TriageGate:
         }
         job_id = key[0]
         self.job_hits[job_id] = self.job_hits.get(job_id, 0) + 1
-        self.cleared[family] = self.cleared.get(family, 0) + len(u["rows"])
+        self.cleared[fam.name] = (self.cleared.get(fam.name, 0)
+                                  + len(u["rows"]))

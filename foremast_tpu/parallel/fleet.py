@@ -183,11 +183,10 @@ score_pairs = jax.jit(_score_rows)
 def pair_arg_spec(B: int, T: int):
     """Zeroed argument tuple matching score_pairs' PRODUCTION signature.
 
-    Mirrors analyzer._launch_pairs' packing (shapes and dtypes) so
-    engine.pipeline.prewarm can compile the (rung, T) grid without
-    synthesizing windows; the zero-recompile regression test
-    (tests/test_pipeline.py) pins this spec to the real packing — drift
-    fails CI, it cannot silently de-warm the cache.
+    Mirrors analyzer._launch_pairs' packing (shapes and dtypes) so a
+    caller (chip_smoke.py, the row-block tests) can compile or cost a
+    (rung, T) program without synthesizing windows; the transfer-counter
+    test (tests/test_pipeline.py) holds its bytes to the real packing's.
     """
     import numpy as np
 

@@ -88,9 +88,6 @@ Env surface (union of the reference services'):
                          raw fetch/archive boundaries — soak runs and the
                          demo turn chaos on without code changes
                          (docs/resilience.md for the grammar)
-  SCORE_PIPELINE         streaming preprocess->dispatch scoring pipeline
-                         (default on; 0 restores the barriered cycle —
-                         engine/pipeline.py, docs/performance.md)
   DELTA_FETCH            steady-state delta window fetch (default on):
                          re-fetch only each window's tail per cycle and
                          splice into the cached grid, byte-identical to a

@@ -255,19 +255,20 @@ def test_poison_job_quarantined_with_exponential_readmission():
     fixtures = {}
     store = JobStore()
     src = CountingSource(fixtures)
-    an = Analyzer(EngineConfig(quarantine_after=2, max_stuck_seconds=1e9,
-                               score_pipeline=False), src, store)
+    an = Analyzer(EngineConfig(quarantine_after=2, max_stuck_seconds=1e9),
+                  src, store)
     _mk_job(store, fixtures, "poison", continuous=True, rng=rng)
 
     poisoned = {"on": True}
-    orig = an._score_pairs
+    orig = an._launch_pairs
 
-    def score(items):
+    def launch(group, T):
+        # the stream's launch fails, and the per-job retry's after it
         if poisoned["on"]:
             raise RuntimeError("poisoned job")
-        return orig(items)
+        return orig(group, T)
 
-    an._score_pairs = score
+    an._launch_pairs = launch
 
     an.run_cycle(worker="w", now=100.0)   # failure 1
     assert an.quarantined_count(100.0) == 0

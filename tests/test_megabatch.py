@@ -107,21 +107,23 @@ def test_mega_accumulator_fires_at_per_T_cap():
     before _launch_chunks re-chunks, so a T-blind threshold would let a
     long-window bucket materialize multi-GB packed arrays the
     launch-time cap can no longer bound."""
+    from foremast_tpu.engine.families import family
     from foremast_tpu.engine.pipeline import CyclePipeline
 
     _, engine, _, _ = _mini(4, megabatch=True, cycles=1, mix=CONT)
     pipe = CyclePipeline(engine)
+    band = family("band")
     fired = []
     pipe._fire = lambda fam, T, entries: fired.append((T, len(entries)))
     cap = engine._mega_cap(16384)
     assert cap < max(engine.config.megabatch_max_rows, 1024)
     for i in range(cap):
-        pipe._add("band", 16384, i)
+        pipe._add(band, 16384, i)
     assert fired == [(16384, cap)]
     # a short-window bucket still accumulates past the long-window cap
     # (its own ceiling is the unscaled row budget)
     for i in range(cap):
-        pipe._add("band", 128, i)
+        pipe._add(band, 128, i)
     assert fired == [(16384, cap)]
 
 

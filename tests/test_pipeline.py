@@ -1,10 +1,13 @@
-"""Pipelined scoring cycle (engine/pipeline.py, ISSUE 2).
+"""Pipelined scoring cycle (engine/pipeline.py, ISSUE 2) over the table
+of families (engine/families.py, ISSUE 33).
 
-Covers the three tentpole contracts — byte-identical verdicts vs. the
-barriered path, streamed rung-granular dispatch, `_isolate` blast radius
-through the launch/collect split — plus the compile-count regression
-gates (zero steady-state recompiles; persistent-cache restarts) and the
-batch-rung edge cases.
+Covers the three tentpole contracts — byte-identical verdicts wherever a
+launch is cut (streamed against flushed at once), streamed rung-granular
+dispatch, `_isolate` blast radius through the launch/collect split — the
+table's seam (a family the engine has never heard of, and every family
+answering every question), plus the compile-count regression gates (zero
+steady-state recompiles; persistent-cache restarts) and the batch-rung
+edge cases.
 """
 import json
 import os
@@ -26,6 +29,7 @@ from foremast_tpu.engine import (
     MetricQueries,
 )
 from foremast_tpu.engine import analyzer as analyzer_mod
+from foremast_tpu.engine import families
 from foremast_tpu.engine import jobs as J
 from foremast_tpu.engine.pipeline import CompileCounter, CyclePipeline, prewarm
 from foremast_tpu.ops.windowing import Window
@@ -117,46 +121,74 @@ def _snapshot(store: JobStore) -> str:
     return json.dumps({"docs": docs, "hpalogs": logs}, sort_keys=True)
 
 
-def _run_fleet(score_pipeline: bool, cycles: int = 2, fleet_kw=None,
-               **cfg_kw):
+# a fire threshold (and a chunk) at or above every fleet of this file: no
+# accumulator fills, nothing launches before `finish`, which is then the
+# barrier the pipeline replaced, through the same `_launch_chunks`
+FLUSH_ONLY = dict(pipeline_fire_rows=8192, score_batch=8192)
+
+
+def _run_fleet(monkeypatch, cycles: int = 2, fleet_kw=None, **cfg_kw):
+    """(outcomes, snapshot, launches fired before `finish`) of `cycles`
+    cycles over the mixed fleet."""
     store, fixtures = _mixed_fleet(**(fleet_kw or {}))
-    cfg = EngineConfig(pairwise_threshold=1e-4, lstm_epochs=2,
-                       score_pipeline=score_pipeline, **cfg_kw)
+    cfg = EngineConfig(pairwise_threshold=1e-4, lstm_epochs=2, **cfg_kw)
     eng = Analyzer(cfg, FixtureDataSource(fixtures), store, VerdictExporter())
-    outs = [eng.run_cycle(now=1000.0 + 10 * c) for c in range(cycles)]
-    return outs, _snapshot(store), eng
+    streamed = []
+    finish = CyclePipeline.finish
+
+    def counting(pipe):
+        streamed.append(pipe.launches)
+        return finish(pipe)
+
+    with monkeypatch.context() as mp:
+        mp.setattr(CyclePipeline, "finish", counting)
+        outs = [eng.run_cycle(now=1000.0 + 10 * c) for c in range(cycles)]
+    return outs, _snapshot(store), sum(streamed)
 
 
 # ------------------------------------------------------------ determinism
-def test_pipeline_verdicts_byte_identical_to_barriered():
-    """The acceptance gate: pipeline on vs. off over an identical mixed
-    fixture fleet produces byte-identical verdict state (statuses,
-    reasons, anomaly payloads, hpalogs) and identical outcome dicts —
-    fold order is claim order regardless of device completion order."""
-    outs_p, snap_p, _ = _run_fleet(True)
-    outs_s, snap_s, _ = _run_fleet(False)
+# The reference of all three is the same fleet flushed at once (FLUSH_ONLY).
+def test_streamed_verdicts_byte_identical_to_flushed_at_once(monkeypatch):
+    """The acceptance gate: a streamed run (full rungs launch while the
+    rest is still being fetched) and the same fleet flushed at once give
+    byte-identical verdict state (statuses, reasons, anomaly payloads,
+    hpalogs) and identical outcome dicts over two cycles and all five
+    families — fold order is claim order regardless of where a launch
+    was cut and of device completion order."""
+    fleet = dict(n_pair=20, n_band=18, n_bi=4, n_lstm=2, n_hpa=3)
+    outs_p, snap_p, early = _run_fleet(monkeypatch, fleet_kw=fleet,
+                                       pipeline_fire_rows=16)
+    outs_s, snap_s, none = _run_fleet(monkeypatch, fleet_kw=fleet,
+                                      **FLUSH_ONLY)
+    assert early >= 2 and none == 0
     assert outs_p == outs_s
     assert snap_p == snap_s
 
 
-def test_pipeline_chunk_boundaries_match_barriered_rungs():
-    """A tiny score_batch forces mid-stream launches; results must still
-    match the barriered path exactly (the accumulator fires at the same
-    chunk boundaries _score_chunks would cut)."""
-    outs_p, snap_p, eng = _run_fleet(True, cycles=1, score_batch=4)
-    outs_s, snap_s, _ = _run_fleet(False, cycles=1, score_batch=4)
+def test_pipeline_chunk_boundaries_match_flushed_rungs(monkeypatch):
+    """A tiny score_batch (the chunk is the smallest rung, 16) cuts a
+    bucket into many launches, some mid-stream; results must still match
+    the one padded launch a bucket that the flush makes."""
+    fleet = dict(n_pair=40, n_band=20)
+    outs_p, snap_p, early = _run_fleet(monkeypatch, cycles=1, fleet_kw=fleet,
+                                       score_batch=4)
+    outs_s, snap_s, none = _run_fleet(monkeypatch, cycles=1, fleet_kw=fleet,
+                                      **FLUSH_ONLY)
+    assert early >= 3 and none == 0
     assert outs_p == outs_s
     assert snap_p == snap_s
 
 
-def test_pipeline_early_fire_rung_keeps_verdicts_identical():
+def test_pipeline_early_fire_rung_keeps_verdicts_identical(monkeypatch):
     """PIPELINE_FIRE_ROWS below the chunk cap launches mid-stream at
-    DIFFERENT boundaries than the barriered chunker — scorers are
+    DIFFERENT boundaries than the flush's chunker — scorers are
     row-wise, so verdicts must still be byte-identical."""
     fleet = dict(n_pair=40, n_band=20, n_bi=6, n_lstm=0, n_hpa=18)
-    outs_p, snap_p, _ = _run_fleet(True, cycles=1, fleet_kw=fleet,
-                                   pipeline_fire_rows=16)
-    outs_s, snap_s, _ = _run_fleet(False, cycles=1, fleet_kw=fleet)
+    outs_p, snap_p, early = _run_fleet(monkeypatch, cycles=1, fleet_kw=fleet,
+                                       pipeline_fire_rows=16)
+    outs_s, snap_s, none = _run_fleet(monkeypatch, cycles=1, fleet_kw=fleet,
+                                      **FLUSH_ONLY)
+    assert early >= 4 and none == 0
     assert outs_p == outs_s
     assert snap_p == snap_s
 
@@ -180,14 +212,14 @@ def test_streaming_accumulator_fires_full_rungs_early():
 
     pipe = CyclePipeline(eng)
     for i in range(40):
-        pipe.feed([item(i)], [], [], [], [])
+        pipe.feed({"pair": [item(i)]})
         # two full rungs fire during the stream, not at the end
         assert pipe.launches == (i + 1) // 16
-    pair_res, *_rest = pipe.finish()
-    assert pipe.launches == 3
-    assert len(pair_res) == 40
-    sync = eng._score_pairs([item(i) for i in range(40)])
-    assert pair_res.keys() == sync.keys()
+    results, bad = pipe.finish()
+    assert pipe.launches == 3 and not bad
+    assert len(results["pair"]) == 40
+    sync = families.family("pair").score(eng, [item(i) for i in range(40)])
+    assert results["pair"].keys() == sync.keys()
 
 
 def test_pipeline_collect_failure_retries_per_job():
@@ -232,6 +264,258 @@ def test_pipeline_poisoned_family_reports_only_bad_jobs():
                for i in range(4))
     # ...band jobs are untouched by the pair family's blast
     assert all(out[f"band{i}"] == J.INITIAL for i in range(2))
+
+
+# ------------------------------------- the table's seam (ISSUE 33)
+def _win(rng, level, n, start=0.0):
+    return Window(rng.normal(level, 0.03 * level, n).astype(np.float32),
+                  np.ones(n, bool), start)
+
+
+def test_a_sixth_family_needs_no_engine_code(monkeypatch):
+    """A family defined here and put in the table is routed, memo-checked,
+    launched, collected, retried per job after a planted launch failure
+    and folded into verdicts, reasons and records: the pipeline, the memo,
+    the retry and the fold read the table and nothing else. What it costs
+    outside `engine/families.py` is what this test supplies: a routing
+    rule (here a wrapper of `_preprocess`), the launch and collect, and
+    the span name."""
+    import dataclasses
+
+    from foremast_tpu.ops.windowing import bucket_length, pack_windows
+
+    @dataclasses.dataclass
+    class SpreadItem:
+        job_id: str
+        metric: str
+        current: Window
+        limit: float
+
+    launched = []  # rows of every launch, in order
+    planted = {"n": 1}  # launches still to fail
+
+    def key(it):
+        return (it.job_id, it.metric, "spread")
+
+    def launch(an, entries, T):
+        launched.append(len(entries))
+        if planted["n"]:
+            planted["n"] -= 1
+            raise RuntimeError("planted launch failure")
+        vals, mask = pack_windows([it.current for it in entries], pad_to=T)
+        return entries, an._launch_chunks(
+            lambda v, m: {"spread": np.where(m, v, -np.inf).max(axis=1)
+                          - np.where(m, v, np.inf).min(axis=1)},
+            [vals, mask])
+
+    def collect(an, state):
+        entries, launches = state
+        spreads = an._collect_chunks(launches)["spread"].tolist()
+        return {key(it): {"spread": sp, "unhealthy": sp > it.limit}
+                for it, sp in zip(entries, spreads)}
+
+    # unhealthy when the current window's max - min is over a limit
+    spread = families.Family(
+        name="spread", count_attr="spreads", key=key,
+        route=lambda an, it: (
+            it, bucket_length(it.current.values.shape[0])),
+        fp_parts=lambda it, T: (b"spread", T, it.metric, it.current,
+                                it.limit),
+        launch=launch, collect=collect,
+        provenance=lambda an, it, r: {
+            "family": "spread", "metric": it.metric,
+            "spread": round(r["spread"], 4), "limit": it.limit,
+            "unhealthy": bool(r["unhealthy"])},
+        unhealthy=lambda an, it, r: (
+            it.metric, f"spread {r['spread']:.2f} over {it.limit:g}", []))
+
+    monkeypatch.setattr(families, "FAMILIES", families.FAMILIES + (spread,))
+    monkeypatch.setitem(tracing.SCORE_SPANS, "spread", "engine.score.spread")
+    store, fixtures = _mixed_fleet(n_pair=6, n_band=0, n_bi=0, n_lstm=0,
+                                   n_hpa=0)
+    eng = Analyzer(EngineConfig(pairwise_threshold=1e-4),
+                   FixtureDataSource(fixtures), store)
+    preprocess = eng._preprocess
+
+    def routed_too(doc, now):
+        routed = preprocess(doc, now)
+        # pair1 is judged by the new family alone, and held to a limit
+        # its healthy window cannot keep
+        alone = doc.id == "pair1"
+        if alone:
+            routed["pair"] = []
+        routed["spread"] = [
+            SpreadItem(doc.id, name, eng._fetch_window(mq.current, now),
+                       0.0 if alone else 1.0)
+            for name, mq in doc.metrics.items()]
+        return routed
+
+    eng._preprocess = routed_too
+    out = eng.run_cycle(now=1000.0)
+    # one bucket of six failed at launch; each job was retried alone
+    assert launched == [6, 1, 1, 1, 1, 1, 1]
+    assert eng.last_cycle_stages["family_launches"]["spread"] == 0
+    score = next(sp for sp in _spans(_cycle_root(eng))
+                 if sp["name"] == tracing.SPAN_ENGINE_SCORE)["attrs"]
+    assert score["spreads"] == 6 and score["pairs"] == 5
+    # folded: a verdict from the new family alone, and one it shares with
+    # the rank test, whose cause comes first (the table's order)
+    assert out == {f"pair{i}": J.COMPLETED_UNHEALTH if i in (1, 3)
+                   else J.INITIAL for i in range(6)}
+    alone = store.get("pair1").reason
+    assert alone.startswith("anomaly detected on error5xx :: error5xx: "
+                            "spread 0.") and alone.endswith(" over 0")
+    shared = store.get("pair3").reason
+    assert shared.index("pairwise rejection") < shared.index("; error5xx: "
+                                                             "spread ")
+    assert shared.endswith(" over 1")
+    rec = eng.provenance.get("pair3")
+    assert [f["family"] for f in rec["families"]] == ["pair", "spread"]
+    assert rec["families"][1]["unhealthy"] is True
+    healthy = eng.provenance.get("pair0")["families"][1]
+    assert 0.0 < healthy.pop("spread") < 1.0
+    assert healthy == {"family": "spread", "metric": "error5xx",
+                       "limit": 1.0, "unhealthy": False}
+    # memo-checked: the same windows again launch nothing
+    eng.run_cycle(now=1000.0)
+    assert launched == [6, 1, 1, 1, 1, 1, 1]
+    assert eng.last_cycle_stages["score_memo_hits"] == {"pair": 4,
+                                                        "spread": 4}
+    assert eng.provenance.get("pair0")["path"] == "memo-hit"
+
+
+@pytest.mark.parametrize("name", ["pair", "band", "bivariate", "hpa", "lstm"])
+def test_every_family_answers_every_question_of_the_table(name):
+    """Each family of the table, asked everything the pipeline, the memo,
+    the retry, the fold, triage and prewarm ask, over a rung of the items
+    `prewarm` itself builds (lstm has none there: three-metric items
+    stand in)."""
+    from foremast_tpu.engine.analyzer import _MultiItem
+    from foremast_tpu.ops.windowing import bucket_length
+
+    rng = np.random.default_rng(8)
+    cfg = EngineConfig(lstm_epochs=1)
+    eng = Analyzer(cfg, None, JobStore())
+    fam = families.family(name)
+    assert fam.name == name and fam.count_attr
+    assert name in tracing.SCORE_SPANS
+    T, rung = 128, 16
+    n_c, n_h = T // 4, T - T // 4
+    items = fam.prewarm_items and fam.prewarm_items(
+        rung, n_h, n_c, lambda n: _win(rng, 10.0, n),
+        cfg.policy_for("latency"))
+    if name == "lstm":
+        assert not fam.streams
+        items = [_MultiItem(f"w{i}", "app/ns", ["a", "b", "c"],
+                            [_win(rng, 10.0, n_h) for _ in range(3)],
+                            [_win(rng, 10.0, n_c) for _ in range(3)])
+                 for i in range(rung)]
+    jobs = {it.job_id for it in items}
+    assert len(jobs) == rung
+    assert all(isinstance(w, Window) for w in fam.currents(items))
+    assert families.newest_sample_ts({name: items}) == (n_c - 1) * 60.0
+    results = fam.score(eng, items)
+    assert len(results) == rung and eng.device_launches >= 1
+    if fam.streams:
+        rows = fam.rows(eng, items)
+        assert len(rows) == rung
+        entry, bucket = fam.route(eng, rows[0])
+        assert bucket == T == bucket_length(bucket)
+        assert {it.job_id for it in fam.row_items(rows[0])} == {"w0"}
+        assert {it.job_id for it in fam.items_of([entry])} == {"w0"}
+        key, fp, nbytes = eng._memo_key_fp(fam, entry, bucket)
+        assert key == fam.entry_key(entry) and key in results
+        assert len(fp) == 16 and nbytes > 0
+        assert fp != eng._memo_key_fp(fam, entry, 2 * bucket)[1]
+        state = fam.launch(eng, [entry], bucket)
+        assert fam.collect(eng, state).keys() == {key}
+    if fam.provenance is not None:
+        it = items[0]
+        r = results[fam.key(it)]
+        entry_of = fam.provenance(eng, it, r)
+        assert entry_of["family"] == name
+        assert entry_of["unhealthy"] is bool(r["unhealthy"])
+        metric, cause, pairs = fam.unhealthy(eng, it, r)
+        assert metric == entry_of["metric"]
+        assert isinstance(cause, str) and isinstance(pairs, list)
+        if fam.bounds is not None:
+            bounds = list(fam.bounds(it, r))
+            assert bounds and all(
+                len(b) == 3 and b[1] >= b[2] for b in bounds)
+    else:
+        # hpa: keyed by the job, folded by Analyzer._finish_hpa
+        assert results.keys() == jobs
+    screened = fam.screens(cfg)
+    assert screened is (name in ("pair", "band", "bivariate"))
+    if screened:
+        rows = fam.screen_rows(entry)
+        assert len(rows) == (2 if name == "bivariate" else 1)
+        for vals, mask, start, policy in rows:
+            assert vals.shape == mask.shape and 0 < start < vals.shape[0]
+            assert policy is cfg.policy_for("latency")
+        assert fam.screen_clears(eng, 0, 32) is True
+        assert fam.screen_clears(eng, 32, 32) is False
+        outs = [{"count": 0, "upper_mean": 2.0, "lower_mean": 1.0}] * len(rows)
+        cleared = fam.cleared_result(entry, outs)
+        assert cleared["unhealthy"] is False
+        # what the fold reads of a scored result, it finds in a cleared one
+        assert fam.provenance(eng, items[0], {**r, **cleared})[
+            "unhealthy"] is False
+    assert not families.family("band").screens(
+        EngineConfig(algorithm="holt_winters"))
+
+
+def test_fold_order_of_a_four_family_job_shows_in_its_reason():
+    """`_preprocess` never routes one job to band, bivariate and lstm at
+    once; routed by hand, its results fold in the table's order (pair,
+    band, bivariate, lstm), which is the order of its reason and of its
+    record's families."""
+    from foremast_tpu.engine.analyzer import (
+        _BandItem,
+        _BiItem,
+        _MultiItem,
+        _PairItem,
+    )
+
+    rng = np.random.default_rng(2)
+    # every family convicts: the current windows sit at three times their
+    # history, and any reconstruction error is over the lstm's gate
+    cfg = EngineConfig(pairwise_threshold=1e-4, lstm_epochs=1,
+                       lstm_threshold=-1e9)
+    store = JobStore()
+    store.create(Document(
+        id="four", app_name="app", namespace="px", strategy="canary",
+        start_time=to_rfc3339(0.0), end_time=to_rfc3339(5_000_000.0),
+        metrics={"m": MetricQueries(current="u/c")}))
+    eng = Analyzer(cfg, FixtureDataSource({}), store)
+    policy = cfg.policy_for("latency")
+
+    def hist():
+        return _win(rng, 10.0, 300)
+
+    def cur():
+        return _win(rng, 30.0, 25, start=300 * 60.0)
+
+    routed = {
+        # given out of order: the table's order decides, not the mapping's
+        "lstm": [_MultiItem("four", "app/px", ["x", "y", "z"],
+                            [hist() for _ in range(3)],
+                            [cur() for _ in range(3)])],
+        "bivariate": [_BiItem("four", ("a", "b"), (hist(), hist()),
+                              (cur(), cur()), (policy, policy))],
+        "band": [_BandItem("four", "m_band", hist(), cur(), policy)],
+        "pair": [_PairItem("four", "m_pair", _win(rng, 10.0, 30),
+                           _win(rng, 30.0, 30), policy)],
+    }
+    eng._preprocess = lambda doc, now: routed
+    assert eng.run_cycle(now=1000.0) == {"four": J.COMPLETED_UNHEALTH}
+    reason = store.get("four").reason
+    assert reason.startswith(
+        "anomaly detected on m_pair, m_band, a&b, x+y+z :: m_pair: ")
+    at = [reason.index(f"; {m}: ") for m in ("m_band", "a&b", "x+y+z")]
+    assert at == sorted(at)
+    assert [f["family"] for f in eng.provenance.get("four")["families"]] == [
+        "pair", "band", "bivariate", "lstm"]
 
 
 # ------------------------------------------------------- batch-rung edges
@@ -307,7 +591,7 @@ def test_hpa_bucket_preserves_series_step(monkeypatch):
         A._HpaItem("j30", "latency", win(90, 0, 30), win(30, 90 * 30, 30),
                    True, 1),
     ]
-    out = eng._score_hpa(items)
+    out = families.family("hpa").score(eng, items)
     assert "j30" in out and out["j30"]["raw_score"] >= 0.0
     steps = {w.step for group in captured for w in group}
     assert steps == {30}
@@ -374,7 +658,7 @@ def test_cycle_stage_gauges_and_status_surface():
     status, payload = svc.status_summary()
     assert status == 200
     cyc = payload["cycle"]
-    assert cyc["pipelined"] is True
+    assert "pipelined" not in cyc  # there is one scoring path
     assert set(cyc["stage_seconds"]) == {"preprocess", "dispatch",
                                          "collect", "fold"}
     assert cyc["family_score_seconds"]["pair"] > 0
@@ -395,13 +679,13 @@ def _spans(span):
         yield from _spans(c)
 
 
-@pytest.mark.parametrize("pipelined", [True, False],
-                         ids=["pipelined", "barriered"])
-def test_cycle_partition_sums_to_the_cycle_span(pipelined):
-    store, fixtures = _mixed_fleet(n_pair=6, n_band=4, n_bi=2, n_lstm=0,
+@pytest.mark.parametrize("cfg_kw", [dict(pipeline_fire_rows=16), FLUSH_ONLY],
+                         ids=["streamed", "flush_only"])
+def test_cycle_partition_sums_to_the_cycle_span(cfg_kw):
+    store, fixtures = _mixed_fleet(n_pair=20, n_band=4, n_bi=2, n_lstm=0,
                                    n_hpa=2)
-    eng = Analyzer(EngineConfig(score_pipeline=pipelined),
-                   FixtureDataSource(fixtures), store)
+    eng = Analyzer(EngineConfig(**cfg_kw), FixtureDataSource(fixtures),
+                   store)
     outcomes = eng.run_cycle(now=1000.0)
     st = eng.last_cycle_stages
     part = st["partition"]
@@ -428,14 +712,15 @@ def test_cycle_partition_sums_to_the_cycle_span(pipelined):
         assert name in by_name, name
     assert by_name[tracing.SPAN_ENGINE_FOLD][0]["duration_ms"] * 1e-3 == \
         pytest.approx(part["seconds"]["fold"], abs=5e-3)
-    if pipelined:
-        dispatch = by_name[tracing.SPAN_ENGINE_DISPATCH]
-        assert sum(d["duration_ms"] for d in dispatch) * 1e-3 == \
-            pytest.approx(part["seconds"]["dispatch"], abs=5e-3)
-        assert {d["attrs"]["family"] for d in dispatch} == {
-            "pair", "band", "bivariate", "hpa"}
-        assert len(by_name[tracing.SPAN_ENGINE_COLLECT]) == len(dispatch)
-        assert part["counters"]["memo_lookups"] > 0
+    dispatch = by_name[tracing.SPAN_ENGINE_DISPATCH]
+    assert sum(d["duration_ms"] for d in dispatch) * 1e-3 == \
+        pytest.approx(part["seconds"]["dispatch"], abs=5e-3)
+    assert {d["attrs"]["family"] for d in dispatch} == {
+        "pair", "band", "bivariate", "hpa"}
+    # the pair bucket fills its 16-row rung while the rest is fetched
+    assert len(dispatch) == (5 if cfg_kw is not FLUSH_ONLY else 4)
+    assert len(by_name[tracing.SPAN_ENGINE_COLLECT]) == len(dispatch)
+    assert part["counters"]["memo_lookups"] > 0
     # the counters ride the spans: per launch, and summed on engine.score
     score = by_name[tracing.SPAN_ENGINE_SCORE][0]["attrs"]
     assert score["h2d_bytes"] == part["counters"]["h2d_bytes"] == sum(
@@ -499,7 +784,7 @@ def test_transfer_and_pack_counters_equal_hand_counts(family):
         T, lens = 32, [(30, 20), (25, 28)]
         items = [_PairItem(f"j{i}", "latency", *_two_windows(rng, b, c),
                            policy) for i, (b, c) in enumerate(lens)]
-        eng._score_pairs(items)
+        families.family(family).score(eng, items)
         spec = fl.pair_arg_spec(R, T)
         h2d = sum(a.nbytes for a in spec)
         d2h = out_bytes(fl.score_pairs, *spec)
@@ -508,7 +793,7 @@ def test_transfer_and_pack_counters_equal_hand_counts(family):
         T, lens = 512, [(300, 25), (290, 20)]
         items = [_BandItem(f"j{i}", "latency", *_two_windows(rng, h, c),
                            policy) for i, (h, c) in enumerate(lens)]
-        eng._score_bands(items)
+        families.family(family).score(eng, items)
         f32, b1, row = R * T * 4, R * T, R * 4
         # _predict (values, history mask), residual_sigma (values,
         # predictions, history mask, judged mask), band_anomalies (values,
@@ -551,6 +836,9 @@ def test_no_span_opens_on_a_fetch_pool_thread(monkeypatch):
     class Source(FixtureDataSource):
         def fetch(self, url):
             pool_threads.add(threading.current_thread().name)
+            # long enough that the executor cannot serve every chunk from
+            # its first thread on a loaded machine
+            time.sleep(0.002)
             return super().fetch(url)
 
     eng = Analyzer(EngineConfig(fetch_concurrency=4, watchdog_seconds=30.0),
@@ -881,8 +1169,8 @@ def test_steady_state_cycles_trigger_zero_recompiles():
 @pytest.mark.perf
 def test_prewarm_grid_covers_matching_cycle_shapes():
     """After prewarm of a (rung 16, T 64/512) grid, a cycle whose fleet
-    lands on those shapes compiles nothing new — this also pins
-    fleet.pair_arg_spec to the analyzer's real packing."""
+    lands on those shapes compiles nothing new: the items the table
+    builds for a rung pack as a cycle's do."""
     cfg = EngineConfig(pairwise_threshold=1e-4)
     prewarm(cfg, rungs=(16,), t_buckets=(64, 512))
     rng = np.random.default_rng(3)
@@ -912,8 +1200,7 @@ def test_prewarm_grid_covers_matching_cycle_shapes():
     assert len(out) == 8
     assert cc.compiles == 0, (
         f"cycle after prewarm compiled {cc.compiles} program(s): the "
-        "prewarm grid (or fleet.pair_arg_spec) drifted from the "
-        "production packing"
+        "prewarm grid drifted from the production packing"
     )
 
 

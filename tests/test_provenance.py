@@ -114,7 +114,7 @@ def test_scored_path_records_families_and_fetch():
 
 def test_memo_hit_path_on_unchanged_second_cycle():
     fixtures, store = {}, JobStore()
-    an = _analyzer(fixtures, store, score_memo=True, score_pipeline=True)
+    an = _analyzer(fixtures, store, score_memo=True)
     _mk_job(store, fixtures, "watch", continuous=True)
     an.run_cycle(worker="w", now=1000.0)
     assert an.provenance.get("watch")["path"] == prov.PATH_SCORED
@@ -174,14 +174,14 @@ def test_shed_carryover_path_with_streak():
 
 def test_quarantined_and_blast_radius_paths():
     fixtures, store = {}, JobStore()
-    an = _analyzer(fixtures, store, quarantine_after=1,
-                   score_pipeline=False)
+    an = _analyzer(fixtures, store, quarantine_after=1)
     _mk_job(store, fixtures, "poison", continuous=True)
 
-    def boom(items):
+    def boom(group, T):
         raise RuntimeError("poisoned")
 
-    an._score_pairs = boom
+    # the stream's launch fails, and the per-job retry's launch after it
+    an._launch_pairs = boom
     an.run_cycle(worker="w", now=1000.0)  # fails -> parked (after=1)
     rec = an.provenance.get("poison")
     assert rec["path"] == prov.PATH_BLAST_RADIUS

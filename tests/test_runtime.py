@@ -12,6 +12,7 @@ from foremast_tpu.dataplane.promql import (
     build_metric_windows,
     materialize_placeholders,
 )
+from foremast_tpu.engine import families
 from foremast_tpu.engine import jobs as J
 from foremast_tpu.engine.config import EngineConfig
 from foremast_tpu.engine.jobs import JobStore
@@ -235,7 +236,7 @@ def test_hpa_sla_metric_respects_is_increase():
         _HpaItem("j", "latency", hist, cur, is_increase=True, priority=2),
     ]
     a = Analyzer(EngineConfig(), FixtureDataSource({}), JobStore())
-    out = a._score_hpa(items)
+    out = families.family("hpa").score(a, items)
     assert out["j"]["sla_metric"] == "latency"
 
 

@@ -27,6 +27,7 @@ from foremast_tpu.engine import (
     JobStore,
     MetricQueries,
 )
+from foremast_tpu.engine import families
 from foremast_tpu.engine import provenance as prov
 from foremast_tpu.engine.triage import TriageGate, screen_cap
 from foremast_tpu.ops import triage as triage_ops
@@ -282,9 +283,9 @@ def test_triage_z_zero_escalates_constant_series():
 
     g.an = _An()
     o = {"n_hist": 100, "shrunk_count": 0, "checked": 32, "robust_z": 0.0}
-    assert g._row_clear("band", o) is False
+    assert g._row_clear(families.family("band"), o) is False
     g.z = 8.0
-    assert g._row_clear("band", o) is True
+    assert g._row_clear(families.family("band"), o) is True
 
 
 def test_screen_cap_memory_scaling():
