@@ -239,6 +239,15 @@ def _concat_ts(cur: Window, n_h: int, j: int) -> float:
     return float(cur.start + (j - n_h) * cur.step)
 
 
+def _flagged_pairs(flags_row, cur: Window, n_h: int, values) -> list:
+    """[ts, value, ...] of a row's first 50 flagged points: the one use a
+    collect has for the elements of a (B, T) output."""
+    pairs = []
+    for j in np.nonzero(flags_row)[0][:50].tolist():
+        pairs += [_concat_ts(cur, n_h, j), float(values[j])]
+    return pairs
+
+
 def _pod_count_stats(win, split_ts: float):
     """(pods_now, pods_hist) from a ready-pod-count Window, or None.
 
@@ -351,7 +360,8 @@ class Analyzer:
         # -- what crosses to and from the chip, counted where it crosses
         # (cumulative; per-cycle deltas land on the engine.score span and
         # in last_cycle_stages["partition"]): nbytes of every host array
-        # handed to a jitted program (_call) and of every device value
+        # handed to a jitted program (_call) or put on the device for a
+        # launch closure's programs (_put) and of every device value
         # brought back (_host), over the four batch families, period
         # detection and the triage screen (the lstm family's eager
         # training path is not counted); and the real samples against
@@ -927,6 +937,15 @@ class Analyzer:
             if isinstance(a, np.ndarray))
         return fn(*args, **kw)
 
+    def _put(self, *arrays) -> list:
+        """Host arrays put on the device once, counted, for a launch
+        closure whose programs share them: a device array handed on to
+        `_call` is not a transfer and is not counted again."""
+        import jax
+
+        self.h2d_bytes_total += sum(a.nbytes for a in arrays)
+        return [jax.device_put(a) for a in arrays]
+
     def _host(self, x) -> np.ndarray:
         """`np.asarray` of a device value, counted: blocks until the
         program has run and copies the result to the host."""
@@ -1052,8 +1071,7 @@ class Analyzer:
         """Synchronous launch+collect (the pre-pipeline contract)."""
         return self._collect_chunks(self._launch_chunks(fn, arrays))
 
-    def _launch_period_partitions(self, band_fn, args, xv, xm, regions,
-                                  row_elems) -> list:
+    def _launch_period_partitions(self, band_fn, args, row_elems) -> list:
         """Launch a band scorer, partitioned by detected seasonal period.
 
         The HW/seasonal-trend scan needs a STATIC period (the season buffer
@@ -1066,7 +1084,8 @@ class Analyzer:
         periods steer host-side batching), but the scoring launches stay
         async. Returns [(row_idx | None, chunk launches)].
         """
-        chosen = self._detect_periods(xv, xm, regions)
+        xv, xm, n_hist, n_total = args[:4]
+        chosen = self._detect_periods(xv, xm, n_hist, n_total)
         if chosen is None:
             return [(None, self._launch_chunks(band_fn, args,
                                                row_elems=row_elems))]
@@ -1174,7 +1193,7 @@ class Analyzer:
             ("holt_winters", "seasonal_trend", "prophet")
         )
 
-    def _detect_periods(self, xv, xm, region) -> "np.ndarray | None":
+    def _detect_periods(self, xv, xm, n_hist, n_total) -> "np.ndarray | None":
         """Per-series seasonal period for the band batch (auto-detection).
 
         Returns an int array of chosen periods, or None when the configured
@@ -1190,9 +1209,10 @@ class Analyzer:
         T = xv.shape[1]
         fallback = min(cfg.hw_period, max(T // 2, 2))
 
-        def detect_fn(xv_c, xm_c, reg_c):
+        def detect_fn(xv_c, xm_c, nh_c, n_c):
+            _, hist_mask = self._call(fc.region_masks, xm_c, nh_c, n_c)
             period, _ = self._call(
-                fc.detect_period, xv_c, xm_c & ~reg_c, cands,
+                fc.detect_period, xv_c, hist_mask, cands,
                 np.int32(fallback), np.float32(cfg.hw_min_seasonal_acf),
                 alias_margin=np.float32(cfg.hw_alias_margin),
                 contrast_margin=np.float32(cfg.hw_contrast_margin),
@@ -1201,11 +1221,13 @@ class Analyzer:
 
         # through the fixed batch rungs like every scorer: one compiled
         # detection program per (rung, T bucket), bounded launch memory
-        return self._score_chunks(detect_fn, [xv, xm, region])["period"]
+        return self._score_chunks(
+            detect_fn, [xv, xm, n_hist, n_total])["period"]
 
-    def _predict(self, xv, xm, region, data_steps: int | None = None,
+    def _predict(self, xv, hist_mask, data_steps: int | None = None,
                  period_override: int | None = None):
-        """Forecaster dispatch on config.algorithm (history-only fit).
+        """Forecaster dispatch on config.algorithm (history-only fit):
+        the (B, T) predictions, left where the program wrote them.
 
         `data_steps` steers the long-window kernel gate; the band path
         passes its bucket T so the choice is a pure function of the
@@ -1216,7 +1238,6 @@ class Analyzer:
         without it the static HW_PERIOD config is clamped to the window.
         """
         algo = self.config.algorithm
-        hist_mask = xm & ~region
         B = xv.shape[0]
         # long windows: same smoother, time-parallel (associative scan).
         # SES only — the DES associative form compounds f32 rounding on
@@ -1235,8 +1256,7 @@ class Analyzer:
         elif algo.startswith("holt_winters"):
             period = (period_override if period_override is not None
                       else min(self.config.hw_period, max(xv.shape[1] // 2, 2)))
-            fitm = hist_mask.copy()
-            fitm[:, : 2 * period] = False
+            fitm = hist_mask & (np.arange(xv.shape[1]) >= 2 * period)
             _, preds = self._call(fc.fit_holt_winters, xv, hist_mask, fitm,
                                   period)
         elif algo.startswith("seasonal_trend") or algo.startswith("prophet"):
@@ -1250,7 +1270,7 @@ class Analyzer:
         else:  # moving_average_all default
             preds = self._call(fc.moving_average_predictions, xv, hist_mask,
                                self.config.ma_window)
-        return self._host(preds), hist_mask
+        return preds
 
     @staticmethod
     def _band_T(it: _BandItem) -> int:
@@ -1263,18 +1283,20 @@ class Analyzer:
 
     def _launch_bands(self, group: list, T: int):
         concats = []
-        regions = np.zeros((len(group), T), bool)
         n_hs = []
-        for i, it in enumerate(group):
+        for it in group:
             h, c = it.historical, it.current
             vals, mask, n_h = _concat_trimmed(h, c)
             n_hs.append(n_h)
             concats.append(Window(vals, mask, h.start, h.step))
-            regions[i, n_h : vals.shape[0]] = True
         xv, xm = pack_windows(concats, pad_to=T)
+        ns = np.asarray([c.values.shape[0] for c in concats], np.int32)
 
-        def band_fn(xv_c, xm_c, reg_c, thr_c, bnd_c, mlb_c, _period=None):
-            # the long-window kernel gate is a function of the BUCKET (T),
+        def band_fn(xv_c, xm_c, nh_c, n_c, thr_c, bnd_c, mlb_c, _period=None):
+            # the chunk crosses to the device once and its three programs
+            # read it there; the judged region [n_h, n) of each row, the
+            # predictions and sigma never visit the host.
+            # The long-window kernel gate is a function of the BUCKET (T),
             # not of the rows sharing a chunk: a data-dependent gate (max
             # real length in the chunk) would make a row's smoother choice
             # depend on its chunk-mates, so streamed launches (different
@@ -1282,45 +1304,38 @@ class Analyzer:
             # a flush at stream end. T is already what the program compiles
             # on; buckets only reach 4096 when their members are >2048
             # points, where the assoc scan is the right kernel anyway.
-            preds, hist_mask = self._predict(
-                xv_c, xm_c, reg_c, T, period_override=_period)
-            sigma = self._host(self._call(
-                fc.residual_sigma, xv_c, preds, hist_mask, ~reg_c))
+            xv_d, xm_d = self._put(xv_c, xm_c)
+            region, hist_mask = self._call(fc.region_masks, xm_d, nh_c, n_c)
+            preds = self._predict(xv_d, hist_mask, T, period_override=_period)
+            sigma = self._call(
+                fc.residual_sigma, xv_d, preds, hist_mask, hist_mask)
             return self._call(
                 fc.band_anomalies,
-                xv_c, xm_c, reg_c, preds, sigma, thr_c, bnd_c, mlb_c)
+                xv_d, xm_d, region, preds, sigma, thr_c, bnd_c, mlb_c)
 
         args = [
-            xv, xm, regions,
+            xv, xm, np.asarray(n_hs, np.int32), ns,
             np.asarray([it.policy.threshold for it in group], np.float32),
             np.asarray([it.policy.bound for it in group], np.int32),
             np.asarray([it.policy.min_lower_bound for it in group], np.float32),
         ]
-        parts = self._launch_period_partitions(
-            band_fn, args, xv, xm, regions,
-            np.asarray([c.values.shape[0] for c in concats]))
-        return (group, parts, xv, regions, n_hs)
+        parts = self._launch_period_partitions(band_fn, args, ns)
+        return (group, parts, xv, n_hs)
 
     def _collect_bands(self, state) -> dict:
-        group, parts, xv, regions, n_hs = state
+        group, parts, xv, n_hs = state
         out = self._collect_period_partitions(parts, len(group))
         results = {}
-        # bulk tolist for the per-row scalar fields (see _collect_pairs);
-        # the (B, T) arrays stay numpy — they are row-sliced, not boxed
+        # bulk tolist for the per-row fields (see _collect_pairs); of the
+        # (B, T) flags only the rows that flagged a point are read
         counts = out["count"].tolist()
         firsts = out["first_index"].tolist()
-        uppers = out["upper"]
-        lowers = out["lower"]
+        uppers = out["upper"].tolist()
+        lowers = out["lower"].tolist()
         flags = out["flags"]
         checked = out["checked"].tolist()
         for i, it in enumerate(group):
             n_h = n_hs[i]
-            anomalous_idx = np.nonzero(flags[i])[0]
-            anomaly_pairs = []
-            for j in anomalous_idx[:50]:
-                anomaly_pairs += [_concat_ts(it.current, n_h, int(j)),
-                                  float(xv[i, j])]
-            region_sel = regions[i]
             first = firsts[i]
             results[(it.job_id, it.metric, "band")] = {
                 "count": counts[i],
@@ -1328,9 +1343,11 @@ class Analyzer:
                 "first_ts": (
                     _concat_ts(it.current, n_h, first) if first >= 0 else -1.0
                 ),
-                "upper": float(np.mean(uppers[i][region_sel])),
-                "lower": float(np.mean(lowers[i][region_sel])),
-                "anomaly_pairs": anomaly_pairs,
+                "upper": uppers[i],
+                "lower": lowers[i],
+                "anomaly_pairs": (
+                    _flagged_pairs(flags[i], it.current, n_h, xv[i])
+                    if counts[i] > 0 else []),
             }
         return results
 
@@ -1355,7 +1372,8 @@ class Analyzer:
         x2 = np.zeros((B, T), np.float32)
         m1 = np.zeros((B, T), bool)
         m2 = np.zeros((B, T), bool)
-        region = np.zeros((B, T), bool)
+        n_hist = np.empty(B, np.int32)
+        n_total = np.empty(B, np.int32)
         thr = np.empty(B, np.float32)
         mlb1 = np.empty(B, np.float32)
         mlb2 = np.empty(B, np.float32)
@@ -1365,7 +1383,7 @@ class Analyzer:
             n = x.shape[1]
             x1[i, :n], x2[i, :n] = x[0], x[1]
             m1[i, :n], m2[i, :n] = m[0], m[1]
-            region[i, n_h:n] = True
+            n_hist[i], n_total[i] = n_h, n
             # the pair shares one ellipse: use the stricter (smaller)
             # radius of the two metric policies
             thr[i] = min(it.policies[0].threshold, it.policies[1].threshold)
@@ -1374,49 +1392,39 @@ class Analyzer:
             bm1[i] = it.policies[0].bound
             bm2[i] = it.policies[1].bound
         launches = self._launch_chunks(bv.bivariate_normal_anomalies, [
-            x1, m1, x2, m2, region, thr, mlb1, mlb2, bm1, bm2,
-        ], donate=5, row_elems=np.asarray(
-            [2 * x.shape[1] for _, (x, _m, _n_h, _n_c) in entries]))
-        return (entries, launches, region)
+            x1, m1, x2, m2, n_hist, n_total, thr, mlb1, mlb2, bm1, bm2,
+        ], donate=4, row_elems=2 * n_total)
+        return (entries, launches)
 
     def _collect_bivariate(self, state) -> dict:
-        entries, launches, region = state
+        entries, launches = state
         out = self._collect_chunks(launches)
         results = {}
-        # bulk tolist for the per-row scalars (see _collect_pairs)
-        counts = np.asarray(out["count"]).tolist()
-        firsts = np.asarray(out["first_index"]).tolist()
-        checked = np.asarray(out["checked"]).tolist()
-        flags = np.asarray(out["flags"])
-        upper1 = np.asarray(out["upper1"])
-        lower1 = np.asarray(out["lower1"])
-        upper2 = np.asarray(out["upper2"])
-        lower2 = np.asarray(out["lower2"])
+        # as _collect_bands: lists of the per-row fields, and a row of
+        # the (B, T) flags only where it flagged a point
+        counts = out["count"].tolist()
+        firsts = out["first_index"].tolist()
+        checked = out["checked"].tolist()
+        flags = out["flags"]
+        upper1 = out["upper1"].tolist()
+        lower1 = out["lower1"].tolist()
+        upper2 = out["upper2"].tolist()
+        lower2 = out["lower2"].tolist()
         for i, (it, (x, m, n_h, n_c)) in enumerate(entries):
             cur0 = it.cur[0]
             first = firsts[i]
-            anomalous_idx = np.nonzero(flags[i])[0]
-            anomaly_pairs = []
-            for j in anomalous_idx[:50]:
-                anomaly_pairs += [_concat_ts(cur0, n_h, int(j)),
-                                  float(x[0, int(j)])]
-            sel = region[i]
             results[(it.job_id, "&".join(it.metrics), "bivariate")] = {
                 "count": counts[i],
                 "unhealthy": counts[i] >= self._gate(checked[i]),
                 "first_ts": (
                     _concat_ts(cur0, n_h, first) if first >= 0 else -1.0
                 ),
-                "anomaly_pairs": anomaly_pairs,
+                "anomaly_pairs": (
+                    _flagged_pairs(flags[i], cur0, n_h, x[0])
+                    if counts[i] > 0 else []),
                 "bounds": {
-                    it.metrics[0]: (
-                        float(np.mean(upper1[i][sel])),
-                        float(np.mean(lower1[i][sel])),
-                    ),
-                    it.metrics[1]: (
-                        float(np.mean(upper2[i][sel])),
-                        float(np.mean(lower2[i][sel])),
-                    ),
+                    it.metrics[0]: (upper1[i], lower1[i]),
+                    it.metrics[1]: (upper2[i], lower2[i]),
                 },
             }
         return results
@@ -1885,18 +1893,15 @@ class Analyzer:
 
         def build(it):
             vals, mask, n_h = _concat_trimmed(it.historical, it.current)
-            region = np.zeros(T, bool)
-            region[n_h : vals.shape[0]] = True
             # carry the series' own step: a non-default-step job must not
             # silently snap back to the 60 s DEFAULT_STEP
             return Window(vals, mask, it.historical.start,
-                          it.historical.step), region
+                          it.historical.step), n_h
 
-        tps_w, regions = zip(*[build(t) for _, t, _ in rows])
+        tps_w, n_hs = zip(*[build(t) for _, t, _ in rows])
         sla_w = [build(s)[0] for _, _, s in rows]
         tv, tm = pack_windows(list(tps_w), pad_to=T)
         sv, sm = pack_windows(list(sla_w), pad_to=T)
-        reg = np.stack(list(regions))
 
         # per-job SLA criteria (dynamic_autoscaling.md:45-56): mode from
         # ML_SLA_MODE, limit from the SLA metric's policy (sla_limit{N})
@@ -1935,18 +1940,21 @@ class Analyzer:
                 pods_now[i], pods_hist[i] = pc
                 had_pods[i] = True
 
-        def hpa_fn(tv_c, tm_c, reg_c, sv_c, sm_c, lim_c, mode_c, abs_c,
+        def hpa_fn(tv_c, tm_c, nh_c, n_c, sv_c, sm_c, lim_c, mode_c, abs_c,
                    pn_c, ph_c):
+            # as band_fn: the traffic chunk crosses once, and the judged
+            # region, the predictions and sigma stay on the device
             n = tv_c.shape[0]
-            hist_mask = tm_c & ~reg_c
-            preds = self._host(self._call(
-                fc.ses_predictions, tv_c, hist_mask,
-                np.full(n, 0.3, np.float32)))
-            sigma = self._host(self._call(
-                fc.residual_sigma, tv_c, preds, hist_mask, ~reg_c))
+            tv_d, tm_d = self._put(tv_c, tm_c)
+            region, hist_mask = self._call(fc.region_masks, tm_d, nh_c, n_c)
+            preds = self._call(
+                fc.ses_predictions, tv_d, hist_mask,
+                np.full(n, 0.3, np.float32))
+            sigma = self._call(
+                fc.residual_sigma, tv_d, preds, hist_mask, hist_mask)
             return self._call(
                 hpa_ops.hpa_scores,
-                tv_c, tm_c, reg_c, preds, sigma, sv_c, sm_c,
+                tv_d, tm_d, region, preds, sigma, sv_c, sm_c,
                 lim_c, mode_c,
                 np.full(n, self.config.threshold, np.float32),
                 np.full(n, self.config.sla_headroom_safe, np.float32),
@@ -1955,8 +1963,9 @@ class Analyzer:
 
         launches = self._launch_chunks(
             hpa_fn,
-            [tv, tm, reg, sv, sm, limits, modes, absolutes,
-             pods_now, pods_hist],
+            [tv, tm, np.asarray(n_hs, np.int32),
+             np.asarray([w.values.shape[0] for w in tps_w], np.int32),
+             sv, sm, limits, modes, absolutes, pods_now, pods_hist],
             row_elems=np.asarray(
                 [t.values.shape[0] + s.values.shape[0]
                  for t, s in zip(tps_w, sla_w)]),

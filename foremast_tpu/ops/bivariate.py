@@ -17,13 +17,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .forecast import judged_region
+
 __all__ = ["bivariate_normal_anomalies"]
 
 _F = jnp.float32
 
 
 @jax.jit
-def bivariate_normal_anomalies(x1, m1, x2, m2, region, threshold,
+def bivariate_normal_anomalies(x1, m1, x2, m2, n_hist, n_total, threshold,
                                min_lower_bound1=None, min_lower_bound2=None,
                                bound_mode1=None, bound_mode2=None):
     """Joint k-sigma-ellipse anomaly flags for a metric pair.
@@ -31,8 +33,9 @@ def bivariate_normal_anomalies(x1, m1, x2, m2, region, threshold,
     Args:
       x1, x2:    (B, T) the two metrics on a shared time grid.
       m1, m2:    (B, T) bool validity masks.
-      region:    (B, T) bool — the current window being judged; the joint
-                 Gaussian is fit on ``~region`` (history).
+      n_hist, n_total: (B,) int — slots [n_hist, n_total) of a row are
+                 the current window being judged (forecast.judged_region);
+                 the joint Gaussian is fit on the rest (history).
       threshold: (B,) Mahalanobis radius in sigmas (per-metric ML_THRESHOLD;
                  the pair uses the min — stricter — of its two policies).
       min_lower_bound1/2: (B,) optional floors for the exported marginal
@@ -45,12 +48,14 @@ def bivariate_normal_anomalies(x1, m1, x2, m2, region, threshold,
                  must not alarm the pair on "too healthy" dips.
 
     Returns dict:
-      flags (B, T) joint anomalies, d2 (B, T) squared Mahalanobis distance,
-      count/first_index/checked (B,), and marginal upper/lower bands
-      (B, T) per metric (mu_i +- threshold * sigma_i, constant over time)
-      for the foremastbrain:*_{upper,lower} export.
+      flags (B, T) joint anomalies, count/first_index/checked (B,), and
+      the marginal upper/lower bounds (B,) per metric (mu_i +- threshold *
+      sigma_i: they do not vary along T, so a row has one of each) for the
+      foremastbrain:*_{upper,lower} export. Nothing but `flags` is
+      (B, T): the squared Mahalanobis distances stay on the device.
     """
     B, T = x1.shape
+    region = judged_region(n_hist, n_total, T)
     joint = m1 & m2
     hist = joint & ~region
     w = hist.astype(_F)
@@ -92,24 +97,21 @@ def bivariate_normal_anomalies(x1, m1, x2, m2, region, threshold,
                       jnp.full((B,), -1))
     checked = jnp.sum((joint & region).astype(jnp.int32), axis=-1)
 
-    s1 = jnp.sqrt(var1)[:, None]
-    s2 = jnp.sqrt(var2)[:, None]
-    thr = threshold[:, None]
-    lo1 = mu1[:, None] - thr * s1
-    lo2 = mu2[:, None] - thr * s2
+    s1 = jnp.sqrt(var1)
+    s2 = jnp.sqrt(var2)
+    lo1 = mu1 - threshold * s1
+    lo2 = mu2 - threshold * s2
     if min_lower_bound1 is not None:
-        lo1 = jnp.maximum(lo1, min_lower_bound1[:, None])
+        lo1 = jnp.maximum(lo1, min_lower_bound1)
     if min_lower_bound2 is not None:
-        lo2 = jnp.maximum(lo2, min_lower_bound2[:, None])
-    full = x1.shape
+        lo2 = jnp.maximum(lo2, min_lower_bound2)
     return {
         "flags": flags,
-        "d2": d2,
         "count": counts,
         "first_index": first,
         "checked": checked,
-        "upper1": jnp.broadcast_to(mu1[:, None] + thr * s1, full),
-        "lower1": jnp.broadcast_to(lo1, full),
-        "upper2": jnp.broadcast_to(mu2[:, None] + thr * s2, full),
-        "lower2": jnp.broadcast_to(lo2, full),
+        "upper1": mu1 + threshold * s1,
+        "lower1": lo1,
+        "upper2": mu2 + threshold * s2,
+        "lower2": lo2,
     }

@@ -50,6 +50,8 @@ __all__ = [
     "detect_period",
     "fit_holt_winters",
     "fit_seasonal_trend",
+    "judged_region",
+    "region_masks",
     "residual_sigma",
     "band_anomalies",
 ]
@@ -474,6 +476,24 @@ def fit_seasonal_trend(x, mask, fit_mask, period: int, order: int = 3,
 # ---------------------------------------------------------------------------
 # Band + anomaly logic
 # ---------------------------------------------------------------------------
+def judged_region(n_hist, n_total, T: int):
+    """(B, T) bool, slots [n_hist, n_total) of each row: a packed row is
+    history, then the current window being judged, then padding. Traced
+    inside the program that reads it, so two (B,) int vectors cross to
+    the device instead of a (B, T) bool block."""
+    t = jnp.arange(T)
+    return (t >= n_hist[:, None]) & (t < n_total[:, None])
+
+
+@jax.jit
+def region_masks(mask, n_hist, n_total):
+    """(judged region (B, T) bool, mask & ~region: the history mask) of
+    packed rows, made on the device for the programs of a launch closure
+    that take them as arguments."""
+    region = judged_region(n_hist, n_total, mask.shape[-1])
+    return region, mask & ~region
+
+
 @jax.jit
 def residual_sigma(x, preds, mask, region_mask):
     """RMS one-step residual over region_mask & mask, per series (B,).
@@ -518,9 +538,11 @@ def band_anomalies(
                     min_lower_bound{N} override; lets error-rate metrics not
                     alarm on "too healthy").
 
-    Returns dict with upper/lower bands (B, T), anomaly flags (B, T),
-    counts (B,), first anomaly index (B,) (-1 if none), and checked point
-    counts (B,).
+    Returns dict with anomaly flags (B, T), and per row (B,): upper/lower,
+    the band curves' means over the judged region (every region slot, valid
+    or not); counts; first anomaly index (-1 if none); checked point
+    counts. Nothing but `flags` is (B, T): the band curves stay on the
+    device (what crosses to the host is what a verdict needs).
     """
     thr = threshold[:, None] * sigma[:, None]
     upper = preds + thr
@@ -537,9 +559,15 @@ def band_anomalies(
         counts > 0, jnp.argmax(flags, axis=-1), jnp.full((x.shape[0],), -1)
     )
     checked = jnp.sum((mask & region_mask).astype(jnp.int32), axis=-1)
+    n_r = jnp.maximum(jnp.sum(region_mask.astype(_F), axis=-1), 1.0)
+
+    def region_mean(curve):
+        # where, not a product with the mask: sigma may be inf
+        return jnp.sum(jnp.where(region_mask, curve, 0.0), axis=-1) / n_r
+
     return {
-        "upper": upper,
-        "lower": lower,
+        "upper": region_mean(upper),
+        "lower": region_mean(lower),
         "flags": flags,
         "count": counts,
         "first_index": first,

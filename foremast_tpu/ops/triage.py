@@ -95,8 +95,8 @@ def _screen_1d(x, mask, region, threshold, bound, min_lower_bound, margin,
     count, upper, lower = band_count(threshold)
     shrunk_count, _, _ = band_count(threshold - margin)
 
-    # region means of the band curves, matching _collect_bands' reduction
-    # (np.mean over ALL region slots) so a cleared row's exported bounds
+    # region means of the band curves, matching band_anomalies' reduction
+    # (the mean over ALL region slots) so a cleared row's exported bounds
     # agree with the full path up to fusion-order float noise
     n_r = jnp.maximum(jnp.sum(region.astype(_F)), 1.0)
     upper_mean = jnp.sum(jnp.where(region, upper, 0.0)) / n_r

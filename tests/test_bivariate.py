@@ -15,6 +15,11 @@ from foremast_tpu.utils.timeutils import to_rfc3339
 STEP = 60
 
 
+def _span(n_hist, n_total):
+    """One row's judged region [n_hist, n_total) as the kernel takes it."""
+    return np.asarray([n_hist], np.int32), np.asarray([n_total], np.int32)
+
+
 def _corr_pair(rng, n, rho=0.98, mu=(10.0, 5.0), scale=(1.0, 0.5)):
     z1 = rng.normal(size=n)
     z2 = rho * z1 + np.sqrt(1 - rho**2) * rng.normal(size=n)
@@ -35,10 +40,8 @@ def test_joint_anomaly_invisible_to_marginals():
     x1 = np.concatenate([x1h, x1c])[None].astype(np.float32)
     x2 = np.concatenate([x2h, x2c])[None].astype(np.float32)
     m = np.ones_like(x1, bool)
-    region = np.zeros_like(m)
-    region[:, n_h:] = True
     out = bivariate_normal_anomalies(
-        x1, m, x2, m, region, np.asarray([3.0], np.float32)
+        x1, m, x2, m, *_span(n_h, n_h + n_c), np.asarray([3.0], np.float32)
     )
     assert int(out["count"][0]) >= 5
     # marginal check: most current x1 points are inside mean +- 3 sigma
@@ -53,10 +56,8 @@ def test_healthy_current_not_flagged():
     x1 = np.concatenate([x1h, x1c])[None].astype(np.float32)
     x2 = np.concatenate([x2h, x2c])[None].astype(np.float32)
     m = np.ones_like(x1, bool)
-    region = np.zeros_like(m)
-    region[:, 400:] = True
     out = bivariate_normal_anomalies(
-        x1, m, x2, m, region, np.asarray([4.0], np.float32)
+        x1, m, x2, m, *_span(400, 440), np.asarray([4.0], np.float32)
     )
     assert int(out["count"][0]) <= 1
 
@@ -64,10 +65,9 @@ def test_healthy_current_not_flagged():
 def test_fail_open_without_history():
     x = np.ones((1, 10), np.float32)
     m = np.ones((1, 10), bool)
-    region = np.ones((1, 10), bool)
-    region[0, 0] = False  # a single history point: not judgeable
+    span = _span(1, 10)  # a single history point: not judgeable
     out = bivariate_normal_anomalies(
-        x * 100, m, x, m, region, np.asarray([2.0], np.float32)
+        x * 100, m, x, m, *span, np.asarray([2.0], np.float32)
     )
     assert int(out["count"][0]) == 0
 
@@ -78,14 +78,48 @@ def test_min_lower_bound_floors_marginal_band():
     x1 = x1h[None].astype(np.float32)
     x2 = x2h[None].astype(np.float32)
     m = np.ones_like(x1, bool)
-    region = np.zeros_like(m)
-    region[:, 150:] = True
     out = bivariate_normal_anomalies(
-        x1, m, x2, m, region, np.asarray([50.0], np.float32),
+        x1, m, x2, m, *_span(150, 200), np.asarray([50.0], np.float32),
         np.asarray([9.0], np.float32), np.asarray([4.0], np.float32),
     )
     assert float(np.min(np.asarray(out["lower1"]))) >= 9.0
     assert float(np.min(np.asarray(out["lower2"]))) >= 4.0
+
+
+def test_marginal_bounds_are_one_value_a_row():
+    """The four bounds are (B,): the value every slot of the old
+    broadcast (B, T) rows held, mu +- threshold * sigma over the history
+    (population variance plus the kernel's ridge), the floor applied.
+    Only `flags` is (B, T), and the distances are not an output."""
+    rng = np.random.default_rng(3)
+    B, T = 3, 256
+    x1 = rng.normal(10.0, 1.0, (B, T)).astype(np.float32)
+    x2 = rng.normal(5.0, 0.5, (B, T)).astype(np.float32)
+    m1, m2 = rng.random((B, T)) > 0.1, rng.random((B, T)) > 0.1
+    n_hist = np.asarray([200, 180, 255], np.int32)
+    n_total = np.asarray([240, 256, 256], np.int32)
+    thr = np.asarray([3.0, 2.0, 4.0], np.float32)
+    floor1 = np.asarray([-1e9, 8.5, -1e9], np.float32)
+    out = {k: np.asarray(v) for k, v in bivariate_normal_anomalies(
+        x1, m1, x2, m2, n_hist, n_total, thr, floor1,
+        np.full(B, -1e9, np.float32)).items()}
+    assert sorted(out) == ["checked", "count", "first_index", "flags",
+                           "lower1", "lower2", "upper1", "upper2"]
+    assert [k for k, v in out.items() if v.ndim == 2] == ["flags"]
+    for i in range(B):
+        hist = m1[i] & m2[i]
+        hist[n_hist[i]:n_total[i]] = False
+        for x, floor, up, lo in ((x1, floor1[i], "upper1", "lower1"),
+                                 (x2, -1e9, "upper2", "lower2")):
+            h = x[i][hist].astype(np.float64)
+            var = h.var()
+            ridge = 1e-6 * max(x1[i][hist].astype(np.float64).var(),
+                               x2[i][hist].astype(np.float64).var(), 1.0)
+            sd = np.sqrt(var + ridge)
+            np.testing.assert_allclose(out[up][i], h.mean() + thr[i] * sd,
+                                       rtol=1e-5)
+            np.testing.assert_allclose(
+                out[lo][i], max(h.mean() - thr[i] * sd, floor), rtol=1e-5)
 
 
 # ------------------------------------------------------------- engine dispatch
@@ -148,16 +182,15 @@ def test_bound_bitmask_upper_only_ignores_improvement_dips():
     x1 = np.concatenate([x1h, x1c])[None].astype(np.float32)
     x2 = np.concatenate([x2h, x2c])[None].astype(np.float32)
     m = np.ones_like(x1, bool)
-    region = np.zeros_like(m)
-    region[:, 300:] = True
+    span = _span(300, 300 + n_c)
     thr = np.asarray([3.0], np.float32)
     upper_only = np.asarray([1], np.int32)
     both = np.asarray([3], np.int32)
     out = bivariate_normal_anomalies(
-        x1, m, x2, m, region, thr, None, None, upper_only, upper_only
+        x1, m, x2, m, *span, thr, None, None, upper_only, upper_only
     )
     assert int(out["count"][0]) == 0  # dips ignored
     out2 = bivariate_normal_anomalies(
-        x1, m, x2, m, region, thr, None, None, both, both
+        x1, m, x2, m, *span, thr, None, None, both, both
     )
     assert int(out2["count"][0]) == n_c  # two-sided policy still fires

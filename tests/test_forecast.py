@@ -172,6 +172,47 @@ def test_band_bitmask_upper_only_ignores_dips():
     assert int(out["count"][0]) == 0
 
 
+def test_band_bounds_are_region_means_a_row():
+    """`upper` and `lower` are (B,): the mean over every slot of the
+    judged region (valid or not) of preds +- threshold * sigma, the floor
+    applied to the lower curve: what the host took of the (B, T) curves.
+    `region_masks` makes that region from two vectors on the device."""
+    rng = np.random.default_rng(8)
+    B, T = 4, 300
+    x = rng.normal(10.0, 1.0, (B, T)).astype(np.float32)
+    preds = rng.normal(10.0, 0.3, (B, T)).astype(np.float32)
+    mask = rng.random((B, T)) > 0.15
+    n_hist = np.asarray([250, 100, 0, 299], np.int32)
+    n_total = np.asarray([290, 101, 300, 300], np.int32)
+    region, hist_mask = (np.asarray(a) for a in
+                         fc.region_masks(mask, n_hist, n_total))
+    expect = np.zeros((B, T), bool)
+    for i in range(B):
+        expect[i, n_hist[i]:n_total[i]] = True
+    np.testing.assert_array_equal(region, expect)
+    np.testing.assert_array_equal(hist_mask, mask & ~expect)
+
+    sigma = np.asarray([0.5, 1.0, 2.0, np.inf], np.float32)
+    thr = np.asarray([3.0, 2.0, 1.0, 3.0], np.float32)
+    floor = np.asarray([-np.inf, 9.0, 9.5, 0.0], np.float32)
+    out = {k: np.asarray(v) for k, v in fc.band_anomalies(
+        x, mask, region, preds, sigma, thr,
+        np.full(B, fc.BOUND_BOTH, np.int32), floor).items()}
+    assert [k for k, v in out.items() if v.ndim == 2] == ["flags"]
+    upper = preds + (thr * sigma)[:, None]
+    lower = np.maximum(preds - (thr * sigma)[:, None], floor[:, None])
+    for i in range(B):
+        np.testing.assert_allclose(
+            out["upper"][i], np.mean(upper[i][region[i]]), rtol=1e-6)
+        np.testing.assert_allclose(
+            out["lower"][i], np.mean(lower[i][region[i]]), rtol=1e-6)
+        flags = ((x[i] > upper[i]) | (x[i] < lower[i])) & mask[i] & region[i]
+        np.testing.assert_array_equal(out["flags"][i], flags)
+        assert out["count"][i] == flags.sum()
+        assert out["checked"][i] == (mask[i] & region[i]).sum()
+    assert out["upper"][3] == np.inf and out["lower"][3] == 0.0
+
+
 def test_moving_average_long_gap_forward_fills_recent():
     # review finding: a gap longer than the window must fall back to the most
     # recent value before the gap, not the start of the series

@@ -760,13 +760,16 @@ def _two_windows(rng, n_a, n_b, start=0):
     return win(n_a), win(n_b)
 
 
-@pytest.mark.parametrize("family", ["pair", "band"])
+@pytest.mark.parametrize("family", ["pair", "band", "bivariate"])
 def test_transfer_and_pack_counters_equal_hand_counts(family):
     """A two-row launch pads to the 16-row rung: every byte handed to a
-    jitted program and brought back, and the pack's fill, by hand."""
+    jitted program and brought back, and the pack's fill, by hand. A
+    (B, T) array crosses once, upward; what comes back is the programs'
+    outputs, of which only `flags` is (B, T)."""
     import jax
 
-    from foremast_tpu.engine.analyzer import _BandItem, _PairItem
+    from foremast_tpu.engine.analyzer import _BandItem, _BiItem, _PairItem
+    from foremast_tpu.ops import bivariate as bv
     from foremast_tpu.ops import forecast as fc
     from foremast_tpu.parallel import fleet as fl
 
@@ -784,26 +787,41 @@ def test_transfer_and_pack_counters_equal_hand_counts(family):
         T, lens = 32, [(30, 20), (25, 28)]
         items = [_PairItem(f"j{i}", "latency", *_two_windows(rng, b, c),
                            policy) for i, (b, c) in enumerate(lens)]
-        families.family(family).score(eng, items)
         spec = fl.pair_arg_spec(R, T)
         h2d = sum(a.nbytes for a in spec)
         d2h = out_bytes(fl.score_pairs, *spec)
         real, total = sum(b + c for b, c in lens), 2 * R * T
     else:
         T, lens = 512, [(300, 25), (290, 20)]
-        items = [_BandItem(f"j{i}", "latency", *_two_windows(rng, h, c),
-                           policy) for i, (h, c) in enumerate(lens)]
-        families.family(family).score(eng, items)
         f32, b1, row = R * T * 4, R * T, R * 4
-        # _predict (values, history mask), residual_sigma (values,
-        # predictions, history mask, judged mask), band_anomalies (values,
-        # mask, region, predictions, sigma and three per-row policies)
-        h2d = (f32 + b1) + (2 * f32 + 2 * b1) + (2 * f32 + 2 * b1 + 4 * row)
         z, m = np.zeros((R, T), np.float32), np.zeros((R, T), bool)
-        r4 = np.zeros(R, np.float32)
-        d2h = f32 + row + out_bytes(
-            fc.band_anomalies, z, m, m, z, r4, r4, np.ones(R, np.int32), r4)
-        real, total = sum(h + c for h, c in lens), R * T
+        r4, i4 = np.zeros(R, np.float32), np.ones(R, np.int32)
+        if family == "band":
+            items = [_BandItem(f"j{i}", "latency",
+                               *_two_windows(rng, h, c), policy)
+                     for i, (h, c) in enumerate(lens)]
+            # one upload of values and validity; the region's two
+            # vectors; the three per-row policies. The predictions and
+            # sigma are device values from one program to the next
+            h2d = (f32 + b1) + 2 * row + 3 * row
+            d2h = out_bytes(fc.band_anomalies, z, m, m, z, r4, r4, i4, r4)
+            real, total = sum(h + c for h, c in lens), R * T
+        else:
+            items = []
+            for i, (h, c) in enumerate(lens):
+                h1, c1 = _two_windows(rng, h, c)
+                h2, c2 = _two_windows(rng, h, c)
+                c1.start = c2.start = h * 60
+                items.append(_BiItem(f"j{i}", ("latency", "cpu"), (h1, h2),
+                                     (c1, c2), (policy, policy)))
+            # two metrics' values and validity; the region's two vectors
+            # and five per-row policies
+            h2d = 2 * (f32 + b1) + 7 * row
+            d2h = out_bytes(bv.bivariate_normal_anomalies,
+                            z, m, z, m, i4, i4, r4, r4, r4, i4, i4)
+            real, total = 2 * sum(h + c for h, c in lens), 2 * R * T
+    results = families.family(family).score(eng, items)
+    assert len(results) == 2
     assert eng.device_launches == 1
     assert eng.h2d_bytes_total == h2d
     assert eng.d2h_bytes_total == d2h

@@ -82,7 +82,7 @@ def test_long_window_engine_dispatch():
     x, m = _series(B=2, T=512, seed=7)
     region = np.zeros_like(m)
     region[:, -32:] = True
-    preds_long, _ = analyzer._predict(x, m, region)
+    preds_long = analyzer._predict(x, m & ~region)
     seq = np.asarray(fc.ses_predictions(x, m & ~region,
                                         np.full(2, 0.3, np.float32)))
     np.testing.assert_allclose(preds_long, seq, rtol=1e-5, atol=1e-4)
@@ -128,7 +128,7 @@ def test_padded_bucket_does_not_flip_kernel(monkeypatch):
     x, m = _series(B=2, T=4096, seed=9)  # padded shape AT the threshold
     region = np.zeros_like(m)
     region[:, -32:] = True
-    analyzer._predict(x, m, region, data_steps=300)  # but only 300 real steps
+    analyzer._predict(x, m & ~region, data_steps=300)  # but only 300 real steps
     assert called["assoc"] == 0
-    analyzer._predict(x, m, region, data_steps=4500)
+    analyzer._predict(x, m & ~region, data_steps=4500)
     assert called["assoc"] == 1
