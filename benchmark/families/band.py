@@ -1,5 +1,8 @@
 """The band family's side of `correct`: a job with a `historical` window
 is judged by the moving-average band of its history (`lib/reference.py`).
+The reference of `engine.algorithm` `moving_average*` and of no other
+forecaster: a configuration that sets another names its own file under
+`references` (`lib/check.py`).
 
 Numbers compared (name, how the jobs' readings merge, limit: a key of the
 configuration's `check` block, or the limit itself):
@@ -10,6 +13,7 @@ configuration's `check` block, or the limit itself):
 """
 from lib import reference
 
+REFERENCE_OF = {"algorithm": "moving_average"}
 NUMBERS = (("band_gap", "max", "band_gap_sigmas"),
            ("band_count_out", "sum", 0))
 

@@ -1,6 +1,9 @@
 """The pair family's side of `correct`: a job with a `baseline` window is
 judged by the rank test of its current window against it, beside the
-baseline's own band (`lib/reference.py`).
+baseline's own band (`lib/reference.py`). The reference of
+`engine.pairwise_algorithm` `mann_whitney*` and of no other rank test: a
+configuration that sets another names its own file under `references`
+(`lib/check.py`).
 
 Number compared:
   pair_p_gap  widest gap between the program's and the reference's
@@ -8,6 +11,7 @@ Number compared:
 """
 from lib import reference
 
+REFERENCE_OF = {"pairwise_algorithm": "mann_whitney"}
 NUMBERS = (("pair_p_gap", "max", "pair_p_gap"),)
 
 

@@ -6,10 +6,27 @@ the last cycle, the job's status in the store and what that cycle
 recorded for it (the record `/jobs/<id>/explain` serves): each scoring
 family's result, the samples its windows held, and how far behind the
 cycle's clock its newest judged sample lay. The reference's side is
-computed from the fleet's own series with the engine freed. A family's
-reference and comparison are `benchmark/families/<family>.py`, found by
-the name the configuration's class lists; each number compared has a
-limit (PERF.md gives the readings they were set from).
+computed from the fleet's own series with the engine freed. Each number
+compared has a limit (PERF.md gives the readings they were set from).
+
+Which file judges a family is the configuration's to say. A class lists
+its families by the names the program records (`"band"`, `"pair"`), and
+those names stay the keys of every result here; the family's reference
+and comparison (`reference_rows`, `answer`, `judge`, `NUMBERS`, `JOINS`)
+are `benchmark/families/<stem>.py`, where the stem is the configuration's
+`"references": {"<family>": "<stem>"}` entry, or the family's own name
+where it has none (`Fleet.references`). So a forecaster or a rank test
+other than the default comes as a configuration and a family file.
+
+A family file is handed the `fleet`, and reads its algorithm's settings
+from `fleet.config["engine"]`. The rule: a reference file states which
+engine setting it is the reference of, as `REFERENCE_OF = {"<engine
+key>": "<prefix of the values it judges>"}` (`{"algorithm":
+"moving_average"}`; a tuple of prefixes where it judges several), and a
+configuration whose engine block states another value, or none, is
+refused with `BenchError` the first time the family is looked up, which
+is in set-up. A file that judges whatever the engine block says states
+nothing.
 
 A job's results are (family, metric) entries, as the program records
 them: a family gives one a metric of the job, or, where its file sets
@@ -29,7 +46,8 @@ Numbers of every cell, beside the families' own:
 from __future__ import annotations
 
 import importlib.util
-import os
+
+from lib.fleet import BenchError, family_path
 
 UNHEALTHY = "completed_unhealth"
 HEALTHY = ("initial", "completed_health")
@@ -37,17 +55,27 @@ _BLOCK = 256  # reference rows at a time: blocks that stay in cache
 _FAMILIES: dict = {}
 
 
-def family(name: str):
-    """`benchmark/families/<name>.py`, loaded once."""
-    if name not in _FAMILIES:
-        path = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "families", name + ".py")
+def family(fleet, name: str):
+    """The file that judges family `name` in this fleet's configuration,
+    loaded once a stem, and only where the configuration's engine block
+    states what the file is the reference of."""
+    stem = fleet.references[name]
+    if stem not in _FAMILIES:
         spec = importlib.util.spec_from_file_location(
-            "bench_family_" + name, path)
+            "bench_family_" + stem, family_path(stem))
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
-        _FAMILIES[name] = mod
-    return _FAMILIES[name]
+        _FAMILIES[stem] = mod
+    mod = _FAMILIES[stem]
+    for key, prefix in getattr(mod, "REFERENCE_OF", {}).items():
+        stated = fleet.config["engine"].get(key)
+        if not isinstance(stated, str) or not stated.startswith(prefix):
+            raise BenchError(
+                f"benchmark/families/{stem}.py is the reference of engine."
+                f"{key} {prefix!r}*, and the configuration states "
+                f"{stated!r}: name the file that judges family {name!r} "
+                f"under \"references\"")
+    return mod
 
 
 def expected(fleet, job: int) -> list:
@@ -57,7 +85,7 @@ def expected(fleet, job: int) -> list:
     metrics = fleet.metrics_of(job)
     out = []
     for f in fleet.families_of(job):
-        joins = getattr(family(f), "JOINS", None)
+        joins = getattr(family(fleet, f), "JOINS", None)
         if joins is None:
             out += [(f, m, (slot,)) for slot, m in enumerate(metrics)]
         else:
@@ -127,7 +155,7 @@ def reference_answers(fleet, jobs: list, now_slot: int, lag_s: float,
     for block in _blocks(fleet, jobs):
         refs = _reference(fleet, block, now_slot, limits, precision)
         for i, j in enumerate(block):
-            fams = {e: family(e[0]).answer(ref, i)
+            fams = {e: family(fleet, e[0]).answer(ref, i)
                     for e, ref in refs.items()}
             bad = any(e["unhealthy"] for e in fams.values())
             out[j] = {"status": UNHEALTHY if bad else HEALTHY[0],
@@ -139,7 +167,7 @@ def reference_answers(fleet, jobs: list, now_slot: int, lag_s: float,
 def _reference(fleet, block: list, k_now: int, limits: dict,
                precision: str = "float64") -> dict:
     """{(family, metric key): reference rows} of one block of jobs."""
-    return {(f, key): family(f).reference_rows(
+    return {(f, key): family(fleet, f).reference_rows(
         fleet, block, slots, k_now, limits, precision)
         for f, key, slots in expected(fleet, block[0])}
 
@@ -152,7 +180,7 @@ def compare(fleet, answers: dict) -> list:
     spec = {}  # number -> (how readings merge, limit), in listed order
     for cls in fleet.classes:
         for f in cls["families"]:
-            for name, how, lim in family(f).NUMBERS:
+            for name, how, lim in family(fleet, f).NUMBERS:
                 spec[name] = (how, float(limits[lim])
                               if isinstance(lim, str) else lim)
     value = {name: 0 for name in spec}
@@ -164,7 +192,7 @@ def compare(fleet, answers: dict) -> list:
             a = got[j]
             must_bad, must_good = False, True
             for e, ref in refs.items():
-                readings, bad, good = family(e[0]).judge(
+                readings, bad, good = family(fleet, e[0]).judge(
                     a["families"][e], ref, i, limits)
                 must_bad, must_good = must_bad or bad, must_good and good
                 for name, v in readings.items():

@@ -19,17 +19,30 @@ Grid: slot k is the sample at `t0 + k * step`. A job's windows are
 where lead is one diurnal period in steps. A job that watches several
 metrics (`metrics` of its class) has these windows of each, on the same
 slots; metric i is series slot i of the job, and the anomaly is on slot 0.
+
+Which file judges a scoring family is the configuration's too:
+`"references": {"<family>": "<file stem>"}` names `benchmark/families/<stem>.py`
+for a family; one it does not list is judged by `families/<family>.py`.
 """
 from __future__ import annotations
 
 import json
 import math
+import os
+import re
 
 import numpy as np
 
 T0 = 1_700_000_000 // 60 * 60  # step-aligned epoch anchor of every trace
 _SLOT_STRIDE = 7
 _GOLDEN = 0.6180339887
+FAMILIES_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "families")
+_STEM = re.compile(r"[A-Za-z0-9_]+\Z")
+
+
+class BenchError(Exception):
+    """The run cannot produce a result; exit non-zero without one."""
 
 
 class Fleet:
@@ -61,6 +74,8 @@ class Fleet:
         counts = [int(c["jobs"]) for c in self.classes]
         self.jobs = sum(counts)
         self.class_of = _interleave(counts)
+        # {family: stem of the file under families/ that judges it}
+        self.references = _references(cfg, self.classes)
         rng = np.random.default_rng(int(seed))
         # fixed draw order: noise field first, then the anomalous jobs
         self.base = self.level + self.sigma * rng.standard_normal(
@@ -205,6 +220,33 @@ class Fleet:
                 "%Y-%m-%dT%H:%M:%SZ")
 
         return rfc(self.t0), rfc(self.t0 + (self.horizon + 1440) * self.step)
+
+
+def family_path(stem: str) -> str:
+    return os.path.join(FAMILIES_DIR, stem + ".py")
+
+
+def _references(cfg: dict, classes: list) -> dict:
+    """{family: file stem} for every family a class lists. A stem is
+    letters, digits and `_`, and its file exists: anything else ends the
+    run here, before an engine is built."""
+    named = dict(cfg.get("references", {}))
+    out = {}
+    for f in dict.fromkeys(f for c in classes for f in c["families"]):
+        stem = named.pop(f, f)
+        if not isinstance(stem, str) or not _STEM.match(stem):
+            raise BenchError(
+                f"references: family {f!r} names {stem!r}; a stem is letters, "
+                f"digits and _ (a file benchmark/families/<stem>.py)")
+        if not os.path.isfile(family_path(stem)):
+            raise BenchError(
+                f"family {f!r} is judged by benchmark/families/{stem}.py, "
+                f"and there is no such file")
+        out[f] = stem
+    if named:
+        raise BenchError(
+            f"references names {sorted(named)}: no class lists such a family")
+    return out
 
 
 def _interleave(counts: list) -> np.ndarray:

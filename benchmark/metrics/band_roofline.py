@@ -1,11 +1,14 @@
 """The band launch's share of its roofline: the least time the chip
 could take for the bytes and operations the band scorer needs
 (`lib/costs.py`, from each traced cycle's real rows and samples) over
-the summed device time of the band programs' runs in the trace."""
+the summed device time of the band programs' runs in the trace: the
+region and history masks, the predictions, sigma, and the bounds and
+flags. `costs.band` already counts the region mark in its bytes a
+sample."""
 from lib import costs
 
-PROGRAMS = ("jit__moving_average_1d", "jit_residual_sigma",
-            "jit_band_anomalies")
+PROGRAMS = ("jit_region_masks", "jit__moving_average_1d",
+            "jit_residual_sigma", "jit_band_anomalies")
 
 
 def read(ctx):

@@ -12,8 +12,9 @@ plain reference, and prints one JSON line. `--trace 1` wraps the window
 A cell is an entry of `BENCHMARK.json`: its configuration is the file
 that entry's `configs` row names, its traffic `benchmark/traffic/<traffic>.json`,
 a per-layer metric `benchmark/metrics/<name>.py`, a scoring family's
-reference and comparison `benchmark/families/<family>.py`. No name is
-listed here.
+reference and comparison `benchmark/families/<stem>.py` (the stem the
+configuration's `references` names for the family, else the family's own
+name). No name is listed here.
 
 Without a TPU it exits non-zero and prints no result, unless `--tiny` is
 given: the CPU rehearsal (job counts and history cut by the
@@ -51,8 +52,7 @@ CYCLE_COUNTERS = ("jobs_shed", "watchdog_fires", "quarantined_jobs",
                   "stale_verdicts_served")
 
 
-class BenchError(Exception):
-    """The run cannot produce a result; exit non-zero without one."""
+BenchError = fleet_mod.BenchError  # the run ends non-zero with no result
 
 
 def load_cell(name: str) -> dict:

@@ -1,9 +1,10 @@
 """The benchmark's own checks: the trace reduction on a small trace, the
 array source against the byte path, the cost functions against hand
-counts, the last line of a tiny run, the control, and the faults that
-`correct` has to catch."""
+counts, the last line of a tiny run, which file judges a family, the
+control, and the faults that `correct` has to catch."""
 import json
 import os
+import shutil
 import subprocess
 import sys
 import types
@@ -23,6 +24,11 @@ ROOT = os.path.dirname(BENCH)
 def _args(workload, seed=3, trace=0):
     return types.SimpleNamespace(workload=workload, seed=seed, seconds=0.2,
                                  trace=trace, tiny=True)
+
+
+def _config(name):
+    return fleet_mod.load_json(os.path.join(BENCH, "configs",
+                                            name + ".json"))
 
 
 # ------------------------------------------------------------ trace reduction
@@ -111,6 +117,25 @@ def test_costs_against_hand_counts():
     assert (secs, bound) == (pytest.approx(2.0), "compute")
     with pytest.raises(KeyError):
         peaks.for_kind("cpu")
+
+
+def test_band_roofline_sums_the_launchs_four_programs():
+    fl = fleet_mod.Fleet(_config("rollout7d"), 1, tiny=True)
+    pk = peaks.for_kind("TPU v5 lite")
+    seconds = {"jit_region_masks": 1e-6, "jit__moving_average_1d": 4e-6,
+               "jit_residual_sigma": 2e-6, "jit_band_anomalies": 3e-6}
+    ctx = {"trace": {"programs": {p: [s, 2] for p, s in seconds.items()}
+                     | {"jit_all_pairwise_tests": [5e-6, 2]}},
+           "fleet": fl, "peaks": pk, "notes": {},
+           "cycles": [{"rows": {"band": 40}, "now_slot": fl.now_slot()}]}
+    share = harness.load_reader("band_roofline")(ctx)
+    assert ctx["notes"]["band_device_s"] == pytest.approx(10e-6)
+    points = fl.hist_steps + 1 + fl.now_slot() - fl.hist_hi + 1
+    least, _ = costs.least_seconds(costs.band(40, points), pk)
+    assert share == pytest.approx(100.0 * least / 10e-6)
+    # a launch none of whose programs is in the trace has no share
+    ctx["trace"] = {"programs": {"jit_all_pairwise_tests": [5e-6, 2]}}
+    assert harness.load_reader("band_roofline")(ctx) is None
 
 
 # ---------------------------------------------------------------- a tiny run
@@ -209,6 +234,150 @@ def test_a_jobs_results_follow_its_metrics_and_families():
     assert (full.app_name(299), two.app_name(47)) == ("app-43", "app-47")
 
 
+# -------------------------------------------------- which file judges a family
+@pytest.mark.parametrize("config,families", [
+    ("rollout7d", ["pair", "band"]),
+    ("rollout7d_2m", ["pair", "bivariate"]),
+])
+def test_an_accepted_configuration_is_judged_by_each_familys_own_file(
+        config, families):
+    cfg = _config(config)
+    assert "references" not in cfg
+    fl = fleet_mod.Fleet(cfg, 1, tiny=True)
+    assert fl.references == {f: f for f in families}
+    for f in families:
+        mod = check.family(fl, f)
+        assert mod.__name__ == "bench_family_" + f
+        assert mod.__file__ == os.path.join(BENCH, "families", f + ".py")
+        assert check.family(fl, f) is mod  # loaded once
+
+
+@pytest.fixture
+def families_dir(tmp_path, monkeypatch):
+    """A families directory of the test's own: the real files, beside
+    `band_fixture.py`, which is `band.py` under other numbers' names and
+    keeps every entry its `judge` was handed."""
+    for name in os.listdir(fleet_mod.FAMILIES_DIR):
+        if name.endswith(".py"):
+            shutil.copy(os.path.join(fleet_mod.FAMILIES_DIR, name), tmp_path)
+    src = (tmp_path / "band.py").read_text()
+    for old, new in (("band_gap", "fx_gap"),
+                     ("band_count_out", "fx_count_out")):
+        assert f'"{old}"' in src
+        src = src.replace(f'"{old}"', f'"{new}"')
+    (tmp_path / "band_fixture.py").write_text(src + """
+
+SEEN = []
+_judge = judge
+
+
+def judge(entry, ref, i, limits):
+    SEEN.append(entry)
+    return _judge(entry, ref, i, limits)
+""")
+    monkeypatch.setattr(fleet_mod, "FAMILIES_DIR", str(tmp_path))
+    monkeypatch.setattr(check, "_FAMILIES", {})
+    return tmp_path
+
+
+def _cell_with(monkeypatch, **keys):
+    """`rollout7d_polled` with keys of its configuration replaced."""
+    real = harness.load_cell
+
+    def load(name):
+        cell = real(name)
+        cell["config"] = dict(cell["config"], **keys)
+        return cell
+
+    monkeypatch.setattr(harness, "load_cell", load)
+
+
+def test_a_named_reference_judges_the_programs_band_entries(
+        monkeypatch, families_dir):
+    _cell_with(monkeypatch, references={"band": "band_fixture"})
+    out = harness.run(_args("rollout7d_polled"))
+    assert out["correct"] is True and out["failed"] == 0
+    assert list(out["compared"]) == [
+        "pair_p_gap", "fx_gap", "fx_count_out", "verdict_miss",
+        "stale_jobs", "compiles_in_window"]
+    assert 0 < out["compared"]["fx_gap"]["value"] < 0.004
+    # the result's key stays the family the program records
+    assert out["cycles"][-1]["rows"] == {
+        "pair": out["cycles"][-1]["offered"],
+        "band": out["cycles"][-1]["offered"]}
+    fixture = check._FAMILIES["band_fixture"]
+    assert fixture.__file__ == str(families_dir / "band_fixture.py")
+    assert len(fixture.SEEN) == out["cycles"][-1]["offered"]
+    assert {(e["family"], e["metric"]) for e in fixture.SEEN} == {
+        ("band", "error4xx")}
+    # a configuration that names no file is judged by the family's own,
+    # in the same process
+    plain = fleet_mod.Fleet(_config("rollout7d"), 1, tiny=True)
+    named = fleet_mod.Fleet(_config("rollout7d") | {
+        "references": {"band": "band_fixture"}}, 1, tiny=True)
+    assert check.family(named, "band") is fixture
+    assert check.family(plain, "band").__name__ == "bench_family_band"
+    assert check.family(named, "pair") is check.family(plain, "pair")
+    assert [n for n, _, _ in check.compare(
+        plain, {"jobs": {}, "now_slot": 0, "lag_s": 0.0})][:3] == [
+            "pair_p_gap", "band_gap", "band_count_out"]
+
+
+def _no_engine(monkeypatch):
+    from foremast_tpu.engine.analyzer import Analyzer
+
+    def built(self, *a, **kw):
+        raise AssertionError("an engine was built")
+
+    monkeypatch.setattr(Analyzer, "__init__", built)
+
+
+@pytest.mark.parametrize("references,naming", [
+    ({"band": "../families/band"}, "'../families/band'"),
+    ({"band": "sub/band"}, "'sub/band'"),
+    ({"band": ".."}, "'..'"),
+    ({"band": ""}, "''"),
+    ({"band": 7}, "7"),
+    ({"band": "band_holt_winters"}, "families/band_holt_winters.py"),
+    ({"bands": "band"}, "bands"),
+])
+def test_a_stem_that_names_no_file_ends_the_run_before_an_engine(
+        monkeypatch, capsys, references, naming):
+    _no_engine(monkeypatch)
+    _cell_with(monkeypatch, references=references)
+    with pytest.raises(harness.BenchError, match=naming):
+        harness.run(_args("rollout7d_polled"))
+    capsys.readouterr()
+    assert harness.main(["--workload", "rollout7d_polled", "--seed", "3",
+                         "--seconds", "0", "--tiny"]) == 3
+    said = capsys.readouterr()
+    assert said.out == "" and naming in said.err
+
+
+@pytest.mark.parametrize("key,value,stem", [
+    ("algorithm", "holt_winters", "band"),
+    ("algorithm", "prophet", "band"),
+    ("algorithm", None, "band"),
+    ("pairwise_algorithm", "wilcoxon_all", "pair"),
+    ("pairwise_algorithm", "all", "pair"),
+])
+def test_a_family_file_refuses_an_algorithm_it_is_not_the_reference_of(
+        monkeypatch, capsys, key, value, stem):
+    engine = dict(_config("rollout7d")["engine"], **{key: value})
+    if value is None:
+        del engine[key]
+    _cell_with(monkeypatch, engine=engine)
+    warmed = []
+    monkeypatch.setattr(harness.Engine, "warm_up",
+                        lambda self: warmed.append(1))
+    assert harness.main(["--workload", "rollout7d_polled", "--seed", "3",
+                         "--seconds", "0", "--tiny"]) == 3
+    said = capsys.readouterr()
+    assert said.out == "" and not warmed
+    assert f"benchmark/families/{stem}.py" in said.err
+    assert f"engine.{key}" in said.err and repr(value) in said.err
+
+
 def test_no_accelerator_means_no_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
@@ -227,9 +396,7 @@ def test_no_accelerator_means_no_result():
      ["bi_bound_gap", "pair_p_gap"]),
 ])
 def test_control_in_bfloat16_is_not_correct(config, names, failing, seed):
-    cfg = fleet_mod.load_json(os.path.join(BENCH, "configs",
-                                           config + ".json"))
-    fl = fleet_mod.Fleet(cfg, seed, tiny=True)
+    fl = fleet_mod.Fleet(_config(config), seed, tiny=True)
     jobs = [j for j in range(fl.jobs) if j not in fl.anomalous]
     k_now = fl.now_slot() + 3
     sound = check.reference_answers(fl, jobs, k_now, 5.0, "float64")

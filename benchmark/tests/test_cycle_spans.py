@@ -24,7 +24,8 @@ NEW = (
     "memo_fp_s_per_cycle", "advance_s_per_cycle", "fold_s_per_cycle",
     "publish_s_per_cycle", "uncovered_s_per_cycle", "pack_s_per_cycle",
     "launch_s_per_cycle", "h2d_bytes_per_cycle", "d2h_bytes_per_cycle",
-    "pack_fill_share", "materialize_s_per_cycle")
+    "pack_fill_share", "materialize_s_per_cycle",
+    "collect_rows_s_per_cycle")
 
 
 @pytest.fixture(scope="module")
@@ -65,8 +66,8 @@ def test_the_named_seconds_fill_the_unstaged_hole(line):
     assert v["fold_s_per_cycle"] == pytest.approx(
         sum(c["stage_seconds"]["fold"] for c in line["cycles"])
         / len(line["cycles"]), abs=TINY_TOLERANCE_S)
-    assert v["materialize_s_per_cycle"] <= \
-        v["collect_s_per_cycle"] + TINY_TOLERANCE_S
+    assert v["materialize_s_per_cycle"] + v["collect_rows_s_per_cycle"] \
+        == pytest.approx(v["collect_s_per_cycle"], abs=TINY_TOLERANCE_S)
 
 
 def test_a_window_whose_root_left_the_ring_reads_as_nothing(line):
@@ -103,3 +104,26 @@ def test_a_root_that_dropped_children_reads_as_nothing(monkeypatch):
     assert cycle_spans.self_seconds(root) == pytest.approx(1e-3)
     # a program from before the pieces existed: no `route_s`, no reading
     assert cycle_spans.per_cycle(ctx, cycle_spans.uncovered_seconds) is None
+
+
+def test_collect_rows_is_the_collect_spans_self_time(monkeypatch):
+    from foremast_tpu.utils import tracing
+
+    def collect(ms, wait_ms):
+        return {"name": "engine.collect", "duration_ms": ms, "children": [
+            {"name": "engine.materialize", "duration_ms": wait_ms}]}
+
+    root = {"name": cycle_spans.ROOT, "duration_ms": 40.0,
+            "attrs": {"cycle_id": "x-c1"},
+            "children": [{"name": cycle_spans.SCORE, "duration_ms": 30.0,
+                          "children": [collect(10.0, 6.0),
+                                       collect(5.0, 4.5)]}]}
+    monkeypatch.setattr(tracing.tracer, "snapshot", lambda **kw: [root])
+    ctx = {"cycles": [{"cycle_id": "x-c1"}]}
+    read = harness.load_reader("collect_rows_s_per_cycle")
+    assert read(ctx) == pytest.approx(4.5e-3)
+    assert harness.load_reader("materialize_s_per_cycle")(ctx) \
+        == pytest.approx(10.5e-3)
+    # a cycle that collected nothing has no such span: nothing to read
+    root["children"][0]["children"] = []
+    assert read(ctx) is None
