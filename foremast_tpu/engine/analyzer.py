@@ -25,6 +25,7 @@ import time
 from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
+from functools import partial
 
 import numpy as np
 
@@ -370,6 +371,11 @@ class Analyzer:
         self.d2h_bytes_total = 0
         self.pack_real_elems_total = 0
         self.pack_total_elems_total = 0
+        # -- seasonal band launches: partitions by detected period
+        # (cumulative), and the largest seasonal state a Holt-Winters fit
+        # of the cycle held on the device, from its shapes (per cycle)
+        self.period_partitions_total = 0
+        self._cycle_hw_state_bytes = 0
         # -- single-dispatch mega-batching (MEGABATCH) cumulative
         # counters: launches through the mega path, real rows carried and
         # padding rows added (the packing-efficiency signal satellite
@@ -956,7 +962,7 @@ class Analyzer:
         return a
 
     def _launch_chunks(self, fn, arrays: list, donate: int = 0,
-                       row_elems=None) -> list:
+                       row_elems=None, with_rows: bool = False) -> list:
         """Row-chunk packed (B, ...) arrays into FIXED batch buckets and
         call fn per chunk WITHOUT materializing the outputs.
 
@@ -983,7 +989,9 @@ class Analyzer:
         `row_elems` (a family's launch half passes it) holds each row's
         real samples over the packed value arrays, the chunk's float
         (rows, T) blocks: the pack's fill is their sum against the
-        blocks' padded size.
+        blocks' padded size. `with_rows` hands fn the chunk's real row
+        count as `rows=` (the band closure partitions the real rows, not
+        the edge padding).
         """
         B = arrays[0].shape[0]
         mega = self.config.megabatch
@@ -1009,6 +1017,7 @@ class Analyzer:
                 sl = [np.pad(a, ((0, target - n),) + ((0, 0),) * (a.ndim - 1),
                              mode="edge") for a in sl]
             self.device_launches += 1
+            call = partial(fn, rows=n) if with_rows else fn
             if row_elems is not None:
                 self.pack_real_elems_total += int(row_elems[i:i + C].sum())
                 self.pack_total_elems_total += sum(
@@ -1020,11 +1029,11 @@ class Analyzer:
                     self.megabatch_launches_total += 1
                     self.megabatch_real_rows_total += n
                     self.megabatch_pad_rows_total += target - n
-                    out = self._mega_call(fn, sl, donate)
+                    out = self._mega_call(call, sl, donate)
                 elif donate:
-                    out = self._call(fn, *sl)
+                    out = self._call(call, *sl)
                 else:
-                    out = fn(*sl)
+                    out = call(*sl)
                 sp.attrs["h2d_bytes"] = self.h2d_bytes_total - h0
             launches.append((out, n))
         return launches
@@ -1066,52 +1075,6 @@ class Analyzer:
         if len(outs) == 1:
             return outs[0]
         return {k: np.concatenate([o[k] for o in outs]) for k in outs[0]}
-
-    def _score_chunks(self, fn, arrays: list) -> dict:
-        """Synchronous launch+collect (the pre-pipeline contract)."""
-        return self._collect_chunks(self._launch_chunks(fn, arrays))
-
-    def _launch_period_partitions(self, band_fn, args, row_elems) -> list:
-        """Launch a band scorer, partitioned by detected seasonal period.
-
-        The HW/seasonal-trend scan needs a STATIC period (the season buffer
-        length is a compiled shape), so per-series detected periods cannot
-        ride one launch. Candidate sets are tiny (operational cycles), so
-        the fleet splits into at most a handful of sub-batches — each still
-        chunked into the fixed rungs — and outputs merge back in original
-        order at collect time. No-period algorithms and auto-off fall
-        through to one partition. Detection itself materializes (the chosen
-        periods steer host-side batching), but the scoring launches stay
-        async. Returns [(row_idx | None, chunk launches)].
-        """
-        xv, xm, n_hist, n_total = args[:4]
-        chosen = self._detect_periods(xv, xm, n_hist, n_total)
-        if chosen is None:
-            return [(None, self._launch_chunks(band_fn, args,
-                                               row_elems=row_elems))]
-        parts = []
-        for p in np.unique(chosen):
-            idx = np.nonzero(chosen == p)[0]
-            parts.append((idx, self._launch_chunks(
-                lambda *a, _p=int(p): band_fn(*a, _period=_p),
-                [a[idx] for a in args], row_elems=row_elems[idx],
-            )))
-        return parts
-
-    def _collect_period_partitions(self, parts: list, B: int) -> dict:
-        if len(parts) == 1 and parts[0][0] is None:
-            return self._collect_chunks(parts[0][1])
-        out: dict | None = None
-        for idx, launches in parts:
-            sub = self._collect_chunks(launches)
-            if out is None:
-                out = {
-                    k: np.empty((B,) + v.shape[1:], v.dtype)
-                    for k, v in sub.items()
-                }
-            for k, v in sub.items():
-                out[k][idx] = v
-        return out
 
     # ------------------------------------------------ family launch/collect
     # Each batch family (pair, band, bivariate, hpa) is split into a
@@ -1193,36 +1156,38 @@ class Analyzer:
             ("holt_winters", "seasonal_trend", "prophet")
         )
 
-    def _detect_periods(self, xv, xm, n_hist, n_total) -> "np.ndarray | None":
-        """Per-series seasonal period for the band batch (auto-detection).
-
-        Returns an int array of chosen periods, or None when the configured
-        algorithm has no period or auto-detection is off. The fallback for
-        unsupported/aperiodic series is the static HW_PERIOD, clamped the
-        same way the static path clamps it."""
+    def _detect_periods(self, xv_d, hist_mask, rows: int):
+        """Per-series seasonal period of a band chunk (auto-detection),
+        from the chunk's device values: the (rows,) int32 periods on the
+        host, or None when the configured algorithm has no period or
+        auto-detection is off. The one wait a band launch keeps: a
+        compiled period is a static shape, so the host has to know which
+        rows share one before it can enqueue their fit; it waits here for
+        the chunk's upload and the detection program, and (B,) int32 come
+        down. The fallback for unsupported or aperiodic series is the
+        static HW_PERIOD, clamped the same way the static path clamps it."""
         cfg = self.config
         cands = tuple(p for p in cfg.hw_period_candidates if p >= 2)
         # an empty candidate set (operator set HW_PERIOD_CANDIDATES="") is
         # an explicit "static period only" — same as auto off
         if not (self._needs_period() and cfg.hw_period_auto and cands):
             return None
-        T = xv.shape[1]
-        fallback = min(cfg.hw_period, max(T // 2, 2))
-
-        def detect_fn(xv_c, xm_c, nh_c, n_c):
-            _, hist_mask = self._call(fc.region_masks, xm_c, nh_c, n_c)
+        fallback = min(cfg.hw_period, max(xv_d.shape[1] // 2, 2))
+        d0 = self.d2h_bytes_total
+        with tracing.span(tracing.SPAN_ENGINE_DETECT_PERIOD, rows=rows) as sp:
             period, _ = self._call(
-                fc.detect_period, xv_c, hist_mask, cands,
+                fc.detect_period, xv_d, hist_mask, cands,
                 np.int32(fallback), np.float32(cfg.hw_min_seasonal_acf),
                 alias_margin=np.float32(cfg.hw_alias_margin),
                 contrast_margin=np.float32(cfg.hw_contrast_margin),
             )
-            return {"period": period}
-
-        # through the fixed batch rungs like every scorer: one compiled
-        # detection program per (rung, T bucket), bounded launch memory
-        return self._score_chunks(
-            detect_fn, [xv, xm, n_hist, n_total])["period"]
+            chosen = self._host(period)[:rows]
+            periods, counts = np.unique(chosen, return_counts=True)
+            sp.attrs.update(
+                d2h_bytes=self.d2h_bytes_total - d0, partitions=len(periods),
+                period_rows={str(p): n for p, n in zip(periods.tolist(),
+                                                       counts.tolist())})
+        return chosen
 
     def _predict(self, xv, hist_mask, data_steps: int | None = None,
                  period_override: int | None = None):
@@ -1256,9 +1221,12 @@ class Analyzer:
         elif algo.startswith("holt_winters"):
             period = (period_override if period_override is not None
                       else min(self.config.hw_period, max(xv.shape[1] // 2, 2)))
-            fitm = hist_mask & (np.arange(xv.shape[1]) >= 2 * period)
+            fitm = self._call(fc.hw_fit_mask, hist_mask, np.int32(period))
             _, preds = self._call(fc.fit_holt_winters, xv, hist_mask, fitm,
                                   period)
+            self._cycle_hw_state_bytes = max(
+                self._cycle_hw_state_bytes,
+                fc.hw_state_bytes(period, fc.HW_CANDIDATES, B))
         elif algo.startswith("seasonal_trend") or algo.startswith("prophet"):
             period = (period_override if period_override is not None
                       else min(self.config.hw_period, max(xv.shape[1] // 2, 2)))
@@ -1292,8 +1260,8 @@ class Analyzer:
         xv, xm = pack_windows(concats, pad_to=T)
         ns = np.asarray([c.values.shape[0] for c in concats], np.int32)
 
-        def band_fn(xv_c, xm_c, nh_c, n_c, thr_c, bnd_c, mlb_c, _period=None):
-            # the chunk crosses to the device once and its three programs
+        def band_fn(xv_c, xm_c, nh_c, n_c, thr_c, bnd_c, mlb_c, rows):
+            # the chunk crosses to the device once and the launch's programs
             # read it there; the judged region [n_h, n) of each row, the
             # predictions and sigma never visit the host.
             # The long-window kernel gate is a function of the BUCKET (T),
@@ -1306,25 +1274,51 @@ class Analyzer:
             # points, where the assoc scan is the right kernel anyway.
             xv_d, xm_d = self._put(xv_c, xm_c)
             region, hist_mask = self._call(fc.region_masks, xm_d, nh_c, n_c)
-            preds = self._predict(xv_d, hist_mask, T, period_override=_period)
-            sigma = self._call(
-                fc.residual_sigma, xv_d, preds, hist_mask, hist_mask)
-            return self._call(
-                fc.band_anomalies,
-                xv_d, xm_d, region, preds, sigma, thr_c, bnd_c, mlb_c)
 
-        args = [
+            def score(xv_p, xm_p, region_p, hist_p, thr_p, bnd_p, mlb_p,
+                      period=None):
+                preds = self._predict(xv_p, hist_p, T, period_override=period)
+                sigma = self._call(
+                    fc.residual_sigma, xv_p, preds, hist_p, hist_p)
+                return self._call(
+                    fc.band_anomalies,
+                    xv_p, xm_p, region_p, preds, sigma, thr_p, bnd_p, mlb_p)
+
+            chosen = self._detect_periods(xv_d, hist_mask, rows)
+            if chosen is None:
+                return score(xv_d, xm_d, region, hist_mask,
+                             thr_c, bnd_c, mlb_c)
+            # a compiled period is a static shape: each period's rows are
+            # gathered from the DEVICE block (row count a batch rung, the
+            # index edge-padded: a padded slot repeats the partition's last
+            # row, so its scatter writes that row's own results again),
+            # fitted, and their results scattered back into claim order on
+            # the device; the collect reads one dict, as for any band.
+            out = None
+            for p in np.unique(chosen):
+                idx = np.nonzero(chosen == p)[0].astype(np.int32)
+                idx = np.pad(idx, (0, self._bucket_rows(len(idx)) - len(idx)),
+                             mode="edge")
+                part = score(
+                    *self._call(fc.take_rows, idx,
+                                xv_d, xm_d, region, hist_mask),
+                    thr_c[idx], bnd_c[idx], mlb_c[idx], period=int(p))
+                out = self._call(fc.scatter_rows, out, part, idx,
+                                 xv_d.shape[0])
+                self.period_partitions_total += 1
+            return out
+
+        launches = self._launch_chunks(band_fn, [
             xv, xm, np.asarray(n_hs, np.int32), ns,
             np.asarray([it.policy.threshold for it in group], np.float32),
             np.asarray([it.policy.bound for it in group], np.int32),
             np.asarray([it.policy.min_lower_bound for it in group], np.float32),
-        ]
-        parts = self._launch_period_partitions(band_fn, args, ns)
-        return (group, parts, xv, n_hs)
+        ], row_elems=ns, with_rows=True)
+        return (group, launches, xv, n_hs)
 
     def _collect_bands(self, state) -> dict:
-        group, parts, xv, n_hs = state
-        out = self._collect_period_partitions(parts, len(group))
+        group, launches, xv, n_hs = state
+        out = self._collect_chunks(launches)
         results = {}
         # bulk tolist for the per-row fields (see _collect_pairs); of the
         # (B, T) flags only the rows that flagged a point are read
@@ -1712,7 +1706,7 @@ class Analyzer:
                         params, cwin, cmask, mu, sd, model.apply))))
                     yield it, z
                 continue
-            # chunk like _score_chunks: groups beyond the configured batch
+            # chunk like _launch_chunks: groups beyond the configured batch
             # cap split into full chunks (pad can never go negative)
             for lo in range(0, len(recs), chunk_cap):
                 chunk = recs[lo:lo + chunk_cap]
@@ -2412,6 +2406,8 @@ class Analyzer:
         rescore_skips0 = self.lstm_rescore_skips
         h2d0, d2h0 = self.h2d_bytes_total, self.d2h_bytes_total
         real0, total0 = self.pack_real_elems_total, self.pack_total_elems_total
+        parts0 = self.period_partitions_total
+        self._cycle_hw_state_bytes = 0
         shed_cycle0 = self.jobs_shed_total
         stale_cycle0 = self.stale_verdicts_served_total
         wd_cycle0 = self.watchdog_fires_total
@@ -2537,7 +2533,11 @@ class Analyzer:
                 "h2d_bytes": self.h2d_bytes_total - h2d0,
                 "d2h_bytes": self.d2h_bytes_total - d2h0,
                 "pack_real_elems": self.pack_real_elems_total - real0,
-                "pack_total_elems": self.pack_total_elems_total - total0}
+                "pack_total_elems": self.pack_total_elems_total - total0,
+                "period_partitions": self.period_partitions_total - parts0,
+                "hw_candidates": (fc.HW_CANDIDATES
+                                  if self._cycle_hw_state_bytes else 0),
+                "hw_state_bytes": self._cycle_hw_state_bytes}
             score_sp.attrs.update(counters)
 
         with tracing.span(tracing.SPAN_ENGINE_FOLD):
@@ -2766,6 +2766,16 @@ class Analyzer:
                 "foremastbrain:fetch_pool_width", {}, pool["width"],
                 help="Fetch pool threads the last cycle used: sized from "
                      "its probe, at most FETCH_CONCURRENCY (1 = no pool).")
+            self.exporter.record_gauge(
+                "foremastbrain:period_partitions", {},
+                counters["period_partitions"],
+                help="Partitions by detected seasonal period that the last "
+                     "cycle's band launches split into (0: no detection).")
+            self.exporter.record_gauge(
+                "foremastbrain:hw_state_bytes", {},
+                counters["hw_state_bytes"],
+                help="Seasonal state the largest Holt-Winters fit of the "
+                     "last cycle held on the device, bytes (0: none ran).")
             triage_cycle = None
             if triage_gate is not None and triage_gate.active:
                 tg = triage_gate

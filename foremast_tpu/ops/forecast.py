@@ -17,9 +17,10 @@ TPU design:
     (region selected by index masks, not slicing, so hist_len is a traced
     per-series value and one compiled program serves every job shape bucket).
   * Holt-Winters parameters are fit by a grid search minimizing historical
-    SSE: candidates stream through `lax.map` (bounded memory), each candidate
-    vmapped across the whole batch — replacing the per-series scipy.optimize
-    loop a CPU brain would run.
+    SSE: the candidates run side by side through one pass over time, the
+    season a (period, candidates, rows) array indexed by t mod period, then
+    one more pass gives the winners' predictions — replacing the per-series
+    scipy.optimize loop a CPU brain would run.
 
 All kernels take (B, T) values + masks and are jit-compiled once per (T,
 period/window) bucket.
@@ -49,6 +50,11 @@ __all__ = [
     "holt_winters_predictions",
     "detect_period",
     "fit_holt_winters",
+    "hw_state_bytes",
+    "hw_fit_mask",
+    "HW_CANDIDATES",
+    "take_rows",
+    "scatter_rows",
     "fit_seasonal_trend",
     "judged_region",
     "region_masks",
@@ -185,27 +191,67 @@ def _des_1d(x, mask, alpha, beta):
     return preds
 
 
-def _hw_1d(x, mask, period: int, alpha, beta, gamma):
-    """Additive Holt-Winters with static seasonal period."""
-    m0 = mask[:period].astype(_F)
-    n0 = jnp.maximum(jnp.sum(m0), 1.0)
-    l0 = jnp.sum(jnp.where(mask[:period], x[:period].astype(_F), 0.0)) / n0
-    s0 = jnp.where(mask[:period], x[:period].astype(_F) - l0, 0.0)
-    b0 = jnp.asarray(0.0, _F)
+def _hw_init(xT, mT, period: int):
+    """Holt-Winters start of time-major rows `(T, ...)`: the level is the
+    masked mean of the first period, the season the first period's
+    deviations from it (0 where a sample is absent)."""
+    x0, m0 = xT[:period].astype(_F), mT[:period]
+    n0 = jnp.maximum(jnp.sum(m0.astype(_F), axis=0), 1.0)
+    l0 = jnp.sum(jnp.where(m0, x0, 0.0), axis=0) / n0
+    return l0, jnp.where(m0, x0 - l0, 0.0)
+
+
+def _hw_step(l, b, s_t, xt, mt, alpha, beta, gamma):
+    """One Holt-Winters step: (prediction, l', b', the season slot's new
+    value). An absent sample advances the state by its own forecast."""
+    pred = l + b + s_t
+    l_next = jnp.where(mt, alpha * (xt - s_t) + (1.0 - alpha) * (l + b), l + b)
+    b_next = jnp.where(mt, beta * (l_next - l) + (1.0 - beta) * b, b)
+    s_new = jnp.where(mt, gamma * (xt - l_next) + (1.0 - gamma) * s_t, s_t)
+    return pred, l_next, b_next, s_new
+
+
+def _hw_predictions_tm(xT, mT, period: int, alpha, beta, gamma):
+    """Additive Holt-Winters over time-major rows: `xT`, `mT` are
+    `(T, ...)`, the parameters broadcast against the trailing axes, the
+    `(T, ...)` one-step predictions come back.
+
+    Per row, period p: `l0` = masked mean of `x[0:p]`, `s0 = x[0:p] - l0`
+    (0 where absent), `b0 = 0`. At step t, `s_t = season[t mod p]` and the
+    prediction is `l + b + s_t`; where the sample is present
+    `l' = alpha (x - s_t) + (1 - alpha)(l + b)`,
+    `b' = beta (l' - l) + (1 - beta) b`,
+    `season[t mod p] = gamma (x - l') + (1 - gamma) s_t`; where it is
+    absent `l' = l + b`, `b' = b` and the slot keeps its value.
+
+    The season is a `(p, ...)` array read and written at `t mod p`: a
+    dynamic slice and an in-place update of one contiguous slab on the
+    leading axis a step, never a roll or a copy of the buffer.
+    """
+    l0, season0 = _hw_init(xT, mT, period)
 
     def step(carry, inp):
-        l, b, season = carry
+        l, b, season, idx = carry
         xt, mt = inp
-        s_t = season[0]
-        pred = l + b + s_t
-        l_next = jnp.where(mt, alpha * (xt - s_t) + (1.0 - alpha) * (l + b), l + b)
-        b_next = jnp.where(mt, beta * (l_next - l) + (1.0 - beta) * b, b)
-        s_new = jnp.where(mt, gamma * (xt - l_next) + (1.0 - gamma) * s_t, s_t)
-        season = jnp.roll(season, -1).at[-1].set(s_new)
-        return (l_next, b_next, season), pred
+        s_t = lax.dynamic_index_in_dim(season, idx, 0, keepdims=False)
+        pred, l, b, s_new = _hw_step(l, b, s_t, xt, mt, alpha, beta, gamma)
+        season = lax.dynamic_update_index_in_dim(season, s_new, idx, 0)
+        return (l, b, season, jnp.where(idx + 1 == period, 0, idx + 1)), pred
 
-    _, preds = lax.scan(step, (l0, b0, s0), (x.astype(_F), mask))
+    init = (l0, jnp.zeros_like(l0), season0, jnp.int32(0))
+    _, preds = lax.scan(step, init, (xT.astype(_F), mT))
     return preds
+
+
+def _hw_1d(x, mask, period: int, alpha, beta, gamma):
+    """Additive Holt-Winters with static seasonal period over one series
+    (the time-major recurrence at a single row)."""
+    return _hw_predictions_tm(x, mask, period, alpha, beta, gamma)
+
+
+def _holt_winters_rows(x, mask, period: int, alpha, beta, gamma):
+    """`(B, T)` rows through the time-major recurrence, `(B,)` parameters."""
+    return _hw_predictions_tm(x.T, mask.T, period, alpha, beta, gamma).T
 
 
 # Batched, jitted entry points.
@@ -215,7 +261,7 @@ moving_average_predictions = jax.jit(
 ses_predictions = jax.jit(jax.vmap(_ses_1d, in_axes=(0, 0, 0)))
 des_predictions = jax.jit(jax.vmap(_des_1d, in_axes=(0, 0, 0, 0)))
 holt_winters_predictions = jax.jit(
-    jax.vmap(_hw_1d, in_axes=(0, 0, None, 0, 0, 0)), static_argnames=("period",)
+    _holt_winters_rows, static_argnames=("period",)
 )
 
 
@@ -348,17 +394,99 @@ def detect_period(x, mask, candidates: tuple, fallback, min_acf,
 # Holt-Winters grid fit: per series, pick (alpha, beta, gamma) minimizing
 # masked SSE over the historical region.
 # ---------------------------------------------------------------------------
+_HW_ALPHAS = (0.1, 0.3, 0.5, 0.7, 0.9)
+_HW_BETAS = (0.0, 0.1, 0.3)
+_HW_GAMMAS = (0.05, 0.1, 0.3, 0.5)
+HW_CANDIDATES = len(_HW_ALPHAS) * len(_HW_BETAS) * len(_HW_GAMMAS)  # 60
+
+
 def _default_grid():
-    a = jnp.asarray([0.1, 0.3, 0.5, 0.7, 0.9], _F)
-    b = jnp.asarray([0.0, 0.1, 0.3], _F)
-    g = jnp.asarray([0.05, 0.1, 0.3, 0.5], _F)
+    a = jnp.asarray(_HW_ALPHAS, _F)
+    b = jnp.asarray(_HW_BETAS, _F)
+    g = jnp.asarray(_HW_GAMMAS, _F)
     A, B, G = jnp.meshgrid(a, b, g, indexing="ij")
     return jnp.stack([A.ravel(), B.ravel(), G.ravel()], axis=-1)  # (60, 3)
 
 
+@jax.jit
+def hw_fit_mask(hist_mask, period):
+    """The slots a Holt-Winters fit is scored on: history from the third
+    period on (the first seeds the season, the second settles it)."""
+    return hist_mask & (jnp.arange(hist_mask.shape[-1]) >= 2 * period)
+
+
+# The seasonal state of the side-by-side fit, `(period, candidates, rows)`
+# float32, that one pass may hold on the device beside the launch's block,
+# its gathered partition, their transposes and the predictions (about 5e9
+# bytes at a full (16384, 16384) chunk on a 16 GB chip; the compiler pads
+# the candidate axis to a multiple of 8). Over it, the grid runs in the
+# fewest equal groups whose state fits, one after another.
+_HW_STATE_BYTES_MAX = 6_600_000_000
+
+
+def _hw_groups(period: int, n_candidates: int, rows: int) -> int:
+    """The fewest equal groups of candidates whose seasonal state fits
+    `_HW_STATE_BYTES_MAX`, from the shapes alone."""
+    return next(
+        g for g in range(1, n_candidates + 1)
+        if n_candidates % g == 0 and (
+            g == n_candidates
+            or 4 * period * (n_candidates // g) * rows <= _HW_STATE_BYTES_MAX))
+
+
+def hw_state_bytes(period: int, n_candidates: int, rows: int) -> int:
+    """Bytes of seasonal state `fit_holt_winters` holds at once: the
+    `(period, candidates of a group, rows)` float32 season array."""
+    per_group = n_candidates // _hw_groups(period, n_candidates, rows)
+    return 4 * period * per_group * rows
+
+
+def _hw_grid_errors(xT, mT, fT, period: int, grid):
+    """Mean squared one-step residual of every candidate of `grid` `(G, 3)`
+    over the fit slots of time-major rows: `(G, B)`. The candidates run
+    side by side through one pass over time: level, trend and the running
+    squared error are `(G, B)`, the season `(period, G, B)`; no `(G, B, T)`
+    predictions exist. The pass ends at the last slot any row fits on, so
+    a bucket's padding and the judged window are not walked."""
+    T, B = xT.shape
+    G = grid.shape[0]
+    alpha, beta, gamma = (grid[:, i][:, None] for i in range(3))
+    l0, s0 = _hw_init(xT, mT, period)
+    fit = fT & mT
+    n_steps = jnp.max(jnp.where(jnp.any(fit, axis=1), jnp.arange(1, T + 1), 0))
+
+    def body(t, carry):
+        l, b, sse, season, idx = carry
+        xt = lax.dynamic_index_in_dim(xT, t, 0, keepdims=False)
+        mt = lax.dynamic_index_in_dim(mT, t, 0, keepdims=False)
+        ft = lax.dynamic_index_in_dim(fit, t, 0, keepdims=False)
+        s_t = lax.dynamic_index_in_dim(season, idx, 0, keepdims=False)
+        pred, l, b, s_new = _hw_step(l, b, s_t, xt, mt, alpha, beta, gamma)
+        r = jnp.where(ft, xt - pred, 0.0)
+        season = lax.dynamic_update_index_in_dim(season, s_new, idx, 0)
+        return (l, b, sse + r * r, season,
+                jnp.where(idx + 1 == period, 0, idx + 1))
+
+    init = (jnp.broadcast_to(l0, (G, B)), jnp.zeros((G, B), _F),
+            jnp.zeros((G, B), _F),
+            jnp.broadcast_to(s0[:, None, :], (period, G, B)), jnp.int32(0))
+    sse = lax.fori_loop(0, n_steps, body, init)[2]
+    n = jnp.maximum(jnp.sum(fit.astype(_F), axis=0), 1.0)
+    return sse / n
+
+
 @partial(jax.jit, static_argnames=("period",))
 def fit_holt_winters(x, mask, fit_mask, period: int, grid=None):
-    """Grid-fit HW per series.
+    """Grid-fit HW per series (the recurrence: `_hw_predictions_tm`).
+
+    The fit is the masked mean squared one-step residual over the slots
+    of `fit_mask & mask`, for each candidate of the grid; the least wins,
+    the first on an exact tie (so a row with no fit slot, whose errors are
+    all 0, takes the grid's first candidate); the predictions are the
+    winner's. Two sequential passes over time: the candidates side by
+    side (`_hw_grid_errors`; in `_hw_groups` equal groups, one after
+    another, where their seasonal state would not fit the device), then
+    the winners.
 
     Args:
       x, mask: (B, T).
@@ -372,25 +500,19 @@ def fit_holt_winters(x, mask, fit_mask, period: int, grid=None):
     """
     if grid is None:
         grid = _default_grid()
-
-    def per_candidate(params):
-        a, b, g = params[0], params[1], params[2]
-        preds = jax.vmap(_hw_1d, in_axes=(0, 0, None, None, None, None))(
-            x, mask, period, a, b, g
-        )
-        r = jnp.where(fit_mask & mask, x - preds, 0.0)
-        n = jnp.maximum(jnp.sum((fit_mask & mask).astype(_F), axis=-1), 1.0)
-        return jnp.sum(r * r, axis=-1) / n  # (B,)
-
-    # lax.map keeps device memory at O(G*B) scores instead of materializing
-    # (G, B, T) candidate predictions; each candidate is still fully vmapped
-    # over the batch. The winner's predictions are recomputed once below.
-    sses = lax.map(per_candidate, grid)  # (G, B)
+    xT, mT, fT = x.T.astype(_F), mask.T, fit_mask.T
+    G = grid.shape[0]
+    groups = _hw_groups(period, G, x.shape[0])
+    if groups == 1:
+        sses = _hw_grid_errors(xT, mT, fT, period, grid)
+    else:
+        sses = lax.map(
+            lambda part: _hw_grid_errors(xT, mT, fT, period, part),
+            grid.reshape(groups, G // groups, 3)).reshape(G, x.shape[0])
     best = jnp.argmin(sses, axis=0)  # (B,)
     params = grid[best]
-    preds = jax.vmap(_hw_1d, in_axes=(0, 0, None, 0, 0, 0))(
-        x, mask, period, params[:, 0], params[:, 1], params[:, 2]
-    )
+    preds = _hw_predictions_tm(
+        xT, mT, period, params[:, 0], params[:, 1], params[:, 2]).T
     return params, preds
 
 
@@ -492,6 +614,24 @@ def region_masks(mask, n_hist, n_total):
     that take them as arguments."""
     region = judged_region(n_hist, n_total, mask.shape[-1])
     return region, mask & ~region
+
+
+@jax.jit
+def take_rows(rows, *blocks):
+    """Rows `rows` (an int32 index whose length is a batch rung) of each
+    device block: a period's partition of a band launch."""
+    return tuple(b[rows] for b in blocks)
+
+
+@partial(jax.jit, static_argnames=("n_rows",))
+def scatter_rows(acc, part, rows, n_rows: int):
+    """`part`'s per-row results written at `rows` of `acc` (a dict of
+    `(n_rows, ...)` arrays, made of zeros where `acc` is None): a
+    partition's results back in the launch's row order."""
+    if acc is None:
+        acc = {k: jnp.zeros((n_rows,) + v.shape[1:], v.dtype)
+               for k, v in part.items()}
+    return {k: acc[k].at[rows].set(part[k]) for k in part}
 
 
 @jax.jit

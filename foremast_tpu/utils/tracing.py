@@ -89,11 +89,14 @@ SPAN_ENGINE_LSTM_TRAIN = "engine.lstm_train"
 SPAN_ENGINE_TRIAGE = "engine.triage"
 SPAN_ENGINE_VERDICT = "engine.verdict"
 # the cycle's partition (engine.cycle's children, in cycle order): claim,
-# preprocess, advance, score (dispatch > launch, collect > materialize),
-# fold, publish
+# preprocess, advance, score (dispatch > launch > detect_period, collect >
+# materialize), fold, publish
 SPAN_ENGINE_ADVANCE = "engine.advance"
 SPAN_ENGINE_DISPATCH = "engine.dispatch"
 SPAN_ENGINE_LAUNCH = "engine.launch"
+# a seasonal band launch's one wait: from the enqueue of period detection
+# to the (rows,) periods on the host (under engine.launch)
+SPAN_ENGINE_DETECT_PERIOD = "engine.detect_period"
 SPAN_ENGINE_COLLECT = "engine.collect"
 SPAN_ENGINE_MATERIALIZE = "engine.materialize"
 SPAN_ENGINE_FOLD = "engine.fold"
@@ -141,7 +144,8 @@ SPAN_NAMES = frozenset({
     SPAN_ENGINE_CYCLE, SPAN_ENGINE_CLAIM, SPAN_ENGINE_PREPROCESS,
     SPAN_ENGINE_SCORE, SPAN_ENGINE_LSTM_TRAIN, SPAN_ENGINE_TRIAGE,
     SPAN_ENGINE_VERDICT, SPAN_ENGINE_ADVANCE, SPAN_ENGINE_DISPATCH,
-    SPAN_ENGINE_LAUNCH, SPAN_ENGINE_COLLECT, SPAN_ENGINE_MATERIALIZE,
+    SPAN_ENGINE_LAUNCH, SPAN_ENGINE_DETECT_PERIOD, SPAN_ENGINE_COLLECT,
+    SPAN_ENGINE_MATERIALIZE,
     SPAN_ENGINE_FOLD, SPAN_ENGINE_PUBLISH, SPAN_ENGINE_ROUTE,
     SPAN_ENGINE_ROUTE_CPU, SPAN_ENGINE_MEMO_FP,
     SPAN_INGEST_RECEIVE, SPAN_INGEST_FORWARD, SPAN_INGEST_WAL,

@@ -548,7 +548,7 @@ def test_crash_resume_e2e_snapshot_plus_lease_takeover(tmp_path):
 
 
 def test_score_chunks_fixed_buckets_and_edge_padding():
-    """_score_chunks: chunked results equal a single whole-batch call, and
+    """_launch_chunks + _collect_chunks: chunked results equal a single whole-batch call, and
     batch sizes map to FIXED buckets so fleet-size changes cannot force
     recompiles (B<=bucket pads up; B>chunk splits)."""
     from foremast_tpu.dataplane import FixtureDataSource
@@ -563,14 +563,14 @@ def test_score_chunks_fixed_buckets_and_edge_padding():
     rng = np.random.default_rng(0)
     vals = rng.normal(0, 1, (70, 8)).astype(np.float32)
     mask = rng.random((70, 8)) > 0.5
-    out = eng._score_chunks(fn, [vals, mask])
+    out = eng._collect_chunks(eng._launch_chunks(fn, [vals, mask]))
     # full chunks launch at 32; the 6-row tail re-buckets DOWN the ladder
     assert calls == [32, 32, 16]
     np.testing.assert_allclose(out["s"], vals.sum(axis=1), rtol=1e-6)
     np.testing.assert_array_equal(out["m"], mask.any(axis=1))
     # small batches pad UP to a fixed bucket, not down to raw B
     calls.clear()
-    eng._score_chunks(fn, [vals[:5], mask[:5]])
+    eng._collect_chunks(eng._launch_chunks(fn, [vals[:5], mask[:5]]))
     assert calls == [16]
 
 

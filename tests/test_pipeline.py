@@ -542,7 +542,7 @@ def test_score_chunks_rung_boundary_no_padding():
         return {"s": vals.sum(axis=1)}
 
     vals = np.ones((64, 4), np.float32)
-    out = eng._score_chunks(fn, [vals])
+    out = eng._collect_chunks(eng._launch_chunks(fn, [vals]))
     assert calls == [64]
     assert out["s"].shape == (64,)
 
@@ -559,7 +559,7 @@ def test_score_chunks_big_fleet_tail_pads_to_own_rung():
         return {"s": vals.sum(axis=1)}
 
     vals = np.arange(70, dtype=np.float32)[:, None] * np.ones(4, np.float32)
-    out = eng._score_chunks(fn, [vals])
+    out = eng._collect_chunks(eng._launch_chunks(fn, [vals]))
     assert calls == [64, 16]
     np.testing.assert_allclose(out["s"], vals.sum(axis=1))
 
