@@ -382,6 +382,12 @@ def _render_explain(payload: dict) -> str:
             ("fetch_delta", "delta"), ("fetch_unmoved", "unmoved"),
             ("fetch_ingest", "pushed"), ("fetch_full", "full"),
             ("fetch_cached", "cached")) if fetch.get(note)]
+        if fetch.get("fetch_delta") and fetch.get("fetch_append"):
+            # of the deltas (the first label), those the append rule grew
+            # without a splice
+            appends = int(fetch["fetch_append"])
+            mode[0] += (" (append)" if appends == fetch["fetch_delta"]
+                        else f" ({appends} append)")
         if mode:
             parts.append("/".join(mode))
         if fetch.get("points"):

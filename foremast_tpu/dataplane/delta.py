@@ -44,6 +44,16 @@ window the entry holds, so ``fetch_window`` returns it without a backend
 query, a splice or the splice lock (``_unmoved``; counted as
 ``unmoved_hits``). A fixed baseline or historical range of a canary is
 one query in its life, not one a cycle.
+
+The append rule (``_append_tail``; counted as ``append_hits``): when the
+first slot the range keeps and the last slot of the cached exact grid
+hold valid samples, and the tail the delta query returned is contiguous,
+on the grid, starts on the first slot it re-read and equals the cached
+slots over the overlap, the new window is the cached slots below the tail
+plus the tail: scalars decide it and slice copies build it. The query is
+still made and the overlap still compared; what is no longer rebuilt is
+every cached sample's timestamp and the grid geometry. Any other entry or
+tail is spliced as before, from the same response.
 """
 from __future__ import annotations
 
@@ -199,6 +209,16 @@ def _split_finite(ts, vals):
     return ts, vals, np.unique(ts[bad])
 
 
+def _cached_sample_ts(w, nan_ts, qstart):
+    """(valid_ts, sample_ts): the timestamp of every sample the cached
+    grid `w` holds (slot time == sample time on an exact grid), and of
+    those and the NaN-valued samples at or after `qstart`."""
+    valid_ts = (w.start
+                + np.nonzero(w.mask)[0].astype(np.float64) * w.step)
+    sample_ts = np.concatenate([valid_ts, nan_ts])
+    return valid_ts, sample_ts[sample_ts >= qstart]
+
+
 def _note_fetch_seconds(lock_wait: float, lock_held: float,
                         source: float) -> None:
     """Where one fetch's seconds went, on the caller's open per-job notes
@@ -266,6 +286,7 @@ class DeltaWindowSource:
         self._cpu_lock = make_lock("dataplane.delta.splice_cpu")
         # observability (served on /metrics and /status)
         self.delta_hits = 0        # spliced windows
+        self.append_hits = 0       # of them, by the append rule
         self.unmoved_hits = 0      # closed, unmoved ranges served as cached
         self.full_fetches = 0      # misses + fallbacks + non-capable URLs
         self.fallbacks: dict[str, int] = {}  # reason -> count
@@ -301,6 +322,7 @@ class DeltaWindowSource:
         return {
             "entries": entries,
             "delta_hits": self.delta_hits,
+            "append_hits": self.append_hits,
             "unmoved_hits": self.unmoved_hits,
             "full_fetches": self.full_fetches,
             "hit_ratio": round(hits / total, 4) if total else 0.0,
@@ -946,15 +968,26 @@ class DeltaWindowSource:
         t0 = time.perf_counter()
         with self._cpu_lock:
             t1 = time.perf_counter()
-            w = entry.win
-            valid_ts = (w.start
-                        + np.nonzero(w.mask)[0].astype(np.float64) * w.step)
-            sample_ts = np.concatenate([valid_ts, entry.nan_ts])
-            sample_ts = sample_ts[sample_ts >= qstart]
-            if sample_ts.size == 0:
-                self._count_fallback("empty_cache_range")
-                return None
-            last_end = float(np.max(sample_ts))
+            w, nan_ts = entry.win, entry.nan_ts
+            n = w.values.shape[0]
+            # the append rule's half that is known before the query. An
+            # exact grid spans its own first and last sample, and every
+            # sample lies on a slot: when the first slot the range keeps
+            # (`j0`) and the last slot both hold a valid sample, no
+            # NaN-valued sample anchors the span, the newest sample is
+            # the last slot, and no timestamp is rebuilt to learn it
+            j0 = 0 if qstart <= w.start else -int((w.start - qstart) // step)
+            appendable = (w.step == step and j0 < n
+                          and w.mask[j0] and w.mask[n - 1])
+            if appendable:
+                valid_ts = sample_ts = None
+                last_end = float(w.start + (n - 1) * step)
+            else:
+                valid_ts, sample_ts = _cached_sample_ts(w, nan_ts, qstart)
+                if sample_ts.size == 0:
+                    self._count_fallback("empty_cache_range")
+                    return None
+                last_end = float(np.max(sample_ts))
             delta_start = max(qstart, last_end - self.overlap_steps * step)
             if delta_start > qend:
                 self._count_fallback("range_regressed")
@@ -969,12 +1002,83 @@ class DeltaWindowSource:
         t3 = time.perf_counter()
         with self._cpu_lock:
             t4 = time.perf_counter()
-            out = self._splice(key, entry, w, valid_ts, sample_ts,
-                               delta_start, qstart, qend, ts_d, vals_d,
-                               nbytes)
+            out = self._append_tail(
+                key, entry, w, nan_ts, j0, delta_start, qstart, qend, ts_d,
+                vals_d, nbytes) if appendable else None
+            appended = out is not None
+            if not appended:
+                # the general path, on the response already in hand: the
+                # backend is asked once a request
+                if appendable:
+                    valid_ts, sample_ts = _cached_sample_ts(w, nan_ts,
+                                                            qstart)
+                out = self._splice(key, entry, w, valid_ts, sample_ts,
+                                   delta_start, qstart, qend, ts_d, vals_d,
+                                   nbytes)
             t5 = time.perf_counter()
         _note_fetch_seconds((t1 - t0) + (t4 - t3), (t2 - t1) + (t5 - t4),
                             t3 - t2)
+        if appended:
+            tracing.tracer.add_note("fetch_append")
+        return out
+
+    def _append_tail(self, key, entry, w, nan_ts, j0, delta_start, qstart,
+                     qend, ts_d, vals_d, nbytes) -> Window | None:
+        """The append rule's half that reads the response (caller holds
+        the cpu lock, and found the entry appendable: `w` is an exact grid
+        at the source's step, slot `j0` is the first the range keeps, it
+        and the last slot hold valid samples; `nan_ts` are the entry's
+        NaN-valued samples' timestamps, all inside `w`). Returns the grown
+        Window, or None for the general splice.
+
+        The tail must be what a backend that only appends returns for
+        `[delta_start, qend]`: one finite (as float32) sample on every
+        grid slot from the first slot it re-read to its newest, which is
+        no older than the cached newest. Then a full refetch grids to the
+        cached slots from `j0` up to the tail plus the tail, provided the
+        overlap (bar the one most recent cached point, as the canary in
+        `_splice` has it) is all valid samples and equals the tail there."""
+        step = self.step
+        ts_d = np.asarray(ts_d, np.float64)
+        vals_d = np.asarray(vals_d, np.float64)
+        k = ts_d.size
+        if k == 0 or ts_d.ndim != 1 or vals_d.shape != ts_d.shape:
+            return None
+        n = w.values.shape[0]
+        start = w.start + j0 * step
+        # the first slot the query re-read: past the range's first slot,
+        # `delta_start` is a slot of the grid
+        first = max(int(delta_start), start)
+        i0 = (first - w.start) // step
+        if (ts_d[0] != first  # a hole, a backfilled head, off the grid
+                or i0 + k < n  # the backend lost the newest sample(s)
+                or i0 - j0 + k > MAX_WINDOW_STEPS  # the head would be cut
+                or first + (k - 1) * step >= min(TS_SPAN_CAP, 2.0**53)):
+            return None
+        with np.errstate(over="ignore", invalid="ignore"):
+            # a gap, a repeat, an out-of-order or a non-finite timestamp
+            ragged = np.count_nonzero(ts_d[1:] - ts_d[:-1] != step)
+            v32 = vals_d.astype(np.float32)  # the cast IS the finiteness check
+        m = n - 1 - i0  # overlap slots the canary compares
+        if (ragged or np.count_nonzero(np.isfinite(v32)) != k
+                or np.count_nonzero(w.mask[i0:n - 1]) != m
+                or np.count_nonzero(w.values[i0:n - 1] != v32[:m])):
+            return None
+        kept = i0 - j0  # cached slots below the tail
+        values = np.empty(kept + k, np.float32)
+        values[:kept] = w.values[j0:i0]
+        values[kept:] = v32
+        mask = np.empty(kept + k, bool)
+        mask[:kept] = w.mask[j0:i0]
+        mask[kept:] = True
+        out = Window(values, mask, start, step)
+        if nan_ts.size:
+            # NaN-valued samples the range keeps and the tail did not
+            # re-read (the tail holds none)
+            nan_ts = nan_ts[(nan_ts >= start) & (nan_ts < first)]
+        self._refresh(key, entry, out, nan_ts, qstart, qend, k,
+                      int(np.count_nonzero(mask)) + nan_ts.size, nbytes,
+                      appended=True)
         return out
 
     def _splice(self, key, entry, w, valid_ts, sample_ts, delta_start,
@@ -1040,9 +1144,18 @@ class DeltaWindowSource:
         if nan_ts.size > _MAX_NAN_TS:
             self._count_fallback("off_grid")
             return None
-        points = int(ts_d.size)
-        total_points = int(out.mask.sum() + nan_ts.size)
+        self._refresh(key, entry, out, nan_ts, qstart, qend, int(ts_d.size),
+                      int(out.mask.sum() + nan_ts.size), nbytes)
+        return out
+
+    def _refresh(self, key, entry, out, nan_ts, qstart, qend, points,
+                 total_points, nbytes, appended=False) -> None:
+        """Accounting and entry refresh of one delta hit: `points` samples
+        in `nbytes` came from the backend, and the entry now holds `out`,
+        `total_points` samples in all."""
         with self._lock:
+            if appended:
+                self.append_hits += 1
             self.bytes_delta += nbytes
             self.points_saved += max(entry.full_points - points, 0)
             if nbytes and entry.full_bytes:
@@ -1073,4 +1186,3 @@ class DeltaWindowSource:
             # correct to return, but a bare move_to_end would KeyError
             if self._cache.get(key) is entry:
                 self._cache.move_to_end(key)
-        return out

@@ -2420,6 +2420,7 @@ class Analyzer:
         # `time.thread_time` is a system call), and the pool's notes
         pool: dict = {}
         busy = 0.0
+        appends = 0  # delta fetches the append rule served (their notes)
         with tracing.span(tracing.SPAN_ENGINE_PREPROCESS,
                           jobs=len(claimed)) as prep_sp:
             for doc in claimed:
@@ -2432,6 +2433,7 @@ class Analyzer:
                 stages["preprocess"] += t_got - t_wait
                 if fetch_notes:
                     states[doc_id].fetch = fetch_notes
+                    appends += fetch_notes.get("fetch_append", 0)
                 if failed:
                     states[doc_id].failed = failed
                 else:
@@ -2450,6 +2452,7 @@ class Analyzer:
             part, memo_counts = self._book_pieces(
                 prep_sp, pipe, stages["preprocess"], busy,
                 time.thread_time() - c_stream, pool)
+            prep_sp.attrs["splice_appends"] = int(appends)
         with tracing.span(tracing.SPAN_ENGINE_ADVANCE) as advance_sp:
             shed_ids: list = []
             for doc_id, st in states.items():

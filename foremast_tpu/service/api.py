@@ -641,6 +641,11 @@ class ForemastService:
             snap = self.delta_source.snapshot()
             lines.append(
                 f"foremastbrain:delta_fetch_hits_total {snap['delta_hits']}")
+            # of them, windows the append rule grew by a contiguous tail
+            # without rebuilding the cached timestamps
+            lines.append(
+                "foremastbrain:delta_fetch_append_total "
+                f"{snap['append_hits']}")
             # closed, unmoved ranges answered from the window cache with
             # no backend query (a canary's fixed baseline and history)
             lines.append(

@@ -266,15 +266,19 @@ def test_delta_bytes_saved_accounting():
     assert snap["hit_ratio"] == 0.5
 
 
-@pytest.mark.parametrize("path", ["full", "delta"])
+@pytest.mark.parametrize("path", ["full", "delta", "append"])
 def test_fetch_notes_where_its_seconds_went(path):
     """Under an open per-job note accumulator (the engine's fetch pool)
     a fetch says how long it queued for the splice lock, held it, and sat
-    in the inner source's call; with none open it notes nothing."""
+    in the inner source's call; with none open it notes nothing. A delta
+    the append rule served notes `fetch_append` beside `fetch_delta`."""
     import time
 
     be = _Backend()
     be.series["a"] = [(T0 + i * STEP, float(i)) for i in range(200)]
+    if path == "delta":
+        # a NaN-valued newest sample in the entry: the general splice
+        be.series["a"][-1] = (T0 + 199 * STEP, float("nan"))
     inner = be.source()
     slow = inner.fetch_series
 
@@ -285,7 +289,7 @@ def test_fetch_notes_where_its_seconds_went(path):
     inner.fetch_series = fetch_series
     dsrc = DeltaWindowSource(inner)
     end = T0 + 199 * STEP
-    if path == "delta":
+    if path != "full":
         dsrc.fetch_window(_url("a", T0, end))  # no notes open: a no-op
         be.series["a"].append((end + STEP, 1.0))
         end += STEP
@@ -294,12 +298,406 @@ def test_fetch_notes_where_its_seconds_went(path):
     dsrc.fetch_window(_url("a", T0, end))
     elapsed = time.perf_counter() - t0
     notes = tracing.tracer.take_notes()
-    assert notes["fetch_" + path] == 1
+    assert notes["fetch_" + ("full" if path == "full" else "delta")] == 1
+    assert notes.get("fetch_append", 0) == (path == "append")
+    assert dsrc.append_hits == (path == "append")
     assert notes["source_thread_seconds"] >= 0.01
     assert notes["lock_held_seconds"] > 0
     assert notes["lock_wait_thread_seconds"] >= 0
     assert (notes["source_thread_seconds"] + notes["lock_held_seconds"]
             + notes["lock_wait_thread_seconds"]) <= elapsed
+
+
+# ------------------------------------------------------- the append rule
+_ENTRY_FIELDS = ("qstart", "qend", "url_step", "full_points", "full_bytes",
+                 "dirty", "pushed_until", "push_blocked")
+_SOURCE_COUNTERS = ("delta_hits", "unmoved_hits", "ingest_hits",
+                    "full_fetches", "bytes_delta", "points_saved",
+                    "bytes_saved", "fallbacks")
+
+
+def _assert_sources_agree(dsrc, twin, ctx=""):
+    """Two delta sources fed the same requests hold the same entries
+    (window, NaN timestamps, every field) and the same counters."""
+    assert ({c: getattr(dsrc, c) for c in _SOURCE_COUNTERS}
+            == {c: getattr(twin, c) for c in _SOURCE_COUNTERS}), ctx
+    assert list(dsrc._cache) == list(twin._cache), ctx
+    for key, mine in dsrc._cache.items():
+        twins = twin._cache[key]
+        _assert_windows_equal(mine.win, twins.win, ctx)
+        assert mine.win.values.dtype == twins.win.values.dtype, ctx
+        assert mine.win.mask.dtype == twins.win.mask.dtype, ctx
+        assert mine.nan_ts.tolist() == twins.nan_ts.tolist(), ctx
+        assert ({f: getattr(mine, f) for f in _ENTRY_FIELDS}
+                == {f: getattr(twins, f) for f in _ENTRY_FIELDS}), ctx
+
+
+class _Append:
+    """One backend and three sources over it: one that may append, a twin
+    whose append rule always declines (the general splice, on the same
+    response), and a full refetch. `fetch` holds the window three ways,
+    the entry's fields and every counter to the twin's, and returns how
+    many backend requests the request cost."""
+
+    def __init__(self, n, lead=0):
+        # the range starts `lead` steps before the first sample, where a
+        # case would otherwise grow its span into the next cache-key bucket
+        self.start = T0 - lead * STEP
+        self.be = _Backend()
+        self.be.series["a"] = [(T0 + i * STEP, float(i % 13) + 0.25)
+                               for i in range(n)]
+        self.end = T0 + (n - 1) * STEP  # the newest sample
+        # the clock sits on the newest sample: no range is closed
+        self.inner, self.tinner = self.be.source(), self.be.source()
+        self.dsrc = DeltaWindowSource(self.inner, clock=lambda: self.end)
+        self.twin = DeltaWindowSource(self.tinner, clock=lambda: self.end)
+        self.twin._append_tail = lambda *a, **kw: None
+        self.full_window = self.be.source().fetch_window
+
+    def add(self, *vals):
+        """Samples on the next grid slots."""
+        for v in vals:
+            self.end += STEP
+            self.be.series["a"].append((self.end, v))
+
+    def url(self, start=None):
+        return _url("a", self.start if start is None else start, self.end)
+
+    def entries(self):
+        return [next(iter(s._cache.values()), None)
+                for s in (self.dsrc, self.twin)]
+
+    def fetch(self, url=None, appended=True, requests=1):
+        url = url or self.url()
+        before = self.inner.request_count, self.tinner.request_count
+        hits = self.dsrc.append_hits
+        tracing.tracer.begin_notes()
+        win = self.dsrc.fetch_window(url)
+        notes = tracing.tracer.take_notes()
+        _assert_windows_equal(win, self.twin.fetch_window(url), "twin")
+        _assert_windows_equal(win, self.full_window(url), "full")
+        _assert_sources_agree(self.dsrc, self.twin)
+        assert self.dsrc.append_hits - hits == appended
+        assert self.twin.append_hits == 0
+        assert notes.get("fetch_append", 0) == appended
+        assert self.dsrc.snapshot()["append_hits"] == self.dsrc.append_hits
+        asked = self.inner.request_count - before[0]
+        assert asked == self.tinner.request_count - before[1] == requests
+        return win
+
+
+def _append_one_new_sample(h):
+    h.fetch(appended=False)  # the prime: a full fetch
+    h.add(7.5)
+    win = h.fetch()
+    assert win.values.shape[0] == 41 and win.values[-1] == np.float32(7.5)
+    # the delta query re-read the overlap: five steps and the newest
+    assert h.inner.requests[-1] == _url("a", h.end - 6 * STEP, h.end)
+    assert (h.dsrc.delta_hits, h.dsrc.full_fetches) == (1, 1)
+    # the most recent cached point may be rewritten, as the canary has it
+    h.be.series["a"][-1] = (h.end, -0.0)
+    h.add(8.5)
+    win = h.fetch()
+    assert np.signbit(win.values[-2]) and win.values.shape[0] == 42
+
+
+def _append_k_new_after_a_skipped_cycle(h):
+    h.fetch(appended=False)
+    h.add(1.5, 2.5, 3.5)
+    assert h.fetch().values.shape[0] == 43
+
+
+def _append_no_new_sample(h):
+    """The tail ends at the cached last slot (the `_stale_newest` shape of
+    benchmark/tests): the window comes back as it was, by the rule."""
+    h.fetch(appended=False)
+    win = h.fetch()
+    assert win.values.shape[0] == 40 and h.dsrc.delta_hits == 1
+
+
+def _append_window_of_one_and_two_slots(h):
+    h.fetch(appended=False)  # one slot: shorter than the overlap
+    h.add(2.5)
+    assert h.fetch().values.shape[0] == 2
+    h.add(3.5)
+    assert h.fetch().values.shape[0] == 3
+    # the overlap reaches back past the window's first slot
+    assert h.inner.requests[-1] == _url("a", T0 - 4 * STEP, h.end)
+
+
+def _append_reaches_max_window_steps(h):
+    from foremast_tpu.ops.windowing import MAX_WINDOW_STEPS
+
+    h.fetch(appended=False)
+    h.add(1.5)
+    assert h.fetch().values.shape[0] == MAX_WINDOW_STEPS
+    h.add(2.5)  # one more would clip the head: the general splice
+    win = h.fetch(appended=False)
+    assert win.values.shape[0] == MAX_WINDOW_STEPS and win.start == T0 + STEP
+    assert h.dsrc.delta_hits == 2
+
+
+def _append_keeps_nan_inside_the_entry(h):
+    """A NaN-valued sample between two valid ones anchors no span: the
+    rule holds, and the entry keeps the sample's timestamp."""
+    h.be.series["a"][10] = (T0 + 10 * STEP, float("nan"))
+    h.fetch(appended=False)
+    h.add(1.5)
+    h.fetch()
+    assert h.entries()[0].nan_ts.tolist() == [T0 + 10 * STEP]
+    assert h.entries()[0].full_points == 41
+
+
+def _append_declines_nan_newest_in_entry(h):
+    h.be.series["a"][-1] = (h.end, float("nan"))
+    h.fetch(appended=False)
+    assert not h.entries()[0].win.mask[-1]
+    h.add(1.5)
+    h.fetch(appended=False)
+    assert h.dsrc.delta_hits == 1
+    h.add(2.5)  # the NaN-valued sample now lies in the overlap
+    h.fetch(appended=False)
+    for _ in range(4):
+        h.add(3.5)
+        h.fetch(appended=False)
+    h.add(4.5)  # and has left it
+    h.fetch()
+    assert h.entries()[0].nan_ts.size == 1 and h.dsrc.delta_hits == 7
+
+
+def _append_declines_nan_oldest_in_entry(h):
+    h.be.series["a"][0] = (T0, float("nan"))
+    h.fetch(appended=False)
+    assert not h.entries()[0].win.mask[0]
+    h.add(1.5)
+    h.fetch(appended=False)
+    assert h.dsrc.delta_hits == 1 and h.entries()[0].nan_ts.size == 1
+
+
+def _append_declines_nan_in_tail(h):
+    h.fetch(appended=False)
+    h.add(float("nan"))
+    h.fetch(appended=False)
+    assert h.dsrc.delta_hits == 1 and h.entries()[0].nan_ts.size == 1
+
+
+def _append_declines_gap_in_tail(h):
+    h.fetch(appended=False)
+    h.end += STEP  # a slot with no sample
+    h.add(1.5)
+    win = h.fetch(appended=False)
+    assert h.dsrc.delta_hits == 1 and not win.mask[-2]
+    h.add(2.5)  # the hole now lies in the overlap: still the splice
+    h.fetch(appended=False)
+
+
+def _append_declines_out_of_order_tail(h):
+    h.fetch(appended=False)
+
+    def newest_first(fetch_series):  # the body parsers sort; a source may not
+        def fetch(url):
+            ts, vals, nbytes = fetch_series(url)
+            return ts[::-1], vals[::-1], nbytes
+        return fetch
+
+    for src in (h.inner, h.tinner):
+        src.fetch_series = newest_first(src.fetch_series)
+    h.add(1.5, 2.5)
+    win = h.fetch(appended=False)
+    assert h.dsrc.delta_hits == 1
+    assert win.values[-2:].tolist() == [1.5, 2.5]
+
+
+def _append_declines_tail_after_a_hole(h):
+    """The backend lost the overlap and the newest sample: the tail
+    starts after `last_end + step`, the canary fires, a full refetch."""
+    h.fetch(appended=False)
+    del h.be.series["a"][-6:]
+    h.end += STEP
+    h.add(1.5)
+    h.fetch(appended=False, requests=2)
+    assert h.dsrc.fallbacks == {"splice_mismatch": 1}
+
+
+def _append_declines_lost_newest(h):
+    h.fetch(appended=False)
+    del h.be.series["a"][-1]
+    win = h.fetch(appended=False)
+    assert win.values.shape[0] == 39 and h.dsrc.delta_hits == 1
+
+
+def _append_declines_overlap_rewritten(h):
+    h.fetch(appended=False)
+    h.be.series["a"][-3] = (h.end - 2 * STEP, 1234.0)
+    h.add(1.5)
+    win = h.fetch(appended=False, requests=2)
+    assert h.dsrc.fallbacks == {"splice_mismatch": 1}
+    assert h.dsrc.full_fetches == 2 and win.values[-4] == np.float32(1234.0)
+
+
+def _append_moved_start(h):
+    """A trailing range: the start moved past the window's first samples
+    onto a valid one, and a NaN-valued sample fell out with them."""
+    h.be.series["a"][1] = (T0 + STEP, float("nan"))
+    h.be.series["a"][12] = (T0 + 12 * STEP, float("nan"))
+    h.fetch(appended=False)
+    h.add(1.5)
+    win = h.fetch(h.url(T0 + 2 * STEP - 1))  # off the grid: the next slot
+    assert win.start == T0 + 2 * STEP and win.values.shape[0] == 39
+    assert h.entries()[0].nan_ts.tolist() == [T0 + 12 * STEP]
+    h.add(2.5)
+    assert h.fetch(h.url(T0 + 3 * STEP)).start == T0 + 3 * STEP
+    assert h.dsrc.delta_hits == 2
+
+
+def _append_trailing_range_inside_the_overlap(h):
+    """A trailing range shorter than the overlap: all of it is re-read."""
+    h.fetch(h.url(h.end - 3 * STEP), appended=False)
+    for _ in range(3):
+        h.add(3.5)
+        win = h.fetch(h.url(h.end - 3 * STEP))
+        assert win.values.shape[0] == 4
+        assert h.inner.requests[-1] == h.url(h.end - 3 * STEP)
+    assert h.dsrc.delta_hits == 3
+
+
+def _append_declines_start_on_a_hole(h):
+    del h.be.series["a"][2]
+    h.fetch(appended=False)
+    h.add(1.5)
+    win = h.fetch(h.url(T0 + 2 * STEP), appended=False)
+    assert win.start == T0 + 3 * STEP and h.dsrc.delta_hits == 1
+    assert h.entries()[0].win.values.shape[0] == 38
+
+
+def _append_declines_float32_overflow(h):
+    from foremast_tpu.dataplane.fetch import grid_from_series
+
+    # the refetch as the delta layer grids it (`_full_grid`): the fused
+    # native body-to-window path leaves such a sample in, as inf
+    inner = h.be.source()
+    h.full_window = lambda url: grid_from_series(*inner.fetch(url), STEP)
+    h.fetch(appended=False)
+    h.add(1e39)
+    win = h.fetch(appended=False)
+    assert h.dsrc.delta_hits == 1 and not win.mask[-1]
+
+
+def _append_declines_push_blocked(h):
+    h.fetch(appended=False)
+    h.add(1.5)
+    for src in (h.dsrc, h.twin):
+        src.ingest_block(h.url())
+    h.fetch(appended=False)
+    assert h.dsrc.fallbacks == {"resync": 1} and h.dsrc.full_fetches == 2
+    h.add(2.5)  # the refetch re-primed a trusted entry
+    h.fetch()
+
+
+def _append_declines_step_change(h):
+    h.fetch(appended=False)
+    h.add(1.5)
+    for entry in h.entries():  # as an entry primed under another step=
+        entry.url_step = 120.0
+    h.fetch(appended=False)
+    assert h.dsrc.fallbacks == {"step_change": 1}
+
+
+@pytest.mark.parametrize("case,n,lead", [
+    (_append_one_new_sample, 40, 0),
+    (_append_k_new_after_a_skipped_cycle, 40, 0),
+    (_append_no_new_sample, 40, 0),
+    (_append_window_of_one_and_two_slots, 1, 40),
+    (_append_reaches_max_window_steps, 16383, 1 << 15),
+    (_append_keeps_nan_inside_the_entry, 40, 0),
+    (_append_moved_start, 40, 0),
+    (_append_trailing_range_inside_the_overlap, 40, 0),
+    (_append_declines_nan_newest_in_entry, 40, 0),
+    (_append_declines_nan_oldest_in_entry, 40, 0),
+    (_append_declines_nan_in_tail, 40, 0),
+    (_append_declines_gap_in_tail, 40, 0),
+    (_append_declines_out_of_order_tail, 40, 0),
+    (_append_declines_tail_after_a_hole, 40, 0),
+    (_append_declines_lost_newest, 40, 0),
+    (_append_declines_overlap_rewritten, 40, 0),
+    (_append_declines_start_on_a_hole, 40, 0),
+    (_append_declines_float32_overflow, 40, 0),
+    (_append_declines_push_blocked, 40, 0),
+    (_append_declines_step_change, 40, 0),
+], ids=lambda c: getattr(c, "__name__", "")[8:] or None)
+def test_append_rule_is_the_splice_and_the_full_refetch(case, n, lead):
+    """A contiguous on-grid tail that continues a trusted window is
+    appended without rebuilding the window's timestamps; anything else is
+    spliced as before, from the response already in hand. Every request
+    is held three ways (append, the general splice forced on a twin
+    source, a full refetch): the window, the entry's fields, the source's
+    counters and notes, and the backend requests it cost."""
+    case(_Append(n, lead))
+
+
+@pytest.mark.parametrize("seed,p_gap,p_nan,overlap", [
+    (0, 0.03, 0.02, 5), (1, 0.15, 0.08, 5), (2, 0.03, 0.02, 1),
+    (3, 0.0, 0.0, 5), (4, 0.03, 0.02, 9)])
+def test_append_property_vs_splice_and_full_refetch(seed, p_gap, p_nan,
+                                                    overlap):
+    """Randomized rounds over a mostly regular series (the append rule's
+    traffic) with what must decline it mixed in: gaps, NaN-valued and
+    float32-overflowing samples, skipped and empty cycles, rewrites and
+    deletions inside the overlap. A fixed-start and two trailing ranges
+    are fetched every round through a source that may append, a twin
+    whose rule always declines, and a full refetch: windows, entries,
+    counters and backend requests agree, and both paths ran."""
+    from foremast_tpu.dataplane.fetch import grid_from_series
+
+    rng = np.random.default_rng(seed)
+    be = _Backend()
+    clock = {"now": 0.0}  # on the newest slot: no range is closed
+    srcs = [DeltaWindowSource(be.source(), clock=lambda: clock["now"],
+                              overlap_steps=overlap) for _ in range(2)]
+    dsrc, twin = srcs
+    twin._append_tail = lambda *a, **kw: None
+    full = be.source()
+
+    def value():
+        u = rng.random()
+        if u < p_nan:
+            return float("nan")
+        return 1e39 if u < p_nan + 0.003 else round(float(rng.normal(10, 2)), 4)
+
+    series = be.series["a"] = [(T0 + i * STEP, value()) for i in range(50)
+                               if rng.random() >= p_gap]
+    end = T0 + 49 * STEP
+    for round_i in range(120):
+        prev_end = end
+        for _ in range(int(rng.choice([0, 1, 1, 1, 1, 2, 3]))):
+            end += STEP
+            if rng.random() >= p_gap:
+                series.append((end, value()))
+        clock["now"] = float(end)
+        # history moves only where every range's next delta query looks
+        hit = prev_end - int(rng.integers(0, overlap + 1)) * STEP
+        at = [j for j in range(max(len(series) - 12, 0), len(series))
+              if series[j][0] == hit]
+        if at and rng.random() < 0.05:
+            series[at[0]] = (hit, round(float(rng.normal(10, 2)), 4))
+        elif at and len(series) > 12 and rng.random() < 0.02:
+            del series[at[0]]
+        for url in (_url("a", T0 - 64 * STEP, end),
+                    _url("a", end - 25 * STEP, end),
+                    _url("a", end - 3 * STEP - 7, end)):
+            ctx = f"seed {seed} round {round_i} {url}"
+            before = [s.inner.request_count for s in srcs]
+            win = dsrc.fetch_window(url)
+            _assert_windows_equal(win, twin.fetch_window(url), ctx)
+            _assert_windows_equal(
+                win, grid_from_series(*full.fetch(url), STEP), ctx)
+            asked = [s.inner.request_count - b for s, b in zip(srcs, before)]
+            assert asked[0] == asked[1] and asked[0] in (1, 2), ctx
+            _assert_sources_agree(dsrc, twin, ctx)
+    assert twin.append_hits == 0
+    assert dsrc.append_hits > 20  # the rule ran
+    if p_gap:
+        assert dsrc.delta_hits - dsrc.append_hits > 20  # so did the splice
 
 
 # ------------------------------------------------- the closed-range rule
@@ -565,7 +963,8 @@ def _run_rolling(delta: bool, jobs=5, cycles=4, W=20, H=200):
     that grows by one sample a cycle, and a baseline and a history whose
     ranges are fixed timestamps in the past. Returns per-cycle verdict
     snapshots (store state and every record's scores), backend requests
-    per cycle, and each job's fetch record."""
+    per cycle beside the cycle's `splice_appends`, and each job's fetch
+    record."""
     be = _Backend()
     rng = np.random.default_rng(11)
     store = JobStore()
@@ -605,6 +1004,13 @@ def _run_rolling(delta: bool, jobs=5, cycles=4, W=20, H=200):
         snaps.append((_snapshot(store),
                       {j: r["families"] for j, r in recs.items()}))
         fetches.append({j: r["fetch"] for j, r in recs.items()})
+        # what the cycle's engine.preprocess span says of its appends
+        root = next(t for t in reversed(tracing.tracer.snapshot(limit=64))
+                    if t["attrs"].get("cycle_id")
+                    == eng.last_cycle_stages["cycle_id"])
+        prep = next(c for c in root["children"]
+                    if c["name"] == tracing.SPAN_ENGINE_PREPROCESS)
+        requests[-1] = (requests[-1], prep["attrs"]["splice_appends"])
         clock[0] += STEP - 5.0
     return snaps, requests, fetches, source
 
@@ -620,8 +1026,10 @@ def test_rolling_update_fixed_ranges_one_backend_query_a_cycle():
     snaps_off, req_off, fetch_off, _ = _run_rolling(delta=False, jobs=jobs)
     assert snaps_on == snaps_off
     assert all(len(fams) == 2 for fams in snaps_on[-1][1].values())
-    assert req_on == [3 * jobs, jobs, jobs, jobs]
-    assert req_off == [3 * jobs] * 4
+    # (backend requests, `splice_appends` on engine.preprocess) a cycle:
+    # every steady-state request is the append rule's
+    assert req_on == [(3 * jobs, 0), (jobs, jobs), (jobs, jobs), (jobs, jobs)]
+    assert req_off == [(3 * jobs, 0)] * 4
     for on, off in zip(fetch_on, fetch_off):
         assert ({j: f["points"] for j, f in on.items()}
                 == {j: f["points"] for j, f in off.items()})
@@ -630,14 +1038,19 @@ def test_rolling_update_fixed_ranges_one_backend_query_a_cycle():
     for cyc in fetch_on[1:]:  # steady state
         for f in cyc.values():
             assert f["fetch_delta"] == 1 and f["fetch_unmoved"] == 2
+            assert f["fetch_append"] == 1
             assert "fetch_full" not in f
     assert src.unmoved_hits == 2 * jobs * 3 and src.delta_hits == jobs * 3
+    assert src.append_hits == src.delta_hits
     # and `foremast explain` says how each window was got
     from foremast_tpu.cli import _render_explain
 
     text = _render_explain(
         {"provenance": {"path": "scored", "fetch": fetch_on[-1]["roll0"]}})
-    assert "3 fetch(es), 1 delta/2 unmoved, 245 points" in text
+    assert "3 fetch(es), 1 delta (append)/2 unmoved, 245 points" in text
+    text = _render_explain({"provenance": {"path": "scored", "fetch": {
+        "fetches": 4, "fetch_delta": 3, "fetch_append": 2, "fetch_full": 1}}})
+    assert "4 fetch(es), 3 delta (2 append)/1 full" in text
 
 
 def test_memo_changed_single_row_rescores_only_its_bucket():
@@ -792,8 +1205,12 @@ def test_window_cache_counters_exported():
     be = _Backend()
     be.series["a"] = [(T0, 1.0), (T0 + STEP, 2.0)]
     dsrc = DeltaWindowSource(be.source())
-    dsrc.fetch_window(_url("a", T0, T0 + STEP))
-    dsrc.fetch_window(_url("a", T0, T0 + STEP))  # closed, unmoved: served
+    lead = T0 - 7 * STEP  # so that one more step keeps the cache key
+    dsrc.fetch_window(_url("a", lead, T0 + STEP))
+    dsrc.fetch_window(_url("a", lead, T0 + STEP))  # closed, unmoved: served
+    be.series["a"].append((T0 + 2 * STEP, 3.0))
+    dsrc.fetch_window(_url("a", lead, T0 + 2 * STEP))  # appended
+    assert dsrc.snapshot()["append_hits"] == 1
     svc = ForemastService(JobStore(), exporter=VerdictExporter(),
                           cache_source=cache, delta_source=dsrc)
     _, text = svc.metrics()
@@ -801,15 +1218,17 @@ def test_window_cache_counters_exported():
     assert "foremastbrain:window_cache_misses_total 1" in text
     assert "foremastbrain:window_cache_single_flight_waits_total 0" in text
     assert "foremastbrain:delta_fetch_full_total 1" in text
-    assert "foremastbrain:delta_fetch_hits_total 0" in text
+    assert "foremastbrain:delta_fetch_hits_total 1" in text
+    assert "foremastbrain:delta_fetch_append_total 1" in text
     assert "foremastbrain:delta_fetch_unmoved_total 1" in text
-    assert "foremastbrain:delta_fetch_hit_ratio 0.5" in text
+    assert "foremastbrain:delta_fetch_hit_ratio 0.6667" in text
     status, payload = svc.status_summary()
     assert status == 200
     assert payload["window_cache"] == {
         "hits": 1, "misses": 1, "single_flight_waits": 0}
     assert payload["delta_fetch"]["full_fetches"] == 1
     assert payload["delta_fetch"]["unmoved_hits"] == 1
+    assert payload["delta_fetch"]["append_hits"] == 1
 
 
 # ------------------------------------------------------- lstm train memo
