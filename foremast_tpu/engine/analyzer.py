@@ -373,9 +373,13 @@ class Analyzer:
         self.pack_total_elems_total = 0
         # -- seasonal band launches: partitions by detected period
         # (cumulative), and the largest seasonal state a Holt-Winters fit
-        # of the cycle held on the device, from its shapes (per cycle)
+        # of the cycle held on the device, from its shapes (per cycle);
+        # the columns of the cycle's seasonal-trend fits and the batched
+        # solves they enqueued (per cycle)
         self.period_partitions_total = 0
         self._cycle_hw_state_bytes = 0
+        self._cycle_st_columns = 0
+        self._cycle_st_solves = 0
         # -- single-dispatch mega-batching (MEGABATCH) cumulative
         # counters: launches through the mega path, real rows carried and
         # padding rows added (the packing-efficiency signal satellite
@@ -1230,11 +1234,13 @@ class Analyzer:
         elif algo.startswith("seasonal_trend") or algo.startswith("prophet"):
             period = (period_override if period_override is not None
                       else min(self.config.hw_period, max(xv.shape[1] // 2, 2)))
+            order, cps = self.config.st_order, self.config.st_changepoints
             _, preds = self._call(
                 fc.fit_seasonal_trend,
-                xv, hist_mask, hist_mask, period, self.config.st_order,
-                n_changepoints=self.config.st_changepoints,
+                xv, hist_mask, hist_mask, period, order, n_changepoints=cps,
             )
+            self._cycle_st_columns = fc.st_columns(order, cps)
+            self._cycle_st_solves += fc.st_solves(cps)
         else:  # moving_average_all default
             preds = self._call(fc.moving_average_predictions, xv, hist_mask,
                                self.config.ma_window)
@@ -2408,6 +2414,7 @@ class Analyzer:
         real0, total0 = self.pack_real_elems_total, self.pack_total_elems_total
         parts0 = self.period_partitions_total
         self._cycle_hw_state_bytes = 0
+        self._cycle_st_columns = self._cycle_st_solves = 0
         shed_cycle0 = self.jobs_shed_total
         stale_cycle0 = self.stale_verdicts_served_total
         wd_cycle0 = self.watchdog_fires_total
@@ -2540,7 +2547,9 @@ class Analyzer:
                 "period_partitions": self.period_partitions_total - parts0,
                 "hw_candidates": (fc.HW_CANDIDATES
                                   if self._cycle_hw_state_bytes else 0),
-                "hw_state_bytes": self._cycle_hw_state_bytes}
+                "hw_state_bytes": self._cycle_hw_state_bytes,
+                "st_columns": self._cycle_st_columns,
+                "st_solves": self._cycle_st_solves}
             score_sp.attrs.update(counters)
 
         with tracing.span(tracing.SPAN_ENGINE_FOLD):
@@ -2779,6 +2788,11 @@ class Analyzer:
                 counters["hw_state_bytes"],
                 help="Seasonal state the largest Holt-Winters fit of the "
                      "last cycle held on the device, bytes (0: none ran).")
+            self.exporter.record_gauge(
+                "foremastbrain:st_columns", {}, counters["st_columns"],
+                help="Columns of the last cycle's seasonal-trend (Prophet) "
+                     "fits: 2 + ST_CHANGEPOINTS + 2 x ST_ORDER (0: none "
+                     "ran).")
             triage_cycle = None
             if triage_gate is not None and triage_gate.active:
                 tg = triage_gate

@@ -56,6 +56,8 @@ __all__ = [
     "take_rows",
     "scatter_rows",
     "fit_seasonal_trend",
+    "st_columns",
+    "st_solves",
     "judged_region",
     "region_masks",
     "residual_sigma",
@@ -519,30 +521,108 @@ def fit_holt_winters(x, mask, fit_mask, period: int, grid=None):
 # ---------------------------------------------------------------------------
 # Prophet-style decomposable model: linear trend + Fourier seasonality.
 # ---------------------------------------------------------------------------
+# the fit's settings no deployment sets (docs/configuration.md, ST_*)
+ST_RIDGE = 1e-4       # Tikhonov weight on every column
+ST_CP_SHRINK = 3e-3   # extra weight on the hinge columns' slope deltas
+ST_L1_ITERS = 3       # solves a fit where it has hinges: one ridge, two reweighted
+# float32 through the MXU. The TPU's default for a float32 contraction
+# rounds both operands to bfloat16 (8 mantissa bits): the Gram and the
+# right-hand side below sum up to 16,384 products a column pair, and a
+# fit from bfloat16 operands drew its predictions 0.07 residual sigma
+# off float64 at the (16384, 16384) block (PERF.md, PR 35), 17 times
+# what the moving-average band is held to. HIGHEST (six bfloat16 passes)
+# is float32 arithmetic; the configuration states float32 and the
+# program computes float32 (the rule of ops/pairwise.py's exact null).
+_ST_PRECISION = lax.Precision.HIGHEST
+
+
+def st_columns(order: int, n_changepoints: int) -> int:
+    """D, the columns of a seasonal-trend fit: intercept, slope, one hinge
+    a changepoint, a sine and a cosine a Fourier order."""
+    return 2 + max(n_changepoints, 0) + 2 * order
+
+
+def st_solves(n_changepoints: int, l1_iters: int = ST_L1_ITERS) -> int:
+    """Batched `(B, D, D)` solves one seasonal-trend fit enqueues: the
+    ridge solve, and the reweighting rounds where it has hinges."""
+    return max(l1_iters, 1) if n_changepoints > 0 else 1
+
+
+def _solve_spd(A, r):
+    """x of A x = r for symmetric positive definite A (B, D, D), r (B, D):
+    Gauss-Jordan elimination without pivoting on the symmetrically scaled
+    system (unit diagonal), every row of the batch at once, D rank-one
+    updates of a (B, D, D + 1) array on the vector unit.
+
+    Why not `jnp.linalg.solve`: compiled for a v5e it is the compiler's
+    `LuDecompositionBlock` call, then `InvertDiagBlocks*` on the two
+    triangles and their products with the right-hand side. The explicit
+    inverses cost accuracy on these nearly dependent columns (the cell's
+    bands read 1.4e-3 to 1.8e-3 reference sigmas off float64 where a
+    float64 solve of the same float32 Gram reads 4.8e-4, and this
+    elimination 4.8e-4), and the call costs time: 0.27 s for a fit's three
+    solves at 16,384 rows against 0.0044 s (PERF.md, PR 38's chip runs).
+    Elimination without pivoting is stable for a positive definite matrix
+    (its pivots are Schur complements, positive, and no entry grows), and
+    the fit's matrix is one: a Gram plus a positive diagonal."""
+    d = lax.rsqrt(jnp.diagonal(A, axis1=-2, axis2=-1))
+    M = jnp.concatenate(
+        [A * d[:, :, None] * d[:, None, :], (r * d)[:, :, None]], axis=-1)
+    D = A.shape[-1]
+    for k in range(D):  # static and small: unrolled
+        row = M[:, k, :] / M[:, k, k][:, None]
+        M = (M - M[:, :, k][:, :, None] * row[:, None, :]).at[:, k, :].set(row)
+    return d * M[:, :, D]
+
+
 @partial(jax.jit,
          static_argnames=("period", "order", "n_changepoints", "l1_iters"))
 def fit_seasonal_trend(x, mask, fit_mask, period: int, order: int = 3,
-                       ridge: float = 1e-4, n_changepoints: int = 0,
-                       cp_shrink: float = 3e-3, l1_iters: int = 3):
+                       ridge: float = ST_RIDGE, n_changepoints: int = 0,
+                       cp_shrink: float = ST_CP_SHRINK,
+                       l1_iters: int = ST_L1_ITERS):
     """Fit trend+seasonality per series by masked ridge least squares.
 
     The reference brain's menu lists Prophet for single-metric forecasting
     (docs/guides/design.md:53-88). Prophet's core is a decomposable model
     y(t) = g(t) + s(t): PIECEWISE-linear trend plus a Fourier-series
     seasonality, fit by regularized regression. This is that core,
-    TPU-shaped: closed-form weighted least-squares solves — the normal
-    equations are batched (B, D, D) systems that XLA maps straight onto the
-    MXU, replacing Prophet's per-series Stan/L-BFGS optimizer loop.
+    TPU-shaped: closed-form weighted least-squares solves, the normal
+    equations formed by matrix products on the MXU and solved as batched
+    (B, D, D) systems (`_solve_spd`), replacing Prophet's per-series
+    Stan/L-BFGS loop.
 
-    Changepoints (n_changepoints > 0) add Prophet's defining trend
-    flexibility: hinge columns relu(t - s_j) on a uniform grid over the
-    first 80% of the window (Prophet's default changepoint_range), so the
-    trend may change slope at each s_j. Prophet shrinks the slope deltas
-    with a Laplace (L1) prior to keep the trend piecewise-SPARSE;
-    here that is an iterated ridge (iteratively reweighted least squares
-    approximation of L1: penalty_j = cp_shrink / (|delta_j| + eps),
-    `l1_iters` rounds) — each round is still one batched solve, so the
-    whole fit stays a handful of MXU launches for any fleet size.
+    The equations (`benchmark/lib/reference_st.py` holds the same in numpy
+    float64). Per row, with T the length of the BUCKET the row is packed
+    to, period p, Fourier order K, C changepoints, D = 2 + C + 2K:
+      columns over slots t = 0..T-1, with tn = t / (T - 1):
+        1; tn; hinges max(tn - s_j, 0), s_j = 0.8 j / (C + 1), j = 1..C
+        (a uniform grid over the first 80% of the window, Prophet's
+        default changepoint_range; none at 0, where the delta would be
+        the base slope); sin(2 pi k t / p), cos(2 pi k t / p), k = 1..K.
+        X is (T, D), shared by the rows.
+      sel = mask & fit_mask (present, and in the history).
+      G = X^T diag(sel) X, r = X^T (sel * x).
+      beta_0 = solve(G + diag(ridge + cp_shrink * is_cp), r), is_cp 1 on
+        the hinge columns; then, where C > 0, l1_iters - 1 rounds of
+        pen = ridge + cp_shrink * is_cp / (|beta| + 1e-3),
+        beta = solve(G + diag(pen), r): small deltas are crushed toward 0
+        (sparse kinks), real kinks keep their slope.
+      predictions X beta over every slot: a slot outside sel (the judged
+        window, a gap, the padding) is extrapolated, never fitted.
+
+    Two departures from Prophet as published (PARITY.md): the hinge grid
+    and tn are laid on the padded bucket, not on the row's own history, so
+    X is one (T, D) matrix for every row of a launch (a per-row grid would
+    make it (B, T, D), 21 GB at the full chunk); for a history shorter
+    than 0.8 T the last hinges start at or past its end and are held at 0
+    by their penalty alone. And the Laplace (L1) prior on the slope deltas
+    is `l1_iters` rounds of reweighted ridge, not a posterior mode.
+
+    Precision: the three contractions state `_ST_PRECISION` (HIGHEST),
+    and they are the program's only matrix products: the solves are
+    float32 elimination on the vector unit (`_solve_spd`, which says why
+    `jnp.linalg.solve` is not used).
 
     Args:
       x, mask:   (B, T) values + validity.
@@ -552,11 +632,10 @@ def fit_seasonal_trend(x, mask, fit_mask, period: int, order: int = 3,
       order:     Fourier order K (static).
       ridge:     Tikhonov weight keeping the solve well-posed when a series
                  has few valid points or the window spans < one period.
-      n_changepoints: hinge-grid size C (static); D = 2 + C + 2K columns.
-      cp_shrink: L1-ish penalty scale on the hinge slope deltas (the
-                 analogue of 1/changepoint_prior_scale — larger = straighter
-                 trend).
-      l1_iters:  reweighting rounds (static; 1 = plain ridge on hinges).
+      n_changepoints: hinge-grid size C (static).
+      cp_shrink: penalty scale on the hinge slope deltas (the analogue of
+                 1/changepoint_prior_scale: larger = straighter trend).
+      l1_iters:  solves where C > 0 (static; 1 = plain ridge on hinges).
 
     Returns (beta (B, D), preds (B, T)).
     """
@@ -565,8 +644,6 @@ def fit_seasonal_trend(x, mask, fit_mask, period: int, order: int = 3,
     cols = [jnp.ones(T, _F), tn]
     C = n_changepoints
     if C > 0:
-        # grid over the first 80% of the window; none at t=0 (that slope
-        # delta would be indistinguishable from the base slope)
         s = (jnp.arange(1, C + 1, dtype=_F) / (C + 1)) * 0.8
         cols += [jnp.maximum(tn - sj, 0.0) for sj in s]
     w = 2.0 * jnp.pi * jnp.arange(T, dtype=_F) / period
@@ -575,23 +652,20 @@ def fit_seasonal_trend(x, mask, fit_mask, period: int, order: int = 3,
     X = jnp.stack(cols, axis=-1)  # (T, D)
     D = X.shape[-1]
     sel = (mask & fit_mask).astype(_F)  # (B, T)
-    G = jnp.einsum("td,te,bt->bde", X, X, sel)  # (B, D, D) gram
-    rhs = jnp.einsum("td,bt->bd", X, sel * x.astype(_F))
+    G = jnp.einsum("td,te,bt->bde", X, X, sel,
+                   precision=_ST_PRECISION)  # (B, D, D) gram
+    rhs = jnp.einsum("td,bt->bd", X, sel * x.astype(_F),
+                     precision=_ST_PRECISION)
     # hinge-column indicator for the per-column penalty vector
     is_cp = jnp.zeros(D, _F).at[2:2 + C].set(1.0) if C > 0 else jnp.zeros(D, _F)
 
     def solve(pen):  # pen: (B, D) per-series per-column ridge weights
-        A = G + jax.vmap(jnp.diag)(pen)
-        return jnp.linalg.solve(A, rhs[..., None])[..., 0]  # (B, D)
+        return _solve_spd(G + jax.vmap(jnp.diag)(pen), rhs)  # (B, D)
 
-    pen0 = jnp.broadcast_to(ridge + cp_shrink * is_cp, (B, D))
-    beta = solve(pen0)
-    for _ in range(max(l1_iters - 1, 0) if C > 0 else 0):
-        # IRLS: L1 on deltas ~ ridge with weight 1/|delta| — small deltas
-        # get crushed toward 0 (sparse kinks), real kinks keep their slope
-        pen = ridge + cp_shrink * is_cp / (jnp.abs(beta) + 1e-3)
-        beta = solve(pen)
-    preds = jnp.einsum("td,bd->bt", X, beta)
+    beta = solve(jnp.broadcast_to(ridge + cp_shrink * is_cp, (B, D)))
+    for _ in range(st_solves(C, l1_iters) - 1):
+        beta = solve(ridge + cp_shrink * is_cp / (jnp.abs(beta) + 1e-3))
+    preds = jnp.einsum("td,bd->bt", X, beta, precision=_ST_PRECISION)
     return beta, preds
 
 

@@ -310,7 +310,10 @@ def test_seasonal_trend_matches_numpy_lstsq():
     for b in range(B):
         sel = mask[b]
         beta, *_ = np.linalg.lstsq(X[sel], x[b, sel], rcond=None)
-        np.testing.assert_allclose(np.asarray(preds)[b], X @ beta, atol=1e-2)
+        # float32 sums over some 100 samples of values near 10, six well
+        # separated columns: a few ulps of 10 (1e-6 each); measured up to
+        # 4.8e-6 over five seeds
+        np.testing.assert_allclose(np.asarray(preds)[b], X @ beta, atol=5e-5)
 
 
 def test_seasonal_trend_sparse_series_stays_finite():
