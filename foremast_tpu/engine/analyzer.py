@@ -167,30 +167,41 @@ class _HpaItem:
     pod_window: object = None
 
 
-def _fp(*parts) -> bytes:
-    """Order-sensitive fingerprint of scorer inputs (SCORE_MEMO).
+def _fp_counted(parts) -> tuple:
+    """(fingerprint, window bytes hashed, windows hashed, window digests
+    reused) of scorer inputs (SCORE_MEMO), order-sensitive.
 
-    Windows hash their full identity (start, step, length, values, mask);
-    ndarrays their bytes; everything else its repr. blake2b-128 — the memo
-    only ever compares fingerprints of the SAME key, so 128 bits is far
-    past accidental-collision territory, and hashing is ~100x cheaper than
-    the device launch it elides."""
+    A Window contributes its own digest of its full identity (start, step,
+    length, values, mask: `Window.digest`), hashed once per object, so a
+    window the fetch layer hands back unmoved costs nothing to fingerprint
+    again; ndarrays their bytes; everything else its repr. blake2b-128 —
+    the memo only ever compares fingerprints of the SAME key, so 128 bits
+    is far past accidental-collision territory, and hashing is ~100x
+    cheaper than the device launch it elides."""
     h = hashlib.blake2b(digest_size=16)
+    nbytes = hashed = reused = 0
     for p in parts:
         if p is None:
             h.update(b"\xffN")
         elif isinstance(p, Window):
-            h.update(np.float64(
-                (p.start, p.step, p.values.shape[0])).tobytes())
-            h.update(p.values.tobytes())
-            h.update(p.mask.tobytes())
+            if p.digested:
+                reused += 1
+            else:
+                hashed += 1
+                nbytes += p.values.nbytes + p.mask.nbytes
+            h.update(p.digest())
         elif isinstance(p, np.ndarray):
             h.update(np.int64(p.shape).tobytes())
             h.update(p.tobytes())
         else:
             h.update(repr(p).encode())
         h.update(b"|")
-    return h.digest()
+    return h.digest(), nbytes, hashed, reused
+
+
+def _fp(*parts) -> bytes:
+    """`_fp_counted`'s fingerprint alone."""
+    return _fp_counted(parts)[0]
 
 
 def _concat_trimmed(hist: Window, cur: Window):
@@ -498,13 +509,11 @@ class Analyzer:
             table.popitem(last=False)
 
     def _memo_key_fp(self, fam, entry, T: int):
-        """(result_key, fingerprint, window bytes hashed) for one routed
-        accumulator entry of family `fam` (engine/families.py says what
-        the fingerprint covers)."""
-        parts = fam.fp_parts(entry, T)
-        return fam.entry_key(entry), _fp(*parts), sum(
-            p.values.nbytes + p.mask.nbytes for p in parts
-            if isinstance(p, Window))
+        """(result_key, *`_fp_counted`) for one routed accumulator entry
+        of family `fam` (engine/families.py says what the fingerprint
+        covers)."""
+        return (fam.entry_key(entry),
+                *_fp_counted(fam.fp_parts(entry, T)))
 
     def _dump_knobs(self) -> dict:
         """Knob values folded into flight-recorder dumps: the degraded-mode
@@ -2345,7 +2354,8 @@ class Analyzer:
                 "route_cpu": busy_cpu - pipe.fired_cpu_seconds}
         memo_counts = {"memo_lookups": pipe.memo_lookups,
                        "memo_hits": sum(pipe.memo_hits.values()),
-                       "memo_fp_bytes": pipe.memo_fp_bytes}
+                       "memo_fp_bytes": pipe.memo_fp_bytes,
+                       "memo_fp_reused": pipe.memo_fp_reused}
         prep_sp.attrs.update(
             {k + "_s": round(v, 6) for k, v in part.items()},
             **memo_counts,
@@ -2778,6 +2788,14 @@ class Analyzer:
                 "foremastbrain:fetch_pool_width", {}, pool["width"],
                 help="Fetch pool threads the last cycle used: sized from "
                      "its probe, at most FETCH_CONCURRENCY (1 = no pool).")
+            fp_windows = pipe.memo_fp_hashed + pipe.memo_fp_reused
+            self.exporter.record_gauge(
+                "foremastbrain:memo_fp_reuse_share", {},
+                round(pipe.memo_fp_reused / fp_windows, 6)
+                if fp_windows else 0.0,
+                help="Share of the windows the last cycle's score memo "
+                     "fingerprinted whose digest the Window already held "
+                     "(0: none fingerprinted).")
             self.exporter.record_gauge(
                 "foremastbrain:period_partitions", {},
                 counters["period_partitions"],

@@ -130,14 +130,17 @@ class CyclePipeline:
         self.memo_job_hits: dict = {}
         self._fps: dict = {}       # (family, result_key) -> fingerprint
         # the cycle's partition (Analyzer._run_cycle): wall seconds inside
-        # _memo_check with its lookups and the bytes it fingerprinted, and
-        # the thread-CPU seconds of streamed fires and screens, which the
-        # route piece's CPU leaves out. The memo check itself reads no CPU
-        # clock: `time.thread_time` is a system call (6 us on the chip's
-        # host), and two a check cost a measurable share of the cycle.
+        # _memo_check with its lookups, the windows (and their bytes) it
+        # hashed and the window digests it reused (Window.digest), and the
+        # thread-CPU seconds of streamed fires and screens, which the route
+        # piece's CPU leaves out. The memo check itself reads no CPU clock:
+        # `time.thread_time` is a system call (6 us on the chip's host),
+        # and two a check cost a measurable share of the cycle.
         self.memo_seconds = 0.0
         self.memo_lookups = 0
         self.memo_fp_bytes = 0
+        self.memo_fp_hashed = 0
+        self.memo_fp_reused = 0
         self.fired_cpu_seconds = 0.0
 
     def _memo_check(self, fam, entry, T: int) -> bool:
@@ -152,9 +155,12 @@ class CyclePipeline:
 
     def _memo_lookup(self, fam, entry, T: int) -> bool:
         family = fam.name
-        key, fp, nbytes = self.an._memo_key_fp(fam, entry, T)
+        key, fp, nbytes, hashed, reused = self.an._memo_key_fp(
+            fam, entry, T)
         self.memo_lookups += 1
         self.memo_fp_bytes += nbytes
+        self.memo_fp_hashed += hashed
+        self.memo_fp_reused += reused
         hit = self.memo.get((family, key))
         if hit is not None and hit[0] == fp:
             self.memo.move_to_end((family, key))

@@ -14,7 +14,8 @@ micro-batch.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+import hashlib
+from dataclasses import dataclass, field
 from typing import Sequence
 
 import numpy as np
@@ -44,10 +45,37 @@ class Window:
     mask: np.ndarray  # (T,) bool
     start: int  # aligned unix seconds
     step: int = DEFAULT_STEP
+    # blake2b-128 of (start, step, length, values, mask), taken once by
+    # `digest()`: only the object keeps it, the warm tier spills the arrays
+    _digest: bytes | None = field(default=None, init=False, repr=False,
+                                  compare=False)
 
     @property
     def n_valid(self) -> int:
         return int(self.mask.sum())
+
+    @property
+    def digested(self) -> bool:
+        return self._digest is not None
+
+    def digest(self) -> bytes:
+        """The window's identity as the score memo fingerprints it, hashed
+        on first use and kept. Taking it makes both arrays read-only: no
+        producer writes into a Window it has handed out (the fetch layer
+        hands the same object back while its range has not moved), and a
+        write after the digest would raise instead of serving a stale
+        memo hit."""
+        d = self._digest
+        if d is None:
+            self.values.setflags(write=False)
+            self.mask.setflags(write=False)
+            h = hashlib.blake2b(digest_size=16)
+            h.update(np.float64(
+                (self.start, self.step, self.values.shape[0])).tobytes())
+            h.update(self.values.tobytes())
+            h.update(self.mask.tobytes())
+            d = self._digest = h.digest()
+        return d
 
 
 def resample_to_grid(

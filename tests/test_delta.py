@@ -958,13 +958,16 @@ def test_delta_memo_cycle_identical_to_full_refetch():
     assert sum(eng_on.score_memo_hits.values()) > 0
 
 
-def _run_rolling(delta: bool, jobs=5, cycles=4, W=20, H=200):
+def _run_rolling(delta: bool, jobs=5, cycles=4, W=20, H=200, memo=None,
+                 seen=None):
     """`rollingUpdate` jobs as barrelman builds them: a current window
     that grows by one sample a cycle, and a baseline and a history whose
     ranges are fixed timestamps in the past. Returns per-cycle verdict
     snapshots (store state and every record's scores), backend requests
     per cycle beside the cycle's `splice_appends`, and each job's fetch
-    record."""
+    record. `memo` (default: as `delta`) sets SCORE_MEMO; a `seen` list
+    gets, a cycle, the engine.preprocess attrs, `last_cycle_stages` and
+    the exporter's gauges."""
     be = _Backend()
     rng = np.random.default_rng(11)
     store = JobStore()
@@ -987,7 +990,8 @@ def _run_rolling(delta: bool, jobs=5, cycles=4, W=20, H=200):
     inner = be.source()
     source = DeltaWindowSource(inner, clock=lambda: clock[0]) \
         if delta else inner
-    eng = Analyzer(EngineConfig(delta_fetch=delta, score_memo=delta),
+    eng = Analyzer(EngineConfig(delta_fetch=delta,
+                                score_memo=delta if memo is None else memo),
                    source, store, VerdictExporter())
     snaps, requests, fetches = [], [], []
     for _ in range(cycles):
@@ -1011,6 +1015,9 @@ def _run_rolling(delta: bool, jobs=5, cycles=4, W=20, H=200):
         prep = next(c for c in root["children"]
                     if c["name"] == tracing.SPAN_ENGINE_PREPROCESS)
         requests[-1] = (requests[-1], prep["attrs"]["splice_appends"])
+        if seen is not None:
+            seen.append((prep["attrs"], eng.last_cycle_stages, {
+                name: value for name, _, value in eng.exporter.samples()}))
         clock[0] += STEP - 5.0
     return snaps, requests, fetches, source
 
@@ -1051,6 +1058,46 @@ def test_rolling_update_fixed_ranges_one_backend_query_a_cycle():
     text = _render_explain({"provenance": {"path": "scored", "fetch": {
         "fetches": 4, "fetch_delta": 3, "fetch_append": 2, "fetch_full": 1}}})
     assert "4 fetch(es), 3 delta (2 append)/1 full" in text
+
+
+def test_unmoved_windows_are_not_hashed_again_and_verdicts_hold():
+    """Rolling jobs with the memo on: from the second cycle a job's
+    baseline and history are the objects the window cache handed out
+    last cycle, so the fingerprint hashes only the appended current
+    window and chains the three other digests (the current's own, taken
+    for the pair row, serves the band row too). Verdicts, records and
+    memo hits equal `SCORE_MEMO=0`'s cycle by cycle; the counts ride the
+    engine.preprocess span, `last_cycle_stages` and the gauge."""
+    jobs, W = 5, 20
+    seen_on, seen_off = [], []
+    snaps_on, req_on, *_ = _run_rolling(delta=True, jobs=jobs, W=W,
+                                        memo=True, seen=seen_on)
+    snaps_off, req_off, *_ = _run_rolling(delta=True, jobs=jobs, W=W,
+                                          memo=False, seen=seen_off)
+    assert snaps_on == snaps_off and req_on == req_off
+    assert [st["score_memo_hits"] for _, st, _ in seen_on] == \
+        [st["score_memo_hits"] for _, st, _ in seen_off] == [{}] * 4
+    for k, (prep, st, gauges) in enumerate(seen_on):
+        counters = st["partition"]["counters"]
+        for name in ("memo_lookups", "memo_hits", "memo_fp_bytes",
+                     "memo_fp_reused"):
+            assert prep[name] == counters[name], name
+        assert counters["memo_lookups"] == 2 * jobs and \
+            counters["memo_hits"] == 0
+        n_c = W + k + 1  # the current window, one sample longer a cycle
+        if k == 0:  # every window is new: baseline 21, history 200
+            assert counters["memo_fp_bytes"] == 5 * jobs * (
+                n_c + W + 1 + 200)
+            assert counters["memo_fp_reused"] == jobs
+            assert gauges["foremastbrain:memo_fp_reuse_share"] == 0.25
+        else:
+            assert counters["memo_fp_bytes"] == 5 * jobs * n_c
+            assert counters["memo_fp_reused"] == 3 * jobs
+            assert gauges["foremastbrain:memo_fp_reuse_share"] == 0.75
+    for prep, st, gauges in seen_off:
+        assert prep["memo_fp_bytes"] == prep["memo_fp_reused"] == 0
+        assert st["partition"]["counters"]["memo_fp_reused"] == 0
+        assert gauges["foremastbrain:memo_fp_reuse_share"] == 0.0
 
 
 def test_memo_changed_single_row_rescores_only_its_bucket():

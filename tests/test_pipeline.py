@@ -423,10 +423,15 @@ def test_every_family_answers_every_question_of_the_table(name):
         assert bucket == T == bucket_length(bucket)
         assert {it.job_id for it in fam.row_items(rows[0])} == {"w0"}
         assert {it.job_id for it in fam.items_of([entry])} == {"w0"}
-        key, fp, nbytes = eng._memo_key_fp(fam, entry, bucket)
+        key, fp, nbytes, hashed, reused = eng._memo_key_fp(fam, entry,
+                                                           bucket)
         assert key == fam.entry_key(entry) and key in results
-        assert len(fp) == 16 and nbytes > 0
-        assert fp != eng._memo_key_fp(fam, entry, 2 * bucket)[1]
+        assert len(fp) == 16 and nbytes > 0 and hashed > 0 and reused == 0
+        # the entry's windows keep their digests: a second fingerprint
+        # hashes no window bytes again
+        _k, fp2, nbytes2, hashed2, reused2 = eng._memo_key_fp(
+            fam, entry, 2 * bucket)
+        assert fp != fp2 and nbytes2 == hashed2 == 0 and reused2 == hashed
         state = fam.launch(eng, [entry], bucket)
         assert fam.collect(eng, state).keys() == {key}
     if fam.provenance is not None:
@@ -463,6 +468,97 @@ def test_every_family_answers_every_question_of_the_table(name):
             "unhealthy"] is False
     assert not families.family("band").screens(
         EngineConfig(algorithm="holt_winters"))
+
+
+def test_a_window_fingerprinted_twice_is_digested_once(monkeypatch):
+    """The memo hashes a Window's bytes once; the object keeps its digest,
+    and a later fingerprint that holds the same object (an unmoved range
+    the fetch layer hands back) chains it. `memo_fp_bytes` counts the
+    bytes hashed, `memo_fp_reused` the digests served from the object."""
+    import hashlib
+    import types
+
+    from foremast_tpu.engine.analyzer import _PairItem
+    from foremast_tpu.ops import windowing
+
+    made = []
+
+    def blake2b(**kw):
+        made.append(kw)
+        return hashlib.blake2b(**kw)
+
+    monkeypatch.setattr(windowing, "hashlib",
+                        types.SimpleNamespace(blake2b=blake2b))
+    rng = np.random.default_rng(5)
+    eng = Analyzer(EngineConfig(), None, JobStore())
+    pipe = CyclePipeline(eng)
+    fam = families.family("pair")
+    policy = eng.config.policy_for("latency")
+    base, cur = _win(rng, 1.0, 80), _win(rng, 1.0, 32)
+    assert not base.digested
+    assert not pipe._memo_check(
+        fam, _PairItem("j", "latency", base, cur, policy), 128)
+    assert len(made) == 2 and base.digested and cur.digested
+    assert (pipe.memo_fp_bytes, pipe.memo_fp_hashed,
+            pipe.memo_fp_reused) == (5 * (80 + 32), 2, 0)
+    # the next cycle: the same baseline object, a current one sample longer
+    cur2 = _win(rng, 1.0, 33)
+    assert not pipe._memo_check(
+        fam, _PairItem("j", "latency", base, cur2, policy), 128)
+    assert len(made) == 3
+    assert (pipe.memo_fp_bytes, pipe.memo_fp_hashed,
+            pipe.memo_fp_reused) == (5 * (80 + 32 + 33), 3, 1)
+    assert pipe.memo_lookups == 2
+
+
+def _one_changed(change, v, m, start, step):
+    """(values, mask, start, step) with one part of a window changed."""
+    if change == "sample":
+        v[3] += np.float32(0.5)
+    elif change == "start":
+        start += step
+    elif change == "step":
+        step = 30
+    elif change == "length":
+        v, m = v[:-1], m[:-1]
+    elif change == "mask":
+        m[5] = not m[5]
+    return v, m, start, step
+
+
+@pytest.mark.parametrize("change", ["equal", "sample", "start", "step",
+                                    "length", "mask"])
+def test_window_fingerprint_equal_exactly_when_the_windows_are(change):
+    """Two distinct Window objects fingerprint alike exactly when start,
+    step, length, values and mask are alike: the digest is of the window's
+    identity, not of the object."""
+    rng = np.random.default_rng(6)
+    v = rng.normal(1.0, 0.1, 40).astype(np.float32)
+    m = rng.random(40) < 0.9
+    a = Window(v.copy(), m.copy(), 6000, STEP)
+    b = Window(*_one_changed(change, v.copy(), m.copy(), 6000, STEP))
+    assert a is not b
+    fa = analyzer_mod._fp(b"pair", 128, "latency", a, None)
+    fb = analyzer_mod._fp(b"pair", 128, "latency", b, None)
+    assert (fa == fb) is (change == "equal")
+    assert (a.digest() == b.digest()) is (change == "equal")
+    # a part's place still counts
+    assert analyzer_mod._fp(a, None) != analyzer_mod._fp(None, a)
+
+
+@pytest.mark.parametrize("field", ["values", "mask"])
+def test_a_digested_window_refuses_writes(field):
+    """Taking the digest makes both arrays read-only: a write into a
+    window the memo has fingerprinted raises instead of serving a stale
+    memo hit next cycle."""
+    w = _win(np.random.default_rng(7), 1.0, 16)
+    arr = getattr(w, field)
+    arr[0] = arr[1]  # writable until digested
+    w.digest()
+    with pytest.raises(ValueError, match="read-only"):
+        arr[0] = arr[2]
+    with pytest.raises(ValueError, match="read-only"):
+        getattr(w, field)[:] = arr[::-1]
 
 
 def test_fold_order_of_a_four_family_job_shows_in_its_reason():
