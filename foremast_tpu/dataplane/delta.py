@@ -220,18 +220,21 @@ def _cached_sample_ts(w, nan_ts, qstart):
 
 
 def _note_fetch_seconds(lock_wait: float, lock_held: float,
-                        source: float) -> None:
+                        source: float, url: float) -> None:
     """Where one fetch's seconds went, on the caller's open per-job notes
     (the engine's fetch pool; a no-op on any other thread): queued for the
-    splice lock and inside the inner source's call are THREAD-seconds,
-    summed over the pool's threads; the lock is serial, so the seconds it
-    was held sum to wall seconds. One flush per fetch, from timestamps
-    taken inline: a timed context manager at every lock site cost ten
-    times as much under the pool's contention for the interpreter lock."""
+    splice lock, inside the inner source's call and on the URL (the
+    range's parse, the cache key, the delta query's range) are
+    THREAD-seconds, summed over the pool's threads; the lock is serial, so
+    the seconds it was held sum to wall seconds. One flush per fetch, from
+    timestamps taken inline: a timed context manager at every lock site
+    cost ten times as much under the pool's contention for the interpreter
+    lock."""
     note = tracing.tracer.add_note
     note("lock_wait_thread_seconds", lock_wait)
     note("lock_held_seconds", lock_held)
     note("source_thread_seconds", source)
+    note("url_thread_seconds", url)
 
 
 class DeltaWindowSource:
@@ -740,22 +743,23 @@ class DeltaWindowSource:
                 entry.push_blocked = True
                 entry.dirty = True  # the latch must survive a restart
 
-    def _try_ingest_serve(self, key, entry, rng):
+    def _try_ingest_serve(self, key, entry, rng, url_s: float):
         """Serve a requested range entirely from the push-fed cache, or
-        None to fall through to the delta/full path. Safe only while the
-        pushed horizon covers every on-grid slot the query's end could
-        hold (``qend < pushed_until + step``) and the cache provably
+        None to fall through to the delta/full path, with the URL seconds
+        `url_s` still to note (0 once noted with the lock's). Safe only
+        while the pushed horizon covers every on-grid slot the query's end
+        could hold (``qend < pushed_until + step``) and the cache provably
         retains every sample at/after the requested start."""
         qstart, qend, url_step = rng
         if url_step != entry.url_step or qstart < entry.qstart:
-            return None
+            return None, url_s
         t0 = time.perf_counter()
         with self._cpu_lock:
             t1 = time.perf_counter()
             out = self._serve_pushed(key, entry, qstart, qend)
             t2 = time.perf_counter()
-        _note_fetch_seconds(t1 - t0, t2 - t1, 0.0)
-        return out
+        _note_fetch_seconds(t1 - t0, t2 - t1, 0.0, url_s)
+        return out, 0.0
 
     def _serve_pushed(self, key, entry, qstart, qend):
         """`_try_ingest_serve` under the cpu lock."""
@@ -798,8 +802,12 @@ class DeltaWindowSource:
 
     # ------------------------------------------------------------- fetch
     def fetch_window(self, url: str) -> Window:
+        # the URL's seconds (the range's parse, the cache key) are noted
+        # once a fetch, with the rest of what its path notes
+        t0 = time.perf_counter()
         rng = parse_range_params(url)
         if rng is None:
+            url_s = time.perf_counter() - t0
             # no parseable range: never delta-capable, so keep the inner
             # source's fused byte->Window fast path when it has one
             with self._lock:
@@ -809,8 +817,9 @@ class DeltaWindowSource:
             if fw is not None:
                 win = fw(url)
                 if win is not None:
+                    tracing.tracer.add_note("url_thread_seconds", url_s)
                     return win
-            return self._full(url, key=None, rng=None)
+            return self._full(url, None, None, url_s)
         # key = URL minus start/end values, PLUS the log2 bucket of the
         # range span: a job's current and historical windows often share
         # the same underlying query and differ only in their range
@@ -823,6 +832,7 @@ class DeltaWindowSource:
         # trailing windows (constant span) and for fixed-start/growing-
         # end windows (one extra miss per span doubling).
         key = self._cache_key(url, rng)
+        url_s = time.perf_counter() - t0
         # read before the lock: the clock is the caller's own function
         closed_before = float(self.clock()) - self.overlap_steps * self.step
         win = None
@@ -839,6 +849,7 @@ class DeltaWindowSource:
                     self.bytes_saved += entry.full_bytes
         if win is not None:
             tracing.tracer.add_note("fetch_unmoved")
+            tracing.tracer.add_note("url_thread_seconds", url_s)
             return win
         if entry is None:
             # warm tier first: a spilled/recovered entry promotes back to
@@ -849,17 +860,17 @@ class DeltaWindowSource:
             with self._lock:
                 self.full_fetches += 1
             tracing.tracer.add_note("fetch_full")
-            return self._full(url, key, rng)
+            return self._full(url, key, rng, url_s)
         if entry.pushed_until > 0:
             # streamed path: pushed samples already cover the requested
             # range end — serve the window without touching the backend
-            win = self._try_ingest_serve(key, entry, rng)
+            win, url_s = self._try_ingest_serve(key, entry, rng, url_s)
             if win is not None:
                 with self._lock:
                     self.ingest_hits += 1
                 tracing.tracer.add_note("fetch_ingest")
                 return win
-        win = self._try_delta(url, key, rng, entry)
+        win, url_s = self._try_delta(url, key, rng, entry, url_s)
         with self._lock:
             if win is not None:
                 self.delta_hits += 1
@@ -871,7 +882,7 @@ class DeltaWindowSource:
                                 else "fetch_full")
         if win is not None:
             return win
-        return self._full(url, key, rng)
+        return self._full(url, key, rng, url_s)
 
     def _unmoved(self, entry, rng, closed_before: float) -> bool:
         """The closed-range rule (caller holds ``_lock``, under which every
@@ -904,9 +915,10 @@ class DeltaWindowSource:
         return qstart <= last_end and last_end + w.step > qend \
             and qend <= closed_before
 
-    def _full(self, url: str, key, rng) -> Window:
+    def _full(self, url: str, key, rng, url_s: float) -> Window:
         """Full refetch; (re)prime the cache entry when the response is
-        exact-grid (spliceable next cycle)."""
+        exact-grid (spliceable next cycle). `url_s`: the URL seconds still
+        to note."""
         t0 = time.perf_counter()
         ts, vals, nbytes = self._series(url)
         t1 = time.perf_counter()
@@ -914,7 +926,7 @@ class DeltaWindowSource:
             t2 = time.perf_counter()
             win = self._full_grid(ts, vals, nbytes, key, rng)
             t3 = time.perf_counter()
-        _note_fetch_seconds(t2 - t1, t3 - t2, t1 - t0)
+        _note_fetch_seconds(t2 - t1, t3 - t2, t1 - t0, url_s)
         self._flush_spills()
         return win
 
@@ -943,9 +955,10 @@ class DeltaWindowSource:
             self._evict_overflow_locked()
         return win
 
-    def _try_delta(self, url, key, rng, entry) -> Window | None:
+    def _try_delta(self, url, key, rng, entry, url_s: float):
         """Splice path. Returns the spliced Window, or None to signal a
-        full refetch (the caller counts it; reasons counted here)."""
+        full refetch (the caller counts it; reasons counted here), with
+        the URL seconds `url_s` still to note (0 once noted)."""
         qstart, qend, url_step = rng
         step = self.step
         if entry.push_blocked:
@@ -955,15 +968,15 @@ class DeltaWindowSource:
             # its splice-mismatch canary never look. Only a full refetch
             # re-establishes trust (and re-primes a clean entry).
             self._count_fallback("resync")
-            return None
+            return None, url_s
         if url_step != entry.url_step:
             self._count_fallback("step_change")
-            return None
+            return None, url_s
         if qstart < entry.qstart:
             # range extends backwards past what the cache ever covered
             self._count_fallback("range_extended")
-            return None
-        # t0..t5: where this fetch's seconds go (_note_fetch_seconds); a
+            return None, url_s
+        # t0..t6: where this fetch's seconds go (_note_fetch_seconds); a
         # fallback that returns from under the first lock notes nothing
         t0 = time.perf_counter()
         with self._cpu_lock:
@@ -986,22 +999,25 @@ class DeltaWindowSource:
                 valid_ts, sample_ts = _cached_sample_ts(w, nan_ts, qstart)
                 if sample_ts.size == 0:
                     self._count_fallback("empty_cache_range")
-                    return None
+                    return None, url_s
                 last_end = float(np.max(sample_ts))
             delta_start = max(qstart, last_end - self.overlap_steps * step)
             if delta_start > qend:
                 self._count_fallback("range_regressed")
-                return None
+                return None, url_s
             t2 = time.perf_counter()
 
         # a delta-query failure propagates like a full-fetch failure would:
         # same backend, same URL shape — the resilience layer already ran.
         # The fetch itself stays OUTSIDE the cpu lock: network I/O is the
-        # part that genuinely overlaps across the engine's fetch pool.
-        ts_d, vals_d, nbytes = self._series(_set_range(url, delta_start, qend))
+        # part that genuinely overlaps across the engine's fetch pool. The
+        # query's URL is the URL's work, not the source's.
+        delta_url = _set_range(url, delta_start, qend)
         t3 = time.perf_counter()
+        ts_d, vals_d, nbytes = self._series(delta_url)
+        t4 = time.perf_counter()
         with self._cpu_lock:
-            t4 = time.perf_counter()
+            t5 = time.perf_counter()
             out = self._append_tail(
                 key, entry, w, nan_ts, j0, delta_start, qstart, qend, ts_d,
                 vals_d, nbytes) if appendable else None
@@ -1015,12 +1031,12 @@ class DeltaWindowSource:
                 out = self._splice(key, entry, w, valid_ts, sample_ts,
                                    delta_start, qstart, qend, ts_d, vals_d,
                                    nbytes)
-            t5 = time.perf_counter()
-        _note_fetch_seconds((t1 - t0) + (t4 - t3), (t2 - t1) + (t5 - t4),
-                            t3 - t2)
+            t6 = time.perf_counter()
+        _note_fetch_seconds((t1 - t0) + (t5 - t4), (t2 - t1) + (t6 - t5),
+                            t4 - t3, url_s + (t3 - t2))
         if appended:
             tracing.tracer.add_note("fetch_append")
-        return out
+        return out, 0.0
 
     def _append_tail(self, key, entry, w, nan_ts, j0, delta_start, qstart,
                      qend, ts_d, vals_d, nbytes) -> Window | None:

@@ -640,7 +640,7 @@ _TRACE_EXEMPT_PREFIXES = (
 )
 
 _SPAN_CALLS = {"span", "tracing.span", "tracer.span", "tracing.tracer.span",
-               "self.span", "tr.span"}
+               "self.span", "tr.span", "annotate", "tracing.annotate"}
 
 
 def _collect_caps_strings(tree: ast.AST) -> set[str]:

@@ -91,6 +91,15 @@ POOL_PROBE_READINGS = 2
 POOL_PROBE_MIN_JOBS_PER_THREAD = 8
 
 
+# the per-job notes the fetch pool sums (_stream_prep): a job's whole
+# preprocess, its fetches' wall time, and the fetches' named parts, in that
+# order; `Analyzer._book_pieces` turns the sums into the partition of
+# tracing.POOL_SPANS
+_POOL_NOTES = ("prep_thread_seconds", "fetch_seconds", "url_thread_seconds",
+               "source_thread_seconds", "lock_wait_thread_seconds",
+               "lock_held_seconds")
+
+
 def pool_width(wall: float, cpu: float, cap: int) -> int:
     """Threads that keep one interpreter busy while the others wait on
     the store: the probe's wall seconds over its CPU seconds, at most
@@ -202,6 +211,12 @@ def _fp_counted(parts) -> tuple:
 def _fp(*parts) -> bytes:
     """`_fp_counted`'s fingerprint alone."""
     return _fp_counted(parts)[0]
+
+
+def _nbytes(arrays) -> int:
+    """Bytes a pack piece wrote into these host arrays (the `bytes` attr
+    of the engine.pack.* spans)."""
+    return sum(a.nbytes for a in arrays)
 
 
 def _concat_trimmed(hist: Window, cur: Window):
@@ -586,8 +601,12 @@ class Analyzer:
     def _fetch_window(self, url: str, now: float) -> Window | None:
         if not url:
             return None
-        url = materialize_placeholders(url, now)
+        # `fetch_seconds` is the whole call, the URL's materialization
+        # with it; that materialization is URL seconds, as the delta
+        # source's own URL work is (dataplane/delta.py)
         t0 = time.perf_counter()
+        url = materialize_placeholders(url, now)
+        t1 = time.perf_counter()
         try:
             # byte-level sources expose fetch_window: body -> grid Window
             # in one fused native call, skipping the intermediate
@@ -608,6 +627,7 @@ class Analyzer:
             return win
         finally:
             dt = time.perf_counter() - t0
+            tracing.tracer.add_note("url_thread_seconds", t1 - t0)
             tracing.tracer.add_note("fetches", 1)
             tracing.tracer.add_note("fetch_seconds", dt)
             self.exporter.record_histogram(
@@ -1020,15 +1040,25 @@ class Analyzer:
             C = self._mega_cap(T)
         else:
             C = self._bucket_rows(B)
+        # every chunk's rows, cut and padded before the first launch: one
+        # span a dispatch, whether or not a chunk needs padding
+        chunks = []
+        with tracing.span(tracing.SPAN_ENGINE_PACK_PAD, rows=B) as sp:
+            written = 0
+            for i in range(0, B, C):
+                sl = [a[i:i + C] for a in arrays]
+                n = sl[0].shape[0]
+                target = (min(self._mega_rows(n), C) if mega
+                          else self._bucket_rows(n))
+                if n < target:
+                    sl = [np.pad(a, ((0, target - n),)
+                                 + ((0, 0),) * (a.ndim - 1), mode="edge")
+                          for a in sl]
+                    written += _nbytes(sl)
+                chunks.append((i, sl, n, target))
+            sp.attrs["bytes"] = written
         launches = []
-        for i in range(0, B, C):
-            sl = [a[i:i + C] for a in arrays]
-            n = sl[0].shape[0]
-            target = (min(self._mega_rows(n), C) if mega
-                      else self._bucket_rows(n))
-            if n < target:
-                sl = [np.pad(a, ((0, target - n),) + ((0, 0),) * (a.ndim - 1),
-                             mode="edge") for a in sl]
+        for i, sl, n, target in chunks:
             self.device_launches += 1
             call = partial(fn, rows=n) if with_rows else fn
             if row_elems is not None:
@@ -1107,37 +1137,45 @@ class Analyzer:
 
     def _launch_pairs(self, group: list, T: int):
         cfg = self.config
-        bvals, bm = pack_windows([it.baseline for it in group], pad_to=T)
-        cv, cm = pack_windows([it.current for it in group], pad_to=T)
         B = len(group)
-        launches = self._launch_chunks(fl.score_pairs, [
-            bvals, bm, cv, cm,
-            np.full(B, cfg.pairwise_threshold, np.float32),
-            np.full(B, cfg.enabled_tests(), np.int32),
-            np.full(
-                B,
-                fl.COMBINE_ALL if cfg.pairwise_combine_all else fl.COMBINE_ANY,
-                np.int32,
-            ),
-            np.full(B, cfg.ma_window, np.int32),
-            np.asarray([it.policy.threshold for it in group], np.float32),
-            np.asarray([it.policy.bound for it in group], np.int32),
-            np.asarray([it.policy.min_lower_bound for it in group], np.float32),
-            np.tile(
-                np.asarray(
-                    [
-                        cfg.min_mann_whitney_points,
-                        cfg.min_wilcoxon_points,
-                        cfg.min_kruskal_points,
-                        cfg.min_friedman_points,
-                    ],
+        # a pair's rows are its routed windows: nothing is built a row
+        with tracing.span(tracing.SPAN_ENGINE_PACK_ROWS, rows=B, bytes=0):
+            pass
+        with tracing.span(tracing.SPAN_ENGINE_PACK_BLOCK, rows=B) as sp:
+            bvals, bm = pack_windows([it.baseline for it in group], pad_to=T)
+            cv, cm = pack_windows([it.current for it in group], pad_to=T)
+            arrays = [
+                bvals, bm, cv, cm,
+                np.full(B, cfg.pairwise_threshold, np.float32),
+                np.full(B, cfg.enabled_tests(), np.int32),
+                np.full(
+                    B,
+                    fl.COMBINE_ALL if cfg.pairwise_combine_all else fl.COMBINE_ANY,
                     np.int32,
                 ),
-                (B, 1),
-            ),
-        ], donate=4, row_elems=np.asarray(
-            [it.baseline.values.shape[0] + it.current.values.shape[0]
-             for it in group]))
+                np.full(B, cfg.ma_window, np.int32),
+                np.asarray([it.policy.threshold for it in group], np.float32),
+                np.asarray([it.policy.bound for it in group], np.int32),
+                np.asarray([it.policy.min_lower_bound for it in group], np.float32),
+                np.tile(
+                    np.asarray(
+                        [
+                            cfg.min_mann_whitney_points,
+                            cfg.min_wilcoxon_points,
+                            cfg.min_kruskal_points,
+                            cfg.min_friedman_points,
+                        ],
+                        np.int32,
+                    ),
+                    (B, 1),
+                ),
+            ]
+            row_elems = np.asarray(
+                [it.baseline.values.shape[0] + it.current.values.shape[0]
+                 for it in group])
+            sp.attrs["bytes"] = _nbytes(arrays)
+        launches = self._launch_chunks(fl.score_pairs, arrays, donate=4,
+                                       row_elems=row_elems)
         return (group, launches)
 
     def _collect_pairs(self, state) -> dict:
@@ -1265,15 +1303,29 @@ class Analyzer:
         )
 
     def _launch_bands(self, group: list, T: int):
-        concats = []
-        n_hs = []
-        for it in group:
-            h, c = it.historical, it.current
-            vals, mask, n_h = _concat_trimmed(h, c)
-            n_hs.append(n_h)
-            concats.append(Window(vals, mask, h.start, h.step))
-        xv, xm = pack_windows(concats, pad_to=T)
-        ns = np.asarray([c.values.shape[0] for c in concats], np.int32)
+        B = len(group)
+        with tracing.span(tracing.SPAN_ENGINE_PACK_ROWS, rows=B) as sp:
+            concats = []
+            n_hs = []
+            written = 0
+            for it in group:
+                h, c = it.historical, it.current
+                vals, mask, n_h = _concat_trimmed(h, c)
+                n_hs.append(n_h)
+                concats.append(Window(vals, mask, h.start, h.step))
+                written += vals.nbytes + mask.nbytes
+            sp.attrs["bytes"] = written
+        with tracing.span(tracing.SPAN_ENGINE_PACK_BLOCK, rows=B) as sp:
+            xv, xm = pack_windows(concats, pad_to=T)
+            ns = np.asarray([c.values.shape[0] for c in concats], np.int32)
+            arrays = [
+                xv, xm, np.asarray(n_hs, np.int32), ns,
+                np.asarray([it.policy.threshold for it in group], np.float32),
+                np.asarray([it.policy.bound for it in group], np.int32),
+                np.asarray([it.policy.min_lower_bound for it in group],
+                           np.float32),
+            ]
+            sp.attrs["bytes"] = _nbytes(arrays)
 
         def band_fn(xv_c, xm_c, nh_c, n_c, thr_c, bnd_c, mlb_c, rows):
             # the chunk crosses to the device once and the launch's programs
@@ -1323,12 +1375,8 @@ class Analyzer:
                 self.period_partitions_total += 1
             return out
 
-        launches = self._launch_chunks(band_fn, [
-            xv, xm, np.asarray(n_hs, np.int32), ns,
-            np.asarray([it.policy.threshold for it in group], np.float32),
-            np.asarray([it.policy.bound for it in group], np.int32),
-            np.asarray([it.policy.min_lower_bound for it in group], np.float32),
-        ], row_elems=ns, with_rows=True)
+        launches = self._launch_chunks(band_fn, arrays, row_elems=ns,
+                                       with_rows=True)
         return (group, launches, xv, n_hs)
 
     def _collect_bands(self, state) -> dict:
@@ -1377,32 +1425,39 @@ class Analyzer:
     def _launch_bivariate(self, entries: list, T: int):
         """entries: [(item, joint-grid prep)] — one launch state per bucket."""
         B = len(entries)
-        x1 = np.zeros((B, T), np.float32)
-        x2 = np.zeros((B, T), np.float32)
-        m1 = np.zeros((B, T), bool)
-        m2 = np.zeros((B, T), bool)
-        n_hist = np.empty(B, np.int32)
-        n_total = np.empty(B, np.int32)
-        thr = np.empty(B, np.float32)
-        mlb1 = np.empty(B, np.float32)
-        mlb2 = np.empty(B, np.float32)
-        bm1 = np.empty(B, np.int32)
-        bm2 = np.empty(B, np.int32)
-        for i, (it, (x, m, n_h, n_c)) in enumerate(entries):
-            n = x.shape[1]
-            x1[i, :n], x2[i, :n] = x[0], x[1]
-            m1[i, :n], m2[i, :n] = m[0], m[1]
-            n_hist[i], n_total[i] = n_h, n
-            # the pair shares one ellipse: use the stricter (smaller)
-            # radius of the two metric policies
-            thr[i] = min(it.policies[0].threshold, it.policies[1].threshold)
-            mlb1[i] = it.policies[0].min_lower_bound
-            mlb2[i] = it.policies[1].min_lower_bound
-            bm1[i] = it.policies[0].bound
-            bm2[i] = it.policies[1].bound
-        launches = self._launch_chunks(bv.bivariate_normal_anomalies, [
-            x1, m1, x2, m2, n_hist, n_total, thr, mlb1, mlb2, bm1, bm2,
-        ], donate=4, row_elems=2 * n_total)
+        # a row's joint grid was made on the stream (_bi_prep, in route)
+        with tracing.span(tracing.SPAN_ENGINE_PACK_ROWS, rows=B, bytes=0):
+            pass
+        with tracing.span(tracing.SPAN_ENGINE_PACK_BLOCK, rows=B) as sp:
+            x1 = np.zeros((B, T), np.float32)
+            x2 = np.zeros((B, T), np.float32)
+            m1 = np.zeros((B, T), bool)
+            m2 = np.zeros((B, T), bool)
+            n_hist = np.empty(B, np.int32)
+            n_total = np.empty(B, np.int32)
+            thr = np.empty(B, np.float32)
+            mlb1 = np.empty(B, np.float32)
+            mlb2 = np.empty(B, np.float32)
+            bm1 = np.empty(B, np.int32)
+            bm2 = np.empty(B, np.int32)
+            for i, (it, (x, m, n_h, n_c)) in enumerate(entries):
+                n = x.shape[1]
+                x1[i, :n], x2[i, :n] = x[0], x[1]
+                m1[i, :n], m2[i, :n] = m[0], m[1]
+                n_hist[i], n_total[i] = n_h, n
+                # the pair shares one ellipse: use the stricter (smaller)
+                # radius of the two metric policies
+                thr[i] = min(it.policies[0].threshold, it.policies[1].threshold)
+                mlb1[i] = it.policies[0].min_lower_bound
+                mlb2[i] = it.policies[1].min_lower_bound
+                bm1[i] = it.policies[0].bound
+                bm2[i] = it.policies[1].bound
+            arrays = [x1, m1, x2, m2, n_hist, n_total, thr, mlb1, mlb2, bm1,
+                      bm2]
+            sp.attrs["bytes"] = _nbytes(arrays)
+        launches = self._launch_chunks(bv.bivariate_normal_anomalies,
+                                       arrays, donate=4,
+                                       row_elems=2 * n_total)
         return (entries, launches)
 
     def _collect_bivariate(self, state) -> dict:
@@ -1907,47 +1962,59 @@ class Analyzer:
             return Window(vals, mask, it.historical.start,
                           it.historical.step), n_h
 
-        tps_w, n_hs = zip(*[build(t) for _, t, _ in rows])
-        sla_w = [build(s)[0] for _, _, s in rows]
-        tv, tm = pack_windows(list(tps_w), pad_to=T)
-        sv, sm = pack_windows(list(sla_w), pad_to=T)
+        B = len(rows)
+        with tracing.span(tracing.SPAN_ENGINE_PACK_ROWS, rows=B) as sp:
+            tps_w, n_hs = zip(*[build(t) for _, t, _ in rows])
+            sla_w = [build(s)[0] for _, _, s in rows]
+            sp.attrs["bytes"] = sum(w.values.nbytes + w.mask.nbytes
+                                    for w in (*tps_w, *sla_w))
+        with tracing.span(tracing.SPAN_ENGINE_PACK_BLOCK, rows=B) as sp:
+            tv, tm = pack_windows(list(tps_w), pad_to=T)
+            sv, sm = pack_windows(list(sla_w), pad_to=T)
 
-        # per-job SLA criteria (dynamic_autoscaling.md:45-56): mode from
-        # ML_SLA_MODE, limit from the SLA metric's policy (sla_limit{N})
-        # falling back to ML_SLA_LIMIT; a static/min mode with no limit
-        # configured degrades to dynamic (there is nothing static to hold
-        # the metric against), never to a fake 1e9 "static" limit that
-        # would make SLA_MIN collapse to dynamic silently.
-        mode_cfg = {"static": hpa_ops.SLA_STATIC, "min": hpa_ops.SLA_MIN}.get(
-            self.config.sla_mode, hpa_ops.SLA_DYNAMIC)
-        limits = np.empty(len(rows), np.float32)
-        modes = np.empty(len(rows), np.int32)
-        absolutes = np.empty(len(rows), bool)
-        pods_now = np.ones(len(rows), np.float32)
-        pods_hist = np.ones(len(rows), np.float32)
-        had_pods = [False] * len(rows)
-        for i, (_job_id, tps_it, sla_it) in enumerate(rows):
-            lim = self.config.policy_for(sla_it.metric).sla_limit
-            if lim <= 0.0:
-                lim = self.config.sla_limit
-            if lim <= 0.0:
-                limits[i], modes[i] = 1e9, hpa_ops.SLA_DYNAMIC
-            else:
-                limits[i], modes[i] = lim, mode_cfg
-            # limit interpretation: ABSOLUTE (the deploy convention quotes
-            # latency SLAs in ms) unless the operator opts the fleet into
-            # relative limits (ML_SLA_LIMIT_RELATIVE); a wire
-            # isAbsolute=true still pins that metric absolute under the
-            # relative default. The bare wire default (false) must NOT
-            # silently turn ML_SLA_LIMIT=250ms into 250*mean.
-            absolutes[i] = (sla_it.is_absolute
-                            or not self.config.sla_limit_relative)
-            # pod counts split at the job's own current-window boundary —
-            # the exact region/history split the demand and capacity use
-            pc = _pod_count_stats(tps_it.pod_window, tps_it.current.start)
-            if pc is not None:
-                pods_now[i], pods_hist[i] = pc
-                had_pods[i] = True
+            # per-job SLA criteria (dynamic_autoscaling.md:45-56): mode from
+            # ML_SLA_MODE, limit from the SLA metric's policy (sla_limit{N})
+            # falling back to ML_SLA_LIMIT; a static/min mode with no limit
+            # configured degrades to dynamic (there is nothing static to hold
+            # the metric against), never to a fake 1e9 "static" limit that
+            # would make SLA_MIN collapse to dynamic silently.
+            mode_cfg = {"static": hpa_ops.SLA_STATIC, "min": hpa_ops.SLA_MIN}.get(
+                self.config.sla_mode, hpa_ops.SLA_DYNAMIC)
+            limits = np.empty(len(rows), np.float32)
+            modes = np.empty(len(rows), np.int32)
+            absolutes = np.empty(len(rows), bool)
+            pods_now = np.ones(len(rows), np.float32)
+            pods_hist = np.ones(len(rows), np.float32)
+            had_pods = [False] * len(rows)
+            for i, (_job_id, tps_it, sla_it) in enumerate(rows):
+                lim = self.config.policy_for(sla_it.metric).sla_limit
+                if lim <= 0.0:
+                    lim = self.config.sla_limit
+                if lim <= 0.0:
+                    limits[i], modes[i] = 1e9, hpa_ops.SLA_DYNAMIC
+                else:
+                    limits[i], modes[i] = lim, mode_cfg
+                # limit interpretation: ABSOLUTE (the deploy convention quotes
+                # latency SLAs in ms) unless the operator opts the fleet into
+                # relative limits (ML_SLA_LIMIT_RELATIVE); a wire
+                # isAbsolute=true still pins that metric absolute under the
+                # relative default. The bare wire default (false) must NOT
+                # silently turn ML_SLA_LIMIT=250ms into 250*mean.
+                absolutes[i] = (sla_it.is_absolute
+                                or not self.config.sla_limit_relative)
+                # pod counts split at the job's own current-window boundary —
+                # the exact region/history split the demand and capacity use
+                pc = _pod_count_stats(tps_it.pod_window, tps_it.current.start)
+                if pc is not None:
+                    pods_now[i], pods_hist[i] = pc
+                    had_pods[i] = True
+
+            arrays = [tv, tm, np.asarray(n_hs, np.int32),
+                      np.asarray([w.values.shape[0] for w in tps_w], np.int32),
+                      sv, sm, limits, modes, absolutes, pods_now, pods_hist]
+            row_elems = np.asarray([t.values.shape[0] + s.values.shape[0]
+                                    for t, s in zip(tps_w, sla_w)])
+            sp.attrs["bytes"] = _nbytes(arrays)
 
         def hpa_fn(tv_c, tm_c, nh_c, n_c, sv_c, sm_c, lim_c, mode_c, abs_c,
                    pn_c, ph_c):
@@ -1970,15 +2037,7 @@ class Analyzer:
                 pods_now=pn_c, pods_hist=ph_c, sla_absolute=abs_c,
             )
 
-        launches = self._launch_chunks(
-            hpa_fn,
-            [tv, tm, np.asarray(n_hs, np.int32),
-             np.asarray([w.values.shape[0] for w in tps_w], np.int32),
-             sv, sm, limits, modes, absolutes, pods_now, pods_hist],
-            row_elems=np.asarray(
-                [t.values.shape[0] + s.values.shape[0]
-                 for t, s in zip(tps_w, sla_w)]),
-        )
+        launches = self._launch_chunks(hpa_fn, arrays, row_elems=row_elems)
         return (rows, launches, had_pods)
 
     def _collect_hpa(self, state) -> dict:
@@ -2256,7 +2315,7 @@ class Analyzer:
 
         def prep_many(chunk):
             out = []
-            sums = dict.fromkeys(tracing.POOL_SPANS, 0.0)
+            sums = dict.fromkeys(_POOL_NOTES, 0.0)
             with tracing.tracer.attach(ctx):
                 for doc in chunk:
                     if (deadline is not None and doc.id != guaranteed
@@ -2299,7 +2358,9 @@ class Analyzer:
                 cpu = wall = 0.0
                 while (probed < step and cpu < POOL_PROBE_CPU_S
                        and wall < POOL_PROBE_WALL_S):
-                    results += merged(prep_many(claimed[probed:probed + 1]))
+                    with tracing.annotate(tracing.SPAN_ENGINE_FETCH):
+                        result = prep_many(claimed[probed:probed + 1])
+                    results += merged(result)
                     probed += 1
                     cpu = time.thread_time() - c0
                     wall = time.perf_counter() - t0
@@ -2323,9 +2384,13 @@ class Analyzer:
             chunks[0] = chunks[0][probed:]
         if width <= 1:
             # no pool: one chunk after another on this thread, each yielded
-            # as it completes, so full rungs still launch between fetches
+            # as it completes, so full rungs still launch between fetches.
+            # The annotation names the chunk's fetch in a device trace; it
+            # closes before the yield, so the routing stays outside it
             for chunk in chunks:
-                yield from merged(prep_many(chunk))
+                with tracing.annotate(tracing.SPAN_ENGINE_FETCH):
+                    result = prep_many(chunk)
+                yield from merged(result)
             return
         with ThreadPoolExecutor(max_workers=width) as ex:
             for result in ex.map(prep_many, chunks):
@@ -2345,7 +2410,14 @@ class Analyzer:
         clock reads it), so `route + memo_fp - route_cpu` is the wait for
         the interpreter lock. (At pool width 1 the preprocess itself runs
         on this thread: its wall seconds are `wait`, and its CPU is in
-        `busy_cpu`.)"""
+        `busy_cpu`.) The pool's `fetch_seconds` sum becomes its two
+        remainders, `cache` and `items` (tracing.POOL_SPANS), so that the
+        pool's parts add up to its `prep` thread-seconds."""
+        fetch = pool.pop("fetch_seconds", 0.0)
+        pool["cache_thread_seconds"] = fetch - sum(
+            pool.get(k, 0.0) for k in _POOL_NOTES[2:])
+        pool["items_thread_seconds"] = (
+            pool.get("prep_thread_seconds", 0.0) - fetch)
         # streamed screens and fires so far (finish() adds its own)
         screens = pipe.triage.seconds if pipe.triage else 0.0
         named = pipe.stage_seconds["dispatch"] + pipe.memo_seconds + screens

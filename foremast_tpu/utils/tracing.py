@@ -45,7 +45,7 @@ the runtime an always-on, zero-dependency tracer:
   * inside jit nothing can be timed from Python — device work is traced by
     XLA itself; `span` additionally emits a `jax.profiler.TraceAnnotation`
     so host spans line up with device timelines when a profiler is
-    attached.
+    attached; `annotate` emits the annotation alone.
 
 Span names are REGISTERED constants (`SPAN_NAMES` below, plus the
 `SCORE_SPANS`/`STAGE_SPANS`/`POOL_SPANS` derived maps): the devtools
@@ -69,8 +69,8 @@ except Exception:  # pragma: no cover - jax always present in this build
 
 __all__ = [
     "Tracer", "TraceContext", "TraceContextFilter", "tracer", "span",
-    "install_log_filter", "SPAN_NAMES", "SCORE_SPANS", "STAGE_SPANS",
-    "POOL_SPANS",
+    "annotate", "install_log_filter", "SPAN_NAMES", "SCORE_SPANS",
+    "STAGE_SPANS", "POOL_SPANS",
     "W3CContext", "parse_traceparent", "mint_trace_id", "mint_span_id",
     "TRACEPARENT_HEADER",
 ]
@@ -93,6 +93,12 @@ SPAN_ENGINE_VERDICT = "engine.verdict"
 # materialize), fold, publish
 SPAN_ENGINE_ADVANCE = "engine.advance"
 SPAN_ENGINE_DISPATCH = "engine.dispatch"
+# a dispatch's pack, in its order (engine.launch is their sibling): the
+# per-row host arrays, the (B, T) blocks and (B,) vectors, and a chunk's
+# row slices with their edge padding up to the rung
+SPAN_ENGINE_PACK_ROWS = "engine.pack.rows"
+SPAN_ENGINE_PACK_BLOCK = "engine.pack.block"
+SPAN_ENGINE_PACK_PAD = "engine.pack.pad"
 SPAN_ENGINE_LAUNCH = "engine.launch"
 # a seasonal band launch's one wait: from the enqueue of period detection
 # to the (rows,) periods on the host (under engine.launch)
@@ -107,6 +113,9 @@ SPAN_ENGINE_PUBLISH = "engine.publish"
 SPAN_ENGINE_ROUTE = "engine.route"
 SPAN_ENGINE_ROUTE_CPU = "engine.route.cpu"
 SPAN_ENGINE_MEMO_FP = "engine.memo_fp"
+# the fetch of a chunk of jobs on the cycle thread, as a profiler
+# annotation alone (`annotate`): never on a pool thread
+SPAN_ENGINE_FETCH = "engine.fetch"
 SPAN_INGEST_RECEIVE = "ingest.receive"
 SPAN_INGEST_FORWARD = "ingest.forward"
 SPAN_INGEST_WAL = "ingest.wal_append"
@@ -124,12 +133,18 @@ SCORE_SPANS = {
 # the fetch pool's per-job notes, summed over its threads: THREAD-seconds
 # (16 threads can book 16 s in one wall second), add_timing only — no span
 # is ever opened on a pool thread. `lock_held` is serial by construction,
-# so its thread-seconds are wall seconds.
+# so its thread-seconds are wall seconds. A job's `prep` is the partition
+# url + cache + items + source + lock_wait + lock_held: `cache` (the fetch
+# less its named parts) and `items` (prep less the fetch) are remainders,
+# computed per cycle from the sums of the notes, `fetch_seconds` with them.
 POOL_SPANS = {
     "prep_thread_seconds": "engine.pool.prep",
+    "url_thread_seconds": "engine.pool.url",
     "source_thread_seconds": "engine.pool.source",
     "lock_wait_thread_seconds": "engine.pool.lock_wait",
     "lock_held_seconds": "engine.pool.lock_held",
+    "cache_thread_seconds": "engine.pool.cache",
+    "items_thread_seconds": "engine.pool.items",
 }
 
 # per-stage cycle timing accumulators (engine.stage.<stage>)
@@ -144,10 +159,11 @@ SPAN_NAMES = frozenset({
     SPAN_ENGINE_CYCLE, SPAN_ENGINE_CLAIM, SPAN_ENGINE_PREPROCESS,
     SPAN_ENGINE_SCORE, SPAN_ENGINE_LSTM_TRAIN, SPAN_ENGINE_TRIAGE,
     SPAN_ENGINE_VERDICT, SPAN_ENGINE_ADVANCE, SPAN_ENGINE_DISPATCH,
+    SPAN_ENGINE_PACK_ROWS, SPAN_ENGINE_PACK_BLOCK, SPAN_ENGINE_PACK_PAD,
     SPAN_ENGINE_LAUNCH, SPAN_ENGINE_DETECT_PERIOD, SPAN_ENGINE_COLLECT,
     SPAN_ENGINE_MATERIALIZE,
     SPAN_ENGINE_FOLD, SPAN_ENGINE_PUBLISH, SPAN_ENGINE_ROUTE,
-    SPAN_ENGINE_ROUTE_CPU, SPAN_ENGINE_MEMO_FP,
+    SPAN_ENGINE_ROUTE_CPU, SPAN_ENGINE_MEMO_FP, SPAN_ENGINE_FETCH,
     SPAN_INGEST_RECEIVE, SPAN_INGEST_FORWARD, SPAN_INGEST_WAL,
     SPAN_INGEST_SPLICE,
     *SCORE_SPANS.values(), *STAGE_SPANS.values(), *POOL_SPANS.values(),
@@ -582,6 +598,26 @@ class Tracer:
 
 tracer = Tracer()  # process-wide default
 span = tracer.span
+
+
+@contextmanager
+def annotate(name: str):
+    """A `jax.profiler.TraceAnnotation` alone: it names a stretch of host
+    time in a device trace and nothing else (no ring span, no stats, no
+    ids), for work too fine-grained for a span. Names are registered like
+    span names."""
+    ann = None
+    if _TraceAnnotation is not None:
+        try:
+            ann = _TraceAnnotation(name)
+            ann.__enter__()
+        except Exception:  # profiler unavailable: nothing to name
+            ann = None
+    try:
+        yield
+    finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
 
 class TraceContextFilter(logging.Filter):

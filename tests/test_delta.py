@@ -269,9 +269,10 @@ def test_delta_bytes_saved_accounting():
 @pytest.mark.parametrize("path", ["full", "delta", "append"])
 def test_fetch_notes_where_its_seconds_went(path):
     """Under an open per-job note accumulator (the engine's fetch pool)
-    a fetch says how long it queued for the splice lock, held it, and sat
-    in the inner source's call; with none open it notes nothing. A delta
-    the append rule served notes `fetch_append` beside `fetch_delta`."""
+    a fetch says how long it queued for the splice lock, held it, sat in
+    the inner source's call and spent on its URL; with none open it notes
+    nothing. A delta the append rule served notes `fetch_append` beside
+    `fetch_delta`."""
     import time
 
     be = _Backend()
@@ -304,8 +305,139 @@ def test_fetch_notes_where_its_seconds_went(path):
     assert notes["source_thread_seconds"] >= 0.01
     assert notes["lock_held_seconds"] > 0
     assert notes["lock_wait_thread_seconds"] >= 0
+    assert notes["url_thread_seconds"] > 0
     assert (notes["source_thread_seconds"] + notes["lock_held_seconds"]
-            + notes["lock_wait_thread_seconds"]) <= elapsed
+            + notes["lock_wait_thread_seconds"]
+            + notes["url_thread_seconds"]) <= elapsed
+
+
+def _series_of(n, end_nan=False):
+    out = [(T0 + i * STEP, float(i % 7)) for i in range(n)]
+    if end_nan:
+        out[-1] = (out[-1][0], float("nan"))
+    return out
+
+
+@pytest.mark.parametrize("path", [
+    "no_range", "full", "unmoved", "ingest", "delta", "append",
+    "range_extended", "splice_mismatch"])
+def test_a_fetch_notes_its_url_seconds_once(path, monkeypatch):
+    """Whatever path a fetch takes, its URL seconds (the range's parse,
+    the cache key, the delta query's range) are noted once, with the rest
+    of its seconds: a fetch that falls back to a full refetch after the
+    splice path noted, or before it did, notes them once either way."""
+    be = _Backend()
+    be.series["a"] = _series_of(200, end_nan=path == "delta")
+    dsrc = DeltaWindowSource(be.source())
+    end = T0 + 199 * STEP
+    start = T0
+    if path == "no_range":
+        body = _body(be.series["a"])
+        dsrc = DeltaWindowSource(RawFixtureDataSource(
+            resolver=lambda url: body))
+        url = "http://prom/a?query=x&step=60"
+    else:
+        if path != "full":
+            dsrc.fetch_window(_url("a", T0, end))
+        if path == "ingest":
+            assert dsrc.ingest_append(_url("a", T0, end), [end + STEP],
+                                      [1.0])["advanced"]
+            end += STEP
+        elif path in ("delta", "append", "splice_mismatch"):
+            be.series["a"].append((end + STEP, 1.0))
+            end += STEP
+            if path == "splice_mismatch":
+                # history rewritten inside the overlap the query re-reads
+                t, v = be.series["a"][-4]
+                be.series["a"][-4] = (t, v + 100.0)
+        elif path == "range_extended":
+            start -= 10 * STEP
+        url = _url("a", start, end)
+    writes = []
+    add_note = tracing.tracer.add_note
+
+    def counting(key, inc=1.0):
+        if key == "url_thread_seconds":
+            writes.append(inc)
+        add_note(key, inc)
+
+    monkeypatch.setattr(tracing.tracer, "add_note", counting)
+    tracing.tracer.begin_notes()
+    try:
+        dsrc.fetch_window(url)
+    finally:
+        notes = tracing.tracer.take_notes()
+    # a full refetch after a noted splice notes no URL seconds of its own
+    assert [w > 0 for w in writes] == [True] + [False] * (
+        path == "splice_mismatch")
+    assert notes["url_thread_seconds"] == writes[0]
+    counters = {"unmoved": dsrc.unmoved_hits, "ingest": dsrc.ingest_hits,
+                "append": dsrc.append_hits, "delta": dsrc.delta_hits}
+    if path in counters:
+        assert counters[path] == 1
+    if path in ("range_extended", "splice_mismatch"):
+        assert dsrc.fallbacks == {path: 1}
+
+
+def test_source_seconds_hold_the_store_and_url_seconds_the_url(monkeypatch):
+    """Two cycles of jobs whose current window is a placeholder URL over a
+    delta source: the pool's `url` holds the placeholders' materialization
+    and the delta query's range (slowed here), its `source` the inner
+    source's call alone, as the store itself counts it, and the six parts
+    add up to the pool's `prep` thread-seconds."""
+    import time
+
+    from foremast_tpu.dataplane import delta as delta_mod
+
+    now0 = T0 + 5000 * STEP
+    be = _Backend()
+    for i in range(3):
+        be.series[f"c{i}"] = [(T0 + k * STEP, float(k % 5 + i))
+                              for k in range(5100)]
+    inner = be.source()
+    counted = []
+    fetch_series = inner.fetch_series
+
+    def timed(url):
+        t0 = time.perf_counter()
+        try:
+            return fetch_series(url)
+        finally:
+            counted.append(time.perf_counter() - t0)
+
+    inner.fetch_series = timed
+    set_range = delta_mod._set_range
+    slowed = []
+
+    def slow_set_range(*a):
+        time.sleep(0.005)
+        slowed.append(1)
+        return set_range(*a)
+
+    monkeypatch.setattr(delta_mod, "_set_range", slow_set_range)
+    store = JobStore()
+    for i in range(3):
+        store.create(Document(
+            id=f"m{i}", app_name=f"app-m{i}", namespace="px",
+            strategy="continuous", start_time=to_rfc3339(0.0),
+            end_time=to_rfc3339(now0 + 10 * 86400.0),
+            metrics={"latency": MetricQueries(
+                current=f"http://prom/c{i}?query=x&start=START_TIME"
+                        "&end=END_TIME&step=60")}))
+    eng = Analyzer(EngineConfig(), DeltaWindowSource(inner), store)
+    for c in range(2):
+        counted.clear()
+        eng.run_cycle(now=now0 + c * STEP)
+        pool = eng.last_cycle_stages["partition"]["pool"]
+        parts = ("url", "cache", "items", "source", "lock_wait")
+        total = sum(pool[k + "_thread_seconds"] for k in parts) \
+            + pool["lock_held_seconds"]
+        assert total == pytest.approx(pool["prep_thread_seconds"], abs=1e-5)
+        assert pool["source_thread_seconds"] == pytest.approx(
+            sum(counted), abs=1e-3)
+        assert pool["url_thread_seconds"] > 0.005 * len(slowed)
+    assert len(slowed) == 3  # one delta query a job, in the second cycle
+    assert eng.source.delta_hits == 3
 
 
 # ------------------------------------------------------- the append rule

@@ -970,6 +970,98 @@ def test_no_span_opens_on_a_fetch_pool_thread(monkeypatch):
         "prep_thread_seconds"] > 0
 
 
+# ------------------------- the fetch's parts and the pack's pieces (ISSUE 40)
+_POOL_PARTS = ("url_thread_seconds", "cache_thread_seconds",
+               "items_thread_seconds", "source_thread_seconds",
+               "lock_wait_thread_seconds", "lock_held_seconds")
+
+
+@pytest.mark.parametrize("forced", [1, "cap"], ids=["width1", "cap"])
+def test_fetch_pool_parts_add_up_to_prep(monkeypatch, forced):
+    """The pool's six parts are a partition of its `prep` thread-seconds,
+    on the span and on /status, at width 1 and across a pool whose store
+    sleeps; every part a fetch of this fleet works is over 0."""
+    eng, _, src, _ = _probed_engine(monkeypatch, forced,
+                                    source_kw={"sleep": 0.001})
+    eng.run_cycle(now=1000.0)
+    pool = eng.last_cycle_stages["partition"]["pool"]
+    me = threading.current_thread().name
+    assert ({t for t, _ in src.seen} == {me}) == (forced == 1)
+    assert sum(pool[k] for k in _POOL_PARTS) == pytest.approx(
+        pool["prep_thread_seconds"], abs=1e-5)
+    for k in ("url_thread_seconds", "cache_thread_seconds",
+              "items_thread_seconds"):
+        assert pool[k] > 0, k
+    # the fixture source is not a delta source: its sleep is the cache's
+    assert pool["cache_thread_seconds"] >= 0.001 * len(src.seen)
+    assert "fetch_seconds" not in pool
+    prep = next(s for s in _spans(_cycle_root(eng))
+                if s["name"] == tracing.SPAN_ENGINE_PREPROCESS)["attrs"]
+    for k in (*_POOL_PARTS, "prep_thread_seconds"):
+        assert prep["pool_" + k] == pool[k], k
+
+
+@pytest.mark.parametrize("algorithm", ["moving_average_all", "holt_winters"],
+                         ids=["band", "band_partitioned"])
+def test_every_dispatch_packs_in_three_spans(algorithm):
+    """Each engine.dispatch (pair, band, bivariate, hpa) holds one
+    engine.pack.rows, .block and .pad, in that order before its launches,
+    each with the dispatch's `rows` and the bytes it wrote; a seasonal
+    band launch, partitioned by period, packs the same way."""
+    store, fixtures = _mixed_fleet(n_pair=20, n_band=4, n_bi=2, n_lstm=0,
+                                   n_hpa=2)
+    eng = Analyzer(EngineConfig(pipeline_fire_rows=16, algorithm=algorithm),
+                   FixtureDataSource(fixtures), store)
+    eng.run_cycle(now=1000.0)
+    root = _cycle_root(eng)
+    dispatches = [s for s in _spans(root)
+                  if s["name"] == tracing.SPAN_ENGINE_DISPATCH]
+    assert {d["attrs"]["family"] for d in dispatches} == {
+        "pair", "band", "bivariate", "hpa"}
+    pieces = (tracing.SPAN_ENGINE_PACK_ROWS, tracing.SPAN_ENGINE_PACK_BLOCK,
+              tracing.SPAN_ENGINE_PACK_PAD)
+    for d in dispatches:
+        kids = d["children"]
+        names = [c["name"] for c in kids]
+        assert names[:3] == list(pieces), names
+        assert set(names[3:]) == {tracing.SPAN_ENGINE_LAUNCH}, names
+        family, rows = d["attrs"]["family"], d["attrs"]["rows"]
+        written = {}
+        for c in kids[:3]:
+            assert c["attrs"]["rows"] == rows
+            assert isinstance(c["attrs"]["bytes"], int)
+            written[c["name"]] = c["attrs"]["bytes"]
+        # pair and bivariate rows are built on the stream, not here
+        assert (written[pieces[0]] > 0) == (family in ("band", "hpa"))
+        assert written[pieces[1]] > 0
+        # a rung the rows fill is not padded
+        padded = sum(s["attrs"]["padded_rows"] - s["attrs"]["rows"]
+                     for s in kids[3:])
+        assert (written[pieces[2]] > 0) == (padded > 0)
+    detects = [s for s in _spans(root)
+               if s["name"] == tracing.SPAN_ENGINE_DETECT_PERIOD]
+    assert bool(detects) == (algorithm == "holt_winters")
+
+
+def test_cycle_root_stays_whole_with_streamed_launches():
+    """Ten streamed pair dispatches and the flush: every span of the cycle
+    keeps all its children (a root that dropped one reads as missing to
+    every span metric of the benchmark)."""
+    store, fixtures = _mixed_fleet(n_pair=160, n_band=4, n_bi=2, n_lstm=0,
+                                   n_hpa=2)
+    eng = Analyzer(EngineConfig(pipeline_fire_rows=16),
+                   FixtureDataSource(fixtures), store)
+    eng.run_cycle(now=1000.0)
+    spans = list(_spans(_cycle_root(eng)))
+    assert not [s["name"] for s in spans if s.get("children_dropped")]
+    dispatches = [s for s in spans
+                  if s["name"] == tracing.SPAN_ENGINE_DISPATCH]
+    assert len(dispatches) >= 13
+    assert sum(c["name"].startswith("engine.pack.")
+               for d in dispatches for c in d["children"]) \
+        == 3 * len(dispatches)
+
+
 # ------------------------------------- the fetch pool's width (ISSUE 32)
 # No case here depends on the machine's load: the rule is held to numbers
 # as a pure function, and a case that drives a cycle either patches the

@@ -240,6 +240,74 @@ def test_notes_accumulate_per_thread_unit_of_work():
     assert tr.take_notes() == {}  # closed
 
 
+def test_annotate_names_a_stretch_and_records_nothing(monkeypatch):
+    """`annotate` opens a profiler annotation under its name and closes it,
+    even when the body raises, and leaves no span in the ring and no stats."""
+    from foremast_tpu.utils import tracing
+
+    seen = []
+
+    class Recording:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(tracing, "_TraceAnnotation", Recording)
+    tracing.tracer.reset()
+    with tracing.annotate(tracing.SPAN_ENGINE_FETCH):
+        pass
+    with pytest.raises(KeyError):
+        with tracing.annotate(tracing.SPAN_ENGINE_FETCH):
+            raise KeyError("body")
+    assert seen == [("enter", "engine.fetch"), ("exit", "engine.fetch")] * 2
+    assert tracing.tracer.snapshot() == [] and tracing.tracer.stats() == {}
+    # no profiler to annotate for: a plain block
+    monkeypatch.setattr(tracing, "_TraceAnnotation", None)
+    with tracing.annotate(tracing.SPAN_ENGINE_FETCH):
+        pass
+    assert tracing.tracer.stats() == {}
+
+
+@pytest.mark.parametrize("name, registered", [
+    ("tracing.SPAN_ENGINE_FETCH", True),
+    ('"engine.fetch"', True),
+    ('"engine.pack.pad"', True),
+    ('"engine.fetch.nowhere"', False),
+])
+def test_annotate_names_pass_the_trace_registry_rule(name, registered):
+    import os
+    import textwrap
+
+    import foremast_tpu
+    from foremast_tpu.devtools.checks import TraceNameRegistry
+    from foremast_tpu.devtools.linter import (
+        Baseline,
+        ModuleInfo,
+        load_module,
+        run_lint,
+    )
+
+    root = os.path.dirname(os.path.dirname(foremast_tpu.__file__))
+    checker = TraceNameRegistry()
+    rel = "foremast_tpu/utils/tracing.py"
+    checker.check(load_module(os.path.join(root, rel), rel))
+    mod = ModuleInfo("<fixture>", "foremast_tpu/engine/fixture.py",
+                     textwrap.dedent(f"""
+        from foremast_tpu.utils import tracing
+
+        def f():
+            with tracing.annotate({name}):
+                pass
+    """))
+    findings = run_lint([checker], [mod], Baseline()).findings
+    assert (not findings) == registered, [f.render() for f in findings]
+
+
 # --------------------------------------------------- W3C trace context
 def test_parse_traceparent_valid_and_flags():
     tid, sid = "a" * 32, "b" * 16
