@@ -994,8 +994,30 @@ class Analyzer:
         self.d2h_bytes_total += a.nbytes
         return a
 
+    def _chunk_plan(self, B: int, T: int) -> list:
+        """[(first row, real rows, launched rows)] of each chunk
+        `_launch_chunks` cuts from B real rows of T-wide blocks: full
+        chunks of C rows, then the last one padded to its target (a rung,
+        or under MEGABATCH a mega class)."""
+        mega = self.config.megabatch
+        C = self._mega_cap(T) if mega else self._bucket_rows(B)
+        plan = []
+        for i in range(0, B, C):
+            n = min(C, B - i)
+            plan.append((i, n, min(self._mega_rows(n), C) if mega
+                         else self._bucket_rows(n)))
+        return plan
+
+    def _launch_rows(self, B: int, T: int) -> int:
+        """Rows `_launch_chunks` hands its launches for B real rows of
+        T-wide blocks: the row count a launch half allocates its blocks
+        at, so that a chunk is a view of them and not a padded copy."""
+        plan = self._chunk_plan(B, T)
+        return plan[-1][0] + plan[-1][2] if plan else 0
+
     def _launch_chunks(self, fn, arrays: list, donate: int = 0,
-                       row_elems=None, with_rows: bool = False) -> list:
+                       row_elems=None, with_rows: bool = False,
+                       rows: int | None = None) -> list:
         """Row-chunk packed (B, ...) arrays into FIXED batch buckets and
         call fn per chunk WITHOUT materializing the outputs.
 
@@ -1009,6 +1031,15 @@ class Analyzer:
         smallest rung that fits — never to the full chunk — with edge
         padding (repeat of the last row — always semantically valid
         inputs); padded rows are trimmed on merge.
+
+        Who writes the pad rows: `rows` is the real row count (default:
+        the first array's). An array that already reaches a chunk's
+        target, because its caller allocated it at `_launch_rows(rows, T)`
+        with the rows past `rows` written as copies of the last real row
+        (the (B, T) blocks of the band, pair and bivariate launch halves),
+        is handed on as a view of its rows, with no copy; any other array
+        (the (B,) vectors, every array of a caller that did not pre-size)
+        is cut and edge-padded here.
 
         Returns [(out_dict, n_valid_rows)] in row order. The out dicts
         hold whatever fn returned — for jitted scorers these are
@@ -1026,35 +1057,30 @@ class Analyzer:
         count as `rows=` (the band closure partitions the real rows, not
         the edge padding).
         """
-        B = arrays[0].shape[0]
+        B = arrays[0].shape[0] if rows is None else rows
         mega = self.config.megabatch
-        if mega:
-            # single-dispatch mega-batching: ONE launch for the whole
-            # accumulated batch (chunked only at the memory-aware cap),
-            # padded to the fine mega class instead of rung-chunked.
-            # Row-wise scorers make the launch boundary verdict-neutral
-            # (the same argument the streamed-vs-flushed determinism
-            # tests pin), so this changes launch count, never results.
-            T = max((a.shape[1] for a in arrays if a.ndim > 1),
-                    default=1024)
-            C = self._mega_cap(T)
-        else:
-            C = self._bucket_rows(B)
+        # single-dispatch mega-batching: ONE launch for the whole
+        # accumulated batch (chunked only at the memory-aware cap of its
+        # T), padded to the fine mega class instead of rung-chunked.
+        # Row-wise scorers make the launch boundary verdict-neutral
+        # (the same argument the streamed-vs-flushed determinism
+        # tests pin), so this changes launch count, never results.
+        T = max((a.shape[1] for a in arrays if a.ndim > 1), default=1024)
         # every chunk's rows, cut and padded before the first launch: one
         # span a dispatch, whether or not a chunk needs padding
         chunks = []
         with tracing.span(tracing.SPAN_ENGINE_PACK_PAD, rows=B) as sp:
             written = 0
-            for i in range(0, B, C):
-                sl = [a[i:i + C] for a in arrays]
-                n = sl[0].shape[0]
-                target = (min(self._mega_rows(n), C) if mega
-                          else self._bucket_rows(n))
-                if n < target:
-                    sl = [np.pad(a, ((0, target - n),)
-                                 + ((0, 0),) * (a.ndim - 1), mode="edge")
-                          for a in sl]
-                    written += _nbytes(sl)
+            for i, n, target in self._chunk_plan(B, T):
+                sl = []
+                for a in arrays:
+                    if a.shape[0] >= i + target:
+                        sl.append(a[i:i + target])
+                        continue
+                    a = np.pad(a[i:i + n], ((0, target - n),)
+                               + ((0, 0),) * (a.ndim - 1), mode="edge")
+                    written += a.nbytes
+                    sl.append(a)
                 chunks.append((i, sl, n, target))
             sp.attrs["bytes"] = written
         launches = []
@@ -1062,7 +1088,7 @@ class Analyzer:
             self.device_launches += 1
             call = partial(fn, rows=n) if with_rows else fn
             if row_elems is not None:
-                self.pack_real_elems_total += int(row_elems[i:i + C].sum())
+                self.pack_real_elems_total += int(row_elems[i:i + n].sum())
                 self.pack_total_elems_total += sum(
                     a.size for a in sl if a.ndim == 2 and a.dtype.kind == "f")
             h0 = self.h2d_bytes_total
@@ -1142,8 +1168,11 @@ class Analyzer:
         with tracing.span(tracing.SPAN_ENGINE_PACK_ROWS, rows=B, bytes=0):
             pass
         with tracing.span(tracing.SPAN_ENGINE_PACK_BLOCK, rows=B) as sp:
-            bvals, bm = pack_windows([it.baseline for it in group], pad_to=T)
-            cv, cm = pack_windows([it.current for it in group], pad_to=T)
+            R = self._launch_rows(B, T)
+            bvals, bm = pack_windows([it.baseline for it in group], pad_to=T,
+                                     rows=R)
+            cv, cm = pack_windows([it.current for it in group], pad_to=T,
+                                  rows=R)
             arrays = [
                 bvals, bm, cv, cm,
                 np.full(B, cfg.pairwise_threshold, np.float32),
@@ -1173,9 +1202,9 @@ class Analyzer:
             row_elems = np.asarray(
                 [it.baseline.values.shape[0] + it.current.values.shape[0]
                  for it in group])
-            sp.attrs["bytes"] = _nbytes(arrays)
+            sp.attrs.update(bytes=_nbytes(arrays), edge_rows=R - B)
         launches = self._launch_chunks(fl.score_pairs, arrays, donate=4,
-                                       row_elems=row_elems)
+                                       row_elems=row_elems, rows=B)
         return (group, launches)
 
     def _collect_pairs(self, state) -> dict:
@@ -1316,7 +1345,8 @@ class Analyzer:
                 written += vals.nbytes + mask.nbytes
             sp.attrs["bytes"] = written
         with tracing.span(tracing.SPAN_ENGINE_PACK_BLOCK, rows=B) as sp:
-            xv, xm = pack_windows(concats, pad_to=T)
+            R = self._launch_rows(B, T)
+            xv, xm = pack_windows(concats, pad_to=T, rows=R)
             ns = np.asarray([c.values.shape[0] for c in concats], np.int32)
             arrays = [
                 xv, xm, np.asarray(n_hs, np.int32), ns,
@@ -1325,7 +1355,7 @@ class Analyzer:
                 np.asarray([it.policy.min_lower_bound for it in group],
                            np.float32),
             ]
-            sp.attrs["bytes"] = _nbytes(arrays)
+            sp.attrs.update(bytes=_nbytes(arrays), edge_rows=R - B)
 
         def band_fn(xv_c, xm_c, nh_c, n_c, thr_c, bnd_c, mlb_c, rows):
             # the chunk crosses to the device once and the launch's programs
@@ -1376,7 +1406,7 @@ class Analyzer:
             return out
 
         launches = self._launch_chunks(band_fn, arrays, row_elems=ns,
-                                       with_rows=True)
+                                       with_rows=True, rows=B)
         return (group, launches, xv, n_hs)
 
     def _collect_bands(self, state) -> dict:
@@ -1429,10 +1459,13 @@ class Analyzer:
         with tracing.span(tracing.SPAN_ENGINE_PACK_ROWS, rows=B, bytes=0):
             pass
         with tracing.span(tracing.SPAN_ENGINE_PACK_BLOCK, rows=B) as sp:
-            x1 = np.zeros((B, T), np.float32)
-            x2 = np.zeros((B, T), np.float32)
-            m1 = np.zeros((B, T), bool)
-            m2 = np.zeros((B, T), bool)
+            # the blocks at the rows they are launched at (pack_windows'
+            # rule: a pad row repeats the last real row's samples)
+            R = self._launch_rows(B, T)
+            x1 = np.zeros((R, T), np.float32)
+            x2 = np.zeros((R, T), np.float32)
+            m1 = np.zeros((R, T), bool)
+            m2 = np.zeros((R, T), bool)
             n_hist = np.empty(B, np.int32)
             n_total = np.empty(B, np.int32)
             thr = np.empty(B, np.float32)
@@ -1452,12 +1485,15 @@ class Analyzer:
                 mlb2[i] = it.policies[1].min_lower_bound
                 bm1[i] = it.policies[0].bound
                 bm2[i] = it.policies[1].bound
+            n = n_total[B - 1]
+            for a in (x1, m1, x2, m2):
+                a[B:, :n] = a[B - 1, :n]
             arrays = [x1, m1, x2, m2, n_hist, n_total, thr, mlb1, mlb2, bm1,
                       bm2]
-            sp.attrs["bytes"] = _nbytes(arrays)
+            sp.attrs.update(bytes=_nbytes(arrays), edge_rows=R - B)
         launches = self._launch_chunks(bv.bivariate_normal_anomalies,
                                        arrays, donate=4,
-                                       row_elems=2 * n_total)
+                                       row_elems=2 * n_total, rows=B)
         return (entries, launches)
 
     def _collect_bivariate(self, state) -> dict:

@@ -152,12 +152,21 @@ def bucket_length(T: int) -> int:
     raise ValueError(f"window length {T} exceeds max bucket {_BUCKETS[-1]}")
 
 
-def pack_windows(windows: Sequence[Window], pad_to: int | None = None):
+def pack_windows(windows: Sequence[Window], pad_to: int | None = None,
+                 rows: int | None = None):
     """Pack windows into dense (B, T) value/mask arrays, right-padded.
 
     Returns (values (B,T) float32, mask (B,T) bool). T is the common bucket
     for the longest member unless `pad_to` pins it (e.g. to batch canary and
     baseline windows together).
+
+    `rows` (at least B) allocates the arrays at that many rows, and the
+    rows past B are the edge padding, written here: each repeats the last
+    window's row. A launch half asks for the row count its chunks are
+    launched at, so the launch slices the block and copies nothing. Only
+    the last window's samples are written into a pad row; the columns
+    past them stay the zeros the allocation gave, which is that row
+    already, and a page nothing writes is never touched on the host.
 
     Numpy on purpose, even at mega-batch sizes: a native batched pack was
     measured (PR 15) and LOST — extracting per-row data pointers for the
@@ -175,10 +184,16 @@ def pack_windows(windows: Sequence[Window], pad_to: int | None = None):
             "truncating would silently drop the most recent samples"
         )
     B = len(windows)
-    vals = np.zeros((B, T), dtype=np.float32)
-    mask = np.zeros((B, T), dtype=bool)
+    R = B if rows is None else rows
+    if R < B:
+        raise ValueError(f"rows={R} cannot hold {B} windows")
+    vals = np.zeros((R, T), dtype=np.float32)
+    mask = np.zeros((R, T), dtype=bool)
     for i, w in enumerate(windows):
         n = w.values.shape[0]
         vals[i, :n] = w.values
         mask[i, :n] = w.mask
+    n = windows[-1].values.shape[0]
+    vals[B:, :n] = vals[B - 1, :n]
+    mask[B:, :n] = mask[B - 1, :n]
     return vals, mask

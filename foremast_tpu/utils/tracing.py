@@ -94,8 +94,9 @@ SPAN_ENGINE_VERDICT = "engine.verdict"
 SPAN_ENGINE_ADVANCE = "engine.advance"
 SPAN_ENGINE_DISPATCH = "engine.dispatch"
 # a dispatch's pack, in its order (engine.launch is their sibling): the
-# per-row host arrays, the (B, T) blocks and (B,) vectors, and a chunk's
-# row slices with their edge padding up to the rung
+# per-row host arrays, the (B, T) blocks (at the rows they are launched
+# at, edge rows written in place: attr edge_rows) and (B,) vectors, and a
+# chunk's row slices with the edge padding of what was not pre-sized
 SPAN_ENGINE_PACK_ROWS = "engine.pack.rows"
 SPAN_ENGINE_PACK_BLOCK = "engine.pack.block"
 SPAN_ENGINE_PACK_PAD = "engine.pack.pad"

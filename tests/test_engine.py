@@ -547,31 +547,56 @@ def test_crash_resume_e2e_snapshot_plus_lease_takeover(tmp_path):
         J.COMPLETED_UNHEALTH
 
 
-def test_score_chunks_fixed_buckets_and_edge_padding():
+@pytest.mark.parametrize("megabatch", [False, True], ids=["rungs", "mega"])
+@pytest.mark.parametrize("B", [70, 5, 32])
+@pytest.mark.parametrize("presized", [False, True],
+                         ids=["short", "presized"])
+def test_score_chunks_fixed_buckets_and_edge_padding(presized, B, megabatch):
     """_launch_chunks + _collect_chunks: chunked results equal a single whole-batch call, and
     batch sizes map to FIXED buckets so fleet-size changes cannot force
-    recompiles (B<=bucket pads up; B>chunk splits)."""
+    recompiles (B<=bucket pads up; B>chunk splits). A block its caller
+    allocated at `_launch_rows` rows, edge rows written, reaches fn as a
+    view of itself; a (B,) vector beside it is edge-padded as before."""
     from foremast_tpu.dataplane import FixtureDataSource
 
-    eng = Analyzer(EngineConfig(score_batch=32), FixtureDataSource({}), JobStore())
-    calls = []
+    eng = Analyzer(EngineConfig(score_batch=32, megabatch=megabatch),
+                   FixtureDataSource({}), JobStore())
+    calls, chunks = [], []
 
-    def fn(vals, mask):
+    def fn(vals, mask, w):
         calls.append(vals.shape[0])
-        return {"s": vals.sum(axis=1), "m": mask.any(axis=1)}
+        chunks.append((vals, mask, w))
+        return {"s": vals.sum(axis=1) + w, "m": mask.any(axis=1)}
 
     rng = np.random.default_rng(0)
-    vals = rng.normal(0, 1, (70, 8)).astype(np.float32)
-    mask = rng.random((70, 8)) > 0.5
-    out = eng._collect_chunks(eng._launch_chunks(fn, [vals, mask]))
-    # full chunks launch at 32; the 6-row tail re-buckets DOWN the ladder
-    assert calls == [32, 32, 16]
-    np.testing.assert_allclose(out["s"], vals.sum(axis=1), rtol=1e-6)
+    vals = rng.normal(0, 1, (B, 8)).astype(np.float32)
+    mask = rng.random((B, 8)) > 0.5
+    w = rng.normal(0, 1, B).astype(np.float32)
+    R = eng._launch_rows(B, 8)
+    if presized:
+        bv = np.concatenate([vals, np.repeat(vals[-1:], R - B, axis=0)])
+        bm = np.concatenate([mask, np.repeat(mask[-1:], R - B, axis=0)])
+        launches = eng._launch_chunks(fn, [bv, bm, w], rows=B)
+    else:
+        launches = eng._launch_chunks(fn, [vals, mask, w])
+    out = eng._collect_chunks(launches)
+    # rungs: full chunks launch at 32 and the 6-row tail re-buckets DOWN
+    # the ladder; a rung B fills is not padded; small batches pad UP to a
+    # fixed bucket, not down to raw B. Mega: one launch, at the mega class
+    want = ({70: [32, 32, 16], 5: [16], 32: [32]} if not megabatch
+            else {70: [256], 5: [16], 32: [64]})[B]
+    assert calls == want
+    assert sum(want) == R
+    np.testing.assert_allclose(out["s"], vals.sum(axis=1) + w, rtol=1e-6)
     np.testing.assert_array_equal(out["m"], mask.any(axis=1))
-    # small batches pad UP to a fixed bucket, not down to raw B
-    calls.clear()
-    eng._collect_chunks(eng._launch_chunks(fn, [vals[:5], mask[:5]]))
-    assert calls == [16]
+    # the rows past B repeat row B-1, in every array of the last chunk
+    i = R - want[-1]
+    for got, real in zip(chunks[-1], (vals, mask, w)):
+        assert (got[B - i:] == real[B - 1]).all()
+    if presized:
+        for k, (cv, cm, _) in enumerate(chunks):
+            assert np.shares_memory(cv, bv) and np.shares_memory(cm, bm)
+            np.testing.assert_array_equal(cv, bv[sum(want[:k]):][:want[k]])
 
 
 def test_e2e_fleet_crosses_chunk_rungs():

@@ -1007,7 +1007,10 @@ def test_every_dispatch_packs_in_three_spans(algorithm):
     """Each engine.dispatch (pair, band, bivariate, hpa) holds one
     engine.pack.rows, .block and .pad, in that order before its launches,
     each with the dispatch's `rows` and the bytes it wrote; a seasonal
-    band launch, partitioned by period, packs the same way."""
+    band launch, partitioned by period, packs the same way. Pair, band
+    and bivariate blocks are built at the rows they are launched at, the
+    edge rows written in the block (attr `edge_rows`), so the pad holds
+    only the (B,) vectors' padding; hpa does not pre-size."""
     store, fixtures = _mixed_fleet(n_pair=20, n_band=4, n_bi=2, n_lstm=0,
                                    n_hpa=2)
     eng = Analyzer(EngineConfig(pipeline_fire_rows=16, algorithm=algorithm),
@@ -1038,6 +1041,15 @@ def test_every_dispatch_packs_in_three_spans(algorithm):
         padded = sum(s["attrs"]["padded_rows"] - s["attrs"]["rows"]
                      for s in kids[3:])
         assert (written[pieces[2]] > 0) == (padded > 0)
+        if family == "hpa":
+            assert "edge_rows" not in kids[1]["attrs"]
+            continue
+        # the block's edge rows are the launches' padding, and the pad
+        # writes less than one block row a padded row: no (B, T) block
+        assert kids[1]["attrs"]["edge_rows"] == padded
+        row_bytes = written[pieces[1]] // (rows + padded)
+        assert written[pieces[2]] < padded * row_bytes or not padded, (
+            family, written[pieces[2]], padded, row_bytes)
     detects = [s for s in _spans(root)
                if s["name"] == tracing.SPAN_ENGINE_DETECT_PERIOD]
     assert bool(detects) == (algorithm == "holt_winters")

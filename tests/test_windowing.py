@@ -1,5 +1,6 @@
 """Windowing: ragged -> masked grid invariants."""
 import numpy as np
+import pytest
 
 from foremast_tpu.ops.windowing import (
     Window,
@@ -69,6 +70,30 @@ def test_pack_windows_refuses_truncation():
     ws = [Window(np.ones(100, np.float32), np.ones(100, bool), 0)]
     with pytest.raises(ValueError):
         pack_windows(ws, pad_to=64)
+
+
+@pytest.mark.parametrize("rows", [None, 3, 4, 16, 2],
+                         ids=["none", "exact", "one-more", "rung", "short"])
+def test_pack_windows_rows_edge_pads_in_place(rows):
+    """`rows` allocates the block at that many rows; the rows past the
+    windows repeat the last window's row, values and mask; `rows` equal
+    to the window count, or None, is the plain pack; fewer rows raise."""
+    rng = np.random.default_rng(41)
+    ws = [Window(rng.normal(size=n).astype(np.float32), rng.random(n) > 0.3,
+                 0) for n in (20, 7, 11)]
+    plain_v, plain_m = pack_windows(ws, pad_to=32)
+    if rows is not None and rows < len(ws):
+        with pytest.raises(ValueError):
+            pack_windows(ws, pad_to=32, rows=rows)
+        return
+    vals, mask = pack_windows(ws, pad_to=32, rows=rows)
+    R = len(ws) if rows is None else rows
+    assert vals.shape == mask.shape == (R, 32)
+    np.testing.assert_array_equal(vals[:3], plain_v)
+    np.testing.assert_array_equal(mask[:3], plain_m)
+    for r in range(3, R):
+        np.testing.assert_array_equal(vals[r], plain_v[2])
+        np.testing.assert_array_equal(mask[r], plain_m[2])
 
 
 def test_resample_masks_values_beyond_f32_range():
