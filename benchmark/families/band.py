@@ -21,8 +21,8 @@ NUMBERS = (("band_gap", "max", "band_gap_sigmas"),
 def reference_rows(fleet, jobs: list, slots: tuple, k_now: int,
                    limits: dict, precision: str = "float64") -> dict:
     (slot,) = slots
-    hist = fleet.served_rows(jobs, slot, fleet.hist_lo, fleet.hist_hi)
-    cur = fleet.served_rows(jobs, slot, fleet.hist_hi, k_now)
+    hist = fleet.role_rows(jobs, slot, "historical", k_now)
+    cur = fleet.role_rows(jobs, slot, "current", k_now)
     return reference.band_rows(hist, cur, fleet.metrics_of(jobs[0])[slot],
                                float(limits["band_gap_sigmas"]), precision)
 

@@ -70,7 +70,7 @@ def reference_rows(fleet, jobs: list, slots: tuple, k_now: int,
     (slot,) = slots
     rows = _class_rows(fleet, jobs[0], slot, k_now,
                        float(limits["hw_band_gap_sigmas"]), precision)
-    checked = k_now - fleet.hist_hi + 1
+    checked = fleet.held("current", int(fleet.class_of[jobs[0]]), k_now)
     return {"rows": [rows[j] for j in jobs],
             "gate": max(reference.BAND_MIN_POINTS,
                         reference.BAND_VIOLATION_FRACTION * checked)}

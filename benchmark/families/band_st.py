@@ -44,7 +44,7 @@ if not hasattr(_program, "st_columns"):
 def reference_rows(fleet, jobs: list, slots: tuple, k_now: int,
                    limits: dict, precision: str = "float64") -> dict:
     (slot,) = slots
-    checked = k_now - fleet.hist_hi + 1
+    checked = fleet.held("current", int(fleet.class_of[jobs[0]]), k_now)
     return {"rows": reference_st.fleet_rows(
                 fleet, jobs, slot, k_now,
                 float(limits["st_band_gap_sigmas"]), precision),

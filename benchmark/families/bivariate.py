@@ -25,10 +25,9 @@ NUMBERS = (("bi_bound_gap", "max", "bi_bound_gap_sigmas"),
 
 def reference_rows(fleet, jobs: list, slots: tuple, k_now: int,
                    limits: dict, precision: str = "float64") -> dict:
-    hists = tuple(fleet.served_rows(jobs, s, fleet.hist_lo, fleet.hist_hi)
+    hists = tuple(fleet.role_rows(jobs, s, "historical", k_now)
                   for s in slots)
-    curs = tuple(fleet.served_rows(jobs, s, fleet.hist_hi, k_now)
-                 for s in slots)
+    curs = tuple(fleet.role_rows(jobs, s, "current", k_now) for s in slots)
     metrics = tuple(fleet.metrics_of(jobs[0])[s] for s in slots)
     ref = reference.bivariate_rows(
         hists, curs, metrics, SLACK * float(limits["bi_bound_gap_sigmas"]),

@@ -18,9 +18,8 @@ NUMBERS = (("pair_p_gap", "max", "pair_p_gap"),)
 def reference_rows(fleet, jobs: list, slots: tuple, k_now: int,
                    limits: dict, precision: str = "float64") -> dict:
     (slot,) = slots
-    base = fleet.served_rows(jobs, slot, fleet.base_lo,
-                             fleet.base_lo + fleet.window_steps)
-    cur = fleet.served_rows(jobs, slot, fleet.hist_hi, k_now)
+    base = fleet.role_rows(jobs, slot, "baseline", k_now)
+    cur = fleet.role_rows(jobs, slot, "current", k_now)
     return reference.pair_rows(base, cur, fleet.metrics_of(jobs[0])[slot],
                                float(limits["band_gap_sigmas"]), precision)
 

@@ -58,6 +58,22 @@ def bivariate(rows: int, points: int, judged: int) -> dict:
             "ops": rows * (11 * (points - judged) + 14 * judged)}
 
 
+def least_over_cycles(ctx: dict, family: str,
+                      cost) -> tuple[float, str | None]:
+    """The least seconds of a family's launches over a run's traced
+    cycles: `cost(rows, c, k_now)` for the rows class `c` gave the family
+    in a cycle whose clock was at slot `k_now`, summed, and the peak that
+    bound the last of them (None where no class gave it rows)."""
+    least, bound = 0.0, None
+    for cycle in ctx["cycles"]:
+        for c, rows in cycle["class_rows"].items():
+            if rows.get(family):
+                secs, bound = least_seconds(
+                    cost(rows[family], c, cycle["now_slot"]), ctx["peaks"])
+                least += secs
+    return least, bound
+
+
 def least_seconds(cost: dict, peaks: dict) -> tuple[float, str]:
     """The least time the chip could take, and which peak bounds it."""
     by_bytes = cost["bytes"] / peaks["bytes_per_s"]

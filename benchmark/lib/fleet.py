@@ -9,16 +9,41 @@ and the query layout a copy of `simfleet/backend.py` `make_docs`, kept here
 so that a later change to the program cannot move the yardstick
 (PERF.md, Open questions, lists the originals).
 
-Grid: slot k is the sample at `t0 + k * step`. A job's windows are
-  historical  slots [lead, lead + H]        (H + 1 points, fixed)
-  current     slots [lead + H, now]         (W + 1 points at warm-up, one
-                                             more per cadence step)
-  baseline    slots [H, H + W]              (the current window's start
-                                             one diurnal period earlier,
-                                             same phase)
-where lead is one diurnal period in steps. A job that watches several
-metrics (`metrics` of its class) has these windows of each, on the same
-slots; metric i is series slot i of the job, and the anomaly is on slot 0.
+Grid: slot k is the sample at `t0 + k * step`. A job's windows are laid
+out from the current window's first slot c:
+  current     slots [c, now]              (`current_points` points at
+                                           warm-up, one more per cadence
+                                           step)
+  historical  slots [c - H, c]            (H + 1 = `history_points`)
+  baseline    slots [c - lead, c - lead + W]  (W + 1 = `current_points`:
+                                           the current window's start one
+                                           diurnal period earlier, same
+                                           phase; [c - H, c - H + W] where
+                                           the trace has no period)
+where lead is one diurnal period in steps and c = lead + H.
+
+A class may lay each role it carries elsewhere, as its request lays it:
+  "placement": {"historical": {"start": -10080, "points": 10081},
+                "baseline":   {"start": -10080, "points": 10081},
+                "current":    {"points": 31}}
+A past role (`baseline`, `historical`) names its first slot relative to
+c (`start`, whole steps, negative for earlier) and its length in points;
+it has to be whole by the warm-up clock. `current` names its greatest
+length: its URL's end is fixed at slot c + points - 1, and the run can
+drive only the cycles that fill it; its warm-up length stays
+`current_points`. A role a class does not state lies where the layout
+above lays it, so a configuration that states nothing reads bit for bit
+what it read before placement was data. c moves later only as far as the
+longest stated look-back needs, and the horizon covers the longest
+current window. A role that lies on a class's historical range is queried
+by the history's own URL, byte for byte (the source answers that range
+from arrays), as foremast-trigger's rollover request copies its
+historical query into its baseline. Whatever reads a role's slots reads
+them from `Fleet.window_slots`.
+
+A job that watches several metrics (`metrics` of its class) has these
+windows of each, on the same slots; metric i is series slot i of the job,
+and the anomaly is on slot 0.
 
 Which file judges a scoring family is the configuration's too:
 `"references": {"<family>": "<file stem>"}` names `benchmark/families/<stem>.py`
@@ -39,6 +64,9 @@ _GOLDEN = 0.6180339887
 FAMILIES_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "families")
 _STEM = re.compile(r"[A-Za-z0-9_]+\Z")
+ROLES = ("current", "baseline", "historical")
+_PAST = ("baseline", "historical")
+_TAGS = {"current": "cur", "baseline": "base", "historical": "hist"}
 
 
 class BenchError(Exception):
@@ -56,8 +84,8 @@ class Fleet:
         tr = cfg["trace"]
         self.step = int(cfg["step_s"])
         self.t0 = T0 // self.step * self.step
-        self.hist_steps = int(cfg["history_points"]) - 1
-        self.window_steps = int(cfg["current_points"]) - 1
+        hist_steps = int(cfg["history_points"]) - 1
+        warm_points = int(cfg["current_points"])
         self.max_cycles = int(cfg["max_cycles"])
         self.level = float(tr["level"])
         self.sigma = float(tr["noise_sigma"])
@@ -66,11 +94,26 @@ class Fleet:
         self.lead = int(round(self.period_s / self.step)) \
             if self.diurnal_amp else 0
         self.n_shapes = int(tr["n_shapes"])
-        self.horizon = (self.lead + self.hist_steps + self.window_steps
-                        + self.max_cycles + 16)
+        self.classes = list(cfg["classes"])
+        # the default layout of the past roles: (start, points) from the
+        # current window's first slot; a class's `placement` overrides it
+        past = {"historical": (-hist_steps, hist_steps + 1),
+                "baseline": (-(self.lead or hist_steps), warm_points)}
+        stated = [_placement(c, warm_points) for c in self.classes]
+        cur_lo = max([self.lead + hist_steps] + [
+            -st[r][0] for st in stated for r in _PAST if r in st])
+        longest = [st["current"] for st in stated if "current" in st]
+        self.horizon = max([cur_lo + warm_points - 1 + self.max_cycles + 16]
+                           + [cur_lo + n for n in longest])
+        # a fixed-end current window holds one cycle a slot past warm-up
+        self.max_cycles = min([self.max_cycles]
+                              + [n - warm_points + 1 for n in longest])
+        warm_slot = cur_lo + warm_points - 1
+        self._slots = [_slots(c, dict(past, **st), cur_lo, warm_slot,
+                              self.horizon) for c, st in zip(self.classes,
+                                                             stated)]
         # class of each job: classes interleave in proportion, so any
         # contiguous slice of the claim order carries the same mix
-        self.classes = list(cfg["classes"])
         counts = [int(c["jobs"]) for c in self.classes]
         self.jobs = sum(counts)
         self.class_of = _interleave(counts)
@@ -88,12 +131,8 @@ class Fleet:
         # the shift starts mid-way through the warm-up current window, so
         # histories and baselines stay clean and the first cycle convicts
         self.active_from = float(self.t0 + (
-            self.lead + self.hist_steps + self.window_steps // 2) * self.step)
-        self.hist_lo = self.lead
-        self.hist_hi = self.lead + self.hist_steps
-        self.base_lo = self.hist_hi - self.lead if self.lead else self.hist_lo
-        self.warm_now = float(
-            self.t0 + (self.hist_hi + self.window_steps) * self.step) + 5.0
+            cur_lo + (warm_points - 1) // 2) * self.step)
+        self.warm_now = float(self.t0 + warm_slot * self.step) + 5.0
         self.now = self.warm_now
 
     # --------------------------------------------------------------- series
@@ -180,23 +219,36 @@ class Fleet:
         every job an app of its own."""
         return f"app-{job % int(self.cls(job).get('apps', 256))}"
 
-    def window_slots(self, role: str) -> tuple[str, int, int]:
-        """(URL tag, first slot, last slot) of a window role. The three
-        roles are the job API's own; a class lists the ones its jobs
-        carry beside `current` (its `windows`)."""
-        if role == "current":
-            return "cur", self.hist_hi, self.horizon - 1
-        if role == "baseline":
-            return "base", self.base_lo, self.base_lo + self.window_steps
-        if role == "historical":
-            return "hist", self.hist_lo, self.hist_hi
-        raise ValueError(f"unknown window role {role!r}")
+    def window_slots(self, role: str, c: int) -> tuple[str, int, int]:
+        """(URL tag, first slot, last slot) of a window role of class `c`
+        (an index of `classes`): the one place a role's slots come from.
+        The three roles are the job API's own; a class lists the ones its
+        jobs carry beside `current` (its `windows`). A role that lies on
+        the class's historical range carries the history's tag."""
+        if role not in ROLES:
+            raise ValueError(f"unknown window role {role!r}")
+        return self._slots[c][role]
+
+    def held(self, role: str, c: int, k_now: int) -> int:
+        """Samples a role's window of class `c` holds with the clock at
+        slot `k_now`."""
+        _, lo, hi = self.window_slots(role, c)
+        return min(hi, k_now) - lo + 1
+
+    def role_rows(self, jobs: list, slot: int, role: str,
+                  k_now: int) -> np.ndarray:
+        """The served series of a role's window of `jobs` (one class)
+        with the clock at slot `k_now`: one row a job."""
+        _, lo, hi = self.window_slots(role, int(self.class_of[jobs[0]]))
+        return self.served_rows(jobs, slot, lo, min(hi, k_now))
 
     def queries(self, job: int) -> dict:
         """{metric: {role: url}} for one job, by its class's metrics and
         windows."""
+        c = int(self.class_of[job])
         roles = ("current", *self.cls(job)["windows"])
-        return {metric: {role: self.url(job, slot, *self.window_slots(role))
+        return {metric: {role: self.url(job, slot,
+                                        *self.window_slots(role, c))
                          for role in roles}
                 for slot, metric in enumerate(self.metrics_of(job))}
 
@@ -204,10 +256,9 @@ class Fleet:
         """Samples the job's windows hold when the clock is at slot
         `k_now`, over all its metrics: what a cycle has to have fetched
         or spliced for it."""
-        n = 0
-        for role in ("current", *self.cls(job)["windows"]):
-            _, lo, hi = self.window_slots(role)
-            n += min(hi, k_now) - lo + 1
+        c = int(self.class_of[job])
+        n = sum(self.held(role, c, k_now)
+                for role in ("current", *self.cls(job)["windows"]))
         return n * len(self.metrics_of(job))
 
     def window_span(self) -> tuple[str, str]:
@@ -220,6 +271,66 @@ class Fleet:
                 "%Y-%m-%dT%H:%M:%SZ")
 
         return rfc(self.t0), rfc(self.t0 + (self.horizon + 1440) * self.step)
+
+
+def _placement(cls: dict, warm_points: int) -> dict:
+    """The roles a class lays itself: {past role: (start, points),
+    "current": greatest length}. A malformed placement, or one of a role
+    the class does not carry, ends the run here, before an engine is
+    built."""
+    stated = cls.get("placement", {})
+    name = cls.get("name")
+    if not isinstance(stated, dict):
+        raise BenchError(f"class {name!r}: placement is an object by role")
+    carried = ("current", *cls["windows"])
+    out = {}
+    for role, where in stated.items():
+        if role not in carried:
+            raise BenchError(
+                f"class {name!r}: placement names {role!r}, and its jobs "
+                f"carry {list(carried)}")
+        keys = ("points",) if role == "current" else ("start", "points")
+        if not isinstance(where, dict) or sorted(where) != sorted(keys) \
+                or not all(type(where[k]) is int for k in keys) \
+                or where["points"] < 1:
+            raise BenchError(
+                f"class {name!r}: placement of {role!r} is {where!r}; it "
+                f"is {{{', '.join(map(repr, keys))}}}, whole numbers, at "
+                f"least one point")
+        if role == "current":
+            if where["points"] < warm_points:
+                raise BenchError(
+                    f"class {name!r}: a current window of at most "
+                    f"{where['points']} points cannot hold the "
+                    f"{warm_points} of its warm-up (current_points)")
+            out[role] = where["points"]
+        else:
+            out[role] = (where["start"], where["points"])
+    return out
+
+
+def _slots(cls: dict, where: dict, cur_lo: int, warm_slot: int,
+           horizon: int) -> dict:
+    """{role: (URL tag, first slot, last slot)} of one class: a past role
+    at `where[role]` = (start, points) from the current window's first
+    slot, the current window to its greatest length or the horizon."""
+    past = {}
+    for role in _PAST:
+        start, points = where[role]
+        lo, hi = cur_lo + start, cur_lo + start + points - 1
+        if hi > warm_slot:
+            raise BenchError(
+                f"class {cls.get('name')!r}: its {role} window, slots "
+                f"[{lo}, {hi}], does not fit the horizon: it has to be "
+                f"whole by the warm-up clock, slot {warm_slot}")
+        past[role] = (lo, hi)
+    # a range is queried by the history's tag, whichever role asks for it
+    out = {role: (_TAGS["historical" if span == past["historical"]
+                        else role], *span) for role, span in past.items()}
+    last = where.get("current")
+    out["current"] = (_TAGS["current"], cur_lo,
+                      horizon - 1 if last is None else cur_lo + last - 1)
+    return out
 
 
 def family_path(stem: str) -> str:

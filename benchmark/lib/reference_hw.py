@@ -280,8 +280,8 @@ def _set_worker_fleet(fleet):
 
 def _chunk_rows(jobs, slot, k_now, slack, precision, fleet=None):
     fleet = fleet if fleet is not None else _worker_fleet
-    hist = fleet.served_rows(jobs, slot, fleet.hist_lo, fleet.hist_hi)
-    cur = fleet.served_rows(jobs, slot, fleet.hist_hi, k_now)
+    hist = fleet.role_rows(jobs, slot, "historical", k_now)
+    cur = fleet.role_rows(jobs, slot, "current", k_now)
     return band_rows(quantize(hist, precision), quantize(cur, precision),
                      reference.POLICIES[fleet.metrics_of(jobs[0])[slot]],
                      settings(fleet.config["engine"]), slack)
@@ -290,10 +290,11 @@ def _chunk_rows(jobs, slot, k_now, slack, precision, fleet=None):
 def fleet_rows(fleet, jobs: list, slot: int, k_now: int, slack: float,
                precision: str) -> dict:
     """{job: its reference row (`band_rows`)} from the fleet's own served
-    series: history slots [hist_lo, hist_hi], judged slots [hist_hi,
-    k_now]. Blocks of jobs are independent, so a large class is computed
-    over a process pool (spawned: the workers import numpy and this
-    package, never the program or the chip); the arithmetic is the same."""
+    series: the `historical` window's slots, and the `current` window's
+    up to `k_now` (`Fleet.window_slots`). Blocks of jobs are independent,
+    so a large class is computed over a process pool (spawned: the
+    workers import numpy and this package, never the program or the
+    chip); the arithmetic is the same."""
     chunks = [jobs[i:i + _CHUNK] for i in range(0, len(jobs), _CHUNK)]
     args = (slot, k_now, slack, precision)
     if len(jobs) < _POOL_FROM:

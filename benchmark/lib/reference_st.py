@@ -182,10 +182,11 @@ def band_rows(hist: np.ndarray, cur: np.ndarray, policy: tuple, hw_cfg: dict,
 def fleet_rows(fleet, jobs: list, slot: int, k_now: int, slack: float,
                precision: str) -> list:
     """The reference rows (`band_rows`) of `jobs` from the fleet's own
-    served series: history slots [hist_lo, hist_hi], judged slots
-    [hist_hi, k_now]. "bfloat16" rounds the samples first (the control)."""
-    hist = fleet.served_rows(jobs, slot, fleet.hist_lo, fleet.hist_hi)
-    cur = fleet.served_rows(jobs, slot, fleet.hist_hi, k_now)
+    served series: the `historical` window's slots, and the `current`
+    window's up to `k_now` (`Fleet.window_slots`). "bfloat16" rounds the
+    samples first (the control)."""
+    hist = fleet.role_rows(jobs, slot, "historical", k_now)
+    cur = fleet.role_rows(jobs, slot, "current", k_now)
     engine = fleet.config["engine"]
     return band_rows(quantize(hist, precision), quantize(cur, precision),
                      reference.POLICIES[fleet.metrics_of(jobs[0])[slot]],

@@ -1,13 +1,16 @@
 """The metric source the engine polls: the fleet's simulated store.
 
 It has the three calls of the program's `RawFixtureDataSource` (`fetch`,
-`fetch_series`, `fetch_window`). A history query (`w=hist`) is answered
-from the fleet's arrays: the same numbers the byte body would carry, at
-the same four decimals, without rendering and parsing 226 KB of text per
-job in every run's set-up. Every other query (`w=cur`, `w=base`: the
-per-cycle tails) is rendered as a Prometheus body and parsed by the
-program's own parser, so the parse stays in the timed path.
-`benchmark/tests/test_benchmark.py` pins the two paths equal.
+`fetch_series`, `fetch_window`). A query of a class's historical range
+(`w=hist`: `Fleet.window_slots` gives that tag to whichever role lies on
+the range, so a baseline laid on the history is one) is answered from the
+fleet's arrays: the same numbers the byte body would carry, at the same
+four decimals, without rendering and parsing 226 KB of text per job in
+every run's set-up. Every other range (`w=cur`, `w=base`: the current
+window's tails and a baseline that lies elsewhere) is rendered as a
+Prometheus body and parsed by the program's own parser, so the parse
+stays in the timed path. `benchmark/tests/test_benchmark.py` pins the two
+paths equal.
 """
 from __future__ import annotations
 

@@ -18,12 +18,10 @@ def read(ctx):
     device_s = sum(tr["programs"].get(p, (0.0, 0))[0] for p in PROGRAMS)
     if device_s <= 0:
         return None
-    least = 0.0
-    for c in ctx["cycles"]:
-        points = fl.hist_steps + 1 + c["now_slot"] - fl.hist_hi + 1
-        secs, bound = costs.least_seconds(
-            costs.band(c["rows"].get("band", 0), points), ctx["peaks"])
-        least += secs
+    least, bound = costs.least_over_cycles(
+        ctx, "band", lambda rows, c, k_now: costs.band(
+            rows, fl.held("historical", c, k_now)
+            + fl.held("current", c, k_now)))
     ctx["notes"]["band_roofline_bound"] = bound
     ctx["notes"]["band_device_s"] = device_s
     return 100.0 * least / device_s

@@ -14,13 +14,13 @@ def read(ctx):
     device_s = sum(tr["programs"].get(p, (0.0, 0))[0] for p in PROGRAMS)
     if device_s <= 0:
         return None
-    least = 0.0
-    for c in ctx["cycles"]:
-        judged = c["now_slot"] - fl.hist_hi + 1
-        secs, bound = costs.least_seconds(costs.bivariate(
-            c["rows"].get("bivariate", 0), fl.hist_steps + 1 + judged,
-            judged), ctx["peaks"])
-        least += secs
+
+    def cost(rows, c, k_now):
+        judged = fl.held("current", c, k_now)
+        return costs.bivariate(
+            rows, fl.held("historical", c, k_now) + judged, judged)
+
+    least, bound = costs.least_over_cycles(ctx, "bivariate", cost)
     ctx["notes"]["bivariate_roofline_bound"] = bound
     ctx["notes"]["bivariate_device_s"] = device_s
     return 100.0 * least / device_s

@@ -16,14 +16,13 @@ def read(ctx):
         return None
     lags = 2 * sum(1 for p in fl.config["engine"].get(
         "hw_period_candidates", (60, 480, 720, 1440)) if p >= 4)
-    least = 0.0
-    for c in ctx["cycles"]:
-        history = fl.hist_steps + 1
-        points = history + c["now_slot"] - fl.hist_hi + 1
-        secs, bound = costs.least_seconds(
-            costs_hw.band_hw(c["rows"].get("band", 0), points, history,
-                             lags=lags), ctx["peaks"])
-        least += secs
+
+    def cost(rows, c, k_now):
+        history = fl.held("historical", c, k_now)
+        return costs_hw.band_hw(rows, history + fl.held("current", c, k_now),
+                                history, lags=lags)
+
+    least, bound = costs.least_over_cycles(ctx, "band", cost)
     ctx["notes"]["hw_roofline_bound"] = bound
     ctx["notes"]["hw_device_s"] = device_s
     return 100.0 * least / device_s

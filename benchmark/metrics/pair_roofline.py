@@ -13,12 +13,10 @@ def read(ctx):
     device_s = sum(tr["programs"].get(p, (0.0, 0))[0] for p in PROGRAMS)
     if device_s <= 0:
         return None
-    least = 0.0
-    for c in ctx["cycles"]:
-        secs, bound = costs.least_seconds(
-            costs.pair(c["rows"].get("pair", 0), fl.window_steps + 1,
-                       c["now_slot"] - fl.hist_hi + 1), ctx["peaks"])
-        least += secs
+    least, bound = costs.least_over_cycles(
+        ctx, "pair", lambda rows, c, k_now: costs.pair(
+            rows, fl.held("baseline", c, k_now),
+            fl.held("current", c, k_now)))
     ctx["notes"]["pair_roofline_bound"] = bound
     ctx["notes"]["pair_device_s"] = device_s
     return 100.0 * least / device_s

@@ -19,15 +19,14 @@ def read(ctx):
     lags = 2 * sum(1 for p in eng.get(
         "hw_period_candidates", (60, 480, 720, 1440)) if p >= 4)
     columns, solves = costs_st.fit_shape(eng)
-    least = 0.0
-    for c in ctx["cycles"]:
-        history = fl.hist_steps + 1
-        points = history + c["now_slot"] - fl.hist_hi + 1
-        secs, bound = costs.least_seconds(
-            costs_st.band_st(c["rows"].get("band", 0), points, history,
-                             columns=columns, solves=solves, lags=lags),
-            ctx["peaks"])
-        least += secs
+
+    def cost(rows, c, k_now):
+        history = fl.held("historical", c, k_now)
+        return costs_st.band_st(
+            rows, history + fl.held("current", c, k_now), history,
+            columns=columns, solves=solves, lags=lags)
+
+    least, bound = costs.least_over_cycles(ctx, "band", cost)
     ctx["notes"]["st_roofline_bound"] = bound
     ctx["notes"]["st_device_s"] = device_s
     return 100.0 * least / device_s

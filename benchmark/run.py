@@ -156,10 +156,14 @@ class Engine:
         unjudged = max(
             offered - (an.provenance.records_total - records0), 0)
         failed = bad + unjudged + sum(int(st[k]) for k in CYCLE_COUNTERS)
-        rows = {}  # rows each scoring family was given
-        for jid in outcomes:
-            cls = fl.class_of[fl.job_index(jid)]
-            for fam, n in self.rows_of[cls].items():
+        # rows each scoring family was given, by class and in all
+        jobs_of = collections.Counter(
+            int(fl.class_of[fl.job_index(jid)]) for jid in outcomes)
+        class_rows = {c: {fam: n * k for fam, k in self.rows_of[c].items()}
+                      for c, n in jobs_of.items()}
+        rows = {}
+        for of_class in class_rows.values():
+            for fam, n in of_class.items():
                 rows[fam] = rows.get(fam, 0) + n
         k_now = fl.now_slot()
         return {
@@ -172,7 +176,7 @@ class Engine:
             "launches": an.device_launches - launches0,
             "fetches": self.inner.request_count - fetches0,
             "compiles": self.compiles.compiles - compiles0,
-            "rows": rows, "outcomes": outcomes,
+            "rows": rows, "class_rows": class_rows, "outcomes": outcomes,
         }
 
     def warm_up(self) -> list:

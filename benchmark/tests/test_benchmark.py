@@ -94,7 +94,8 @@ def test_array_path_equals_byte_path_for_one_history():
     ts_c, v_c, _ = arrays.fetch_series(cur)
     assert ts_c.shape == (80,) and ts_c[-1] <= fl.now
     np.testing.assert_allclose(
-        v_c, fl.served(job, 0, fl.hist_hi, fl.now_slot()), atol=1e-12)
+        v_c, fl.role_rows([job], 0, "current", fl.now_slot())[0],
+        atol=1e-12)
 
 
 # -------------------------------------------------------------------- costs
@@ -127,10 +128,11 @@ def test_band_roofline_sums_the_launchs_four_programs():
     ctx = {"trace": {"programs": {p: [s, 2] for p, s in seconds.items()}
                      | {"jit_all_pairwise_tests": [5e-6, 2]}},
            "fleet": fl, "peaks": pk, "notes": {},
-           "cycles": [{"rows": {"band": 40}, "now_slot": fl.now_slot()}]}
+           "cycles": [{"class_rows": {0: {"band": 40}},
+                       "now_slot": fl.now_slot()}]}
     share = harness.load_reader("band_roofline")(ctx)
     assert ctx["notes"]["band_device_s"] == pytest.approx(10e-6)
-    points = fl.hist_steps + 1 + fl.now_slot() - fl.hist_hi + 1
+    points = 600 + 80  # the tiny history and the warm-up current window
     least, _ = costs.least_seconds(costs.band(40, points), pk)
     assert share == pytest.approx(100.0 * least / 10e-6)
     # a launch none of whose programs is in the trace has no share

@@ -55,7 +55,8 @@ def test_the_configuration_is_rollout7d_under_holt_winters():
     assert hw["assumed"][:len(base["assumed"])] == base["assumed"]
     fl = fleet_mod.Fleet(hw, 1, tiny=True)
     # the tiny history holds its period five times over
-    assert fl.lead == 120 and fl.hist_steps + 1 >= 2 * fl.lead
+    assert fl.lead == 120
+    assert fl.held("historical", 0, fl.now_slot()) == 600 >= 2 * fl.lead
     assert fl.config["engine"]["hw_period_candidates"] == [5, 120]
     assert check.family(fl, "band").__name__ == "bench_family_band_hw"
 
@@ -144,8 +145,10 @@ def _ctx(programs):
     fl = fleet_mod.Fleet(_config(), 1, tiny=True)
     return {"trace": {"programs": programs}, "fleet": fl, "notes": {},
             "peaks": peaks.for_kind("TPU v5 lite"),
-            "cycles": [{"rows": {"band": 40}, "now_slot": fl.now_slot()},
-                       {"rows": {"band": 40}, "now_slot": fl.now_slot() + 1}]}
+            "cycles": [{"class_rows": {0: {"band": 40}},
+                        "now_slot": fl.now_slot()},
+                       {"class_rows": {0: {"band": 40}},
+                        "now_slot": fl.now_slot() + 1}]}
 
 
 def test_hw_readers_on_a_built_trace():
@@ -157,11 +160,10 @@ def test_hw_readers_on_a_built_trace():
     ctx = _ctx(programs)
     share = harness.load_reader("hw_roofline")(ctx)
     assert ctx["notes"]["hw_device_s"] == pytest.approx(36e-6)
-    fl = ctx["fleet"]
     least = 0.0
-    for c in ctx["cycles"]:
-        history = fl.hist_steps + 1
-        points = history + c["now_slot"] - fl.hist_hi + 1
+    for i, _ in enumerate(ctx["cycles"]):
+        # the tiny history, and the current window at warm-up and after
+        history, points = 600, 600 + 80 + i
         least += costs.least_seconds(
             costs_hw.band_hw(40, points, history, lags=2), ctx["peaks"])[0]
     assert share == pytest.approx(100.0 * least / 36e-6)

@@ -153,8 +153,10 @@ def _ctx(programs):
     fl = fleet_mod.Fleet(_config(), 1, tiny=True)
     return {"trace": {"programs": programs}, "fleet": fl, "notes": {},
             "peaks": peaks.for_kind("TPU v5 lite"),
-            "cycles": [{"rows": {"band": 40}, "now_slot": fl.now_slot()},
-                       {"rows": {"band": 40}, "now_slot": fl.now_slot() + 1}]}
+            "cycles": [{"class_rows": {0: {"band": 40}},
+                        "now_slot": fl.now_slot()},
+                       {"class_rows": {0: {"band": 40}},
+                        "now_slot": fl.now_slot() + 1}]}
 
 
 def test_st_readers_on_a_built_trace():
@@ -166,11 +168,10 @@ def test_st_readers_on_a_built_trace():
     share = harness.load_reader("st_roofline")(ctx)
     assert ctx["notes"]["st_device_s"] == pytest.approx(28e-6)
     assert ctx["notes"]["st_roofline_bound"] in ("bandwidth", "compute")
-    fl = ctx["fleet"]
     least = 0.0
-    for c in ctx["cycles"]:
-        history = fl.hist_steps + 1
-        points = history + c["now_slot"] - fl.hist_hi + 1
+    for i, _ in enumerate(ctx["cycles"]):
+        # the tiny history, and the current window at warm-up and after
+        history, points = 600, 600 + 80 + i
         least += costs.least_seconds(
             costs_st.band_st(40, points, history, lags=2), ctx["peaks"])[0]
     assert share == pytest.approx(100.0 * least / 28e-6)
