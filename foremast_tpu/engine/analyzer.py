@@ -397,6 +397,13 @@ class Analyzer:
         self.d2h_bytes_total = 0
         self.pack_real_elems_total = 0
         self.pack_total_elems_total = 0
+        # -- the pair program's sort (cumulative): elements each launched
+        # row sorts (both windows at the bucket, 2T) against the valid
+        # samples of the real rows' windows; and the widest bucket a pair
+        # launch of the cycle used (per cycle)
+        self.pair_sort_elems_total = 0
+        self.pair_real_elems_total = 0
+        self._cycle_pair_T_max = 0
         # -- seasonal band launches: partitions by detected period
         # (cumulative), and the largest seasonal state a Holt-Winters fit
         # of the cycle held on the device, from its shapes (per cycle);
@@ -1203,6 +1210,11 @@ class Analyzer:
                 [it.baseline.values.shape[0] + it.current.values.shape[0]
                  for it in group])
             sp.attrs.update(bytes=_nbytes(arrays), edge_rows=R - B)
+        self.pair_sort_elems_total += R * 2 * T
+        self.pair_real_elems_total += sum(
+            int(np.count_nonzero(it.baseline.mask))
+            + int(np.count_nonzero(it.current.mask)) for it in group)
+        self._cycle_pair_T_max = max(self._cycle_pair_T_max, T)
         launches = self._launch_chunks(fl.score_pairs, arrays, donate=4,
                                        row_elems=row_elems, rows=B)
         return (group, launches)
@@ -2530,6 +2542,9 @@ class Analyzer:
         rescore_skips0 = self.lstm_rescore_skips
         h2d0, d2h0 = self.h2d_bytes_total, self.d2h_bytes_total
         real0, total0 = self.pack_real_elems_total, self.pack_total_elems_total
+        sort0 = self.pair_sort_elems_total
+        pair_real0 = self.pair_real_elems_total
+        self._cycle_pair_T_max = 0
         parts0 = self.period_partitions_total
         self._cycle_hw_state_bytes = 0
         self._cycle_st_columns = self._cycle_st_solves = 0
@@ -2662,6 +2677,9 @@ class Analyzer:
                 "d2h_bytes": self.d2h_bytes_total - d2h0,
                 "pack_real_elems": self.pack_real_elems_total - real0,
                 "pack_total_elems": self.pack_total_elems_total - total0,
+                "pair_sort_elems": self.pair_sort_elems_total - sort0,
+                "pair_real_elems": self.pair_real_elems_total - pair_real0,
+                "pair_T_max": self._cycle_pair_T_max,
                 "period_partitions": self.period_partitions_total - parts0,
                 "hw_candidates": (fc.HW_CANDIDATES
                                   if self._cycle_hw_state_bytes else 0),
@@ -2904,6 +2922,14 @@ class Analyzer:
                 help="Share of the windows the last cycle's score memo "
                      "fingerprinted whose digest the Window already held "
                      "(0: none fingerprinted).")
+            self.exporter.record_gauge(
+                "foremastbrain:pair_sort_fill_share", {},
+                round(counters["pair_real_elems"]
+                      / counters["pair_sort_elems"], 6)
+                if counters["pair_sort_elems"] else 0.0,
+                help="Share of the elements the last cycle's pair launches "
+                     "sorted that were valid samples of real rows (0: no "
+                     "pair launch).")
             self.exporter.record_gauge(
                 "foremastbrain:period_partitions", {},
                 counters["period_partitions"],

@@ -17,9 +17,11 @@ EXACT finite-n nulls (batchable scan forms — the lattice-path DP and the
 binomial tail) in the sample-count regimes the engine scores, matching
 scipy's exact modes. The parity tests assert both.
 
-All statistics are computed in float32; windows in this domain are short
-(10-min..30-min at 60 s step), far inside float32's exact-integer range for
-rank sums.
+All statistics are computed in float32. A rank sum is taken of the side
+with fewer valid samples (`_short_side`): a current window of 10 to 30
+minutes at 60 s has a rank sum far inside float32's exact range even
+against a 7-day baseline, whose own rank sum (about 5e7 at 10,081 + 31
+samples, where a float32 step is 4) is not.
 """
 from __future__ import annotations
 
@@ -152,6 +154,24 @@ def _ks_pvalue(t, n1, n2, Ti: int, Tj: int):
     return jnp.where((n1 > 0) & (n2 > 0), p, 1.0)
 
 
+def _short_side(from_x, n1, n2):
+    """(x is the short side, membership weight of the short side): the
+    side with fewer valid samples, x where the counts are equal."""
+    x_short = n1 <= n2
+    return x_short, jnp.where(x_short, from_x, 1.0 - from_x)
+
+
+def _u_of_x(r_short, n1, n2, x_short):
+    """U1, the U statistic of x (scipy's convention), from the rank sum of
+    the short side: U_short = R_short - n_s (n_s + 1) / 2, and x's U is
+    n1 n2 - U_short where x is the long side. R_short is at most n_s (N + 1),
+    a few 1e5 for a 31-point window against 10,081, so it and U_short are
+    exact in float32 (ranks are half-integers); R1 of the long side is not."""
+    n_s = jnp.where(x_short, n1, n2)
+    u_short = r_short - n_s * (n_s + 1.0) / 2.0
+    return jnp.where(x_short, u_short, n1 * n2 - u_short)
+
+
 # ---------------------------------------------------------------------------
 # Mann-Whitney U  (scipy.stats.mannwhitneyu, method="asymptotic",
 #                  use_continuity=True, alternative="two-sided")
@@ -163,8 +183,9 @@ def mann_whitney_u(x, x_mask, y, y_mask):
     convention); pvalue uses the tie-corrected normal approximation with
     continuity correction.
 
-    The rank sum R1 comes from rank_sum_stats with an x-membership weight —
-    ranks are never materialized in input order (see ranks.py perf note).
+    The rank sum of the short side comes from rank_sum_stats with that
+    side's membership weight — ranks are never materialized in input order
+    (see ranks.py perf note).
     """
     Tx = x.shape[-1]
     comb = jnp.concatenate([x, y]).astype(_F)
@@ -172,12 +193,13 @@ def mann_whitney_u(x, x_mask, y, y_mask):
     from_x = jnp.concatenate(
         [jnp.ones((Tx,), _F), jnp.zeros((y.shape[-1],), _F)]
     )
-    R1, tie, _ = rank_sum_stats(comb, cmask, from_x)
-
     n1 = jnp.sum(x_mask.astype(_F))
     n2 = jnp.sum(y_mask.astype(_F))
+    x_short, from_short = _short_side(from_x, n1, n2)
+    R_short, tie, _ = rank_sum_stats(comb, cmask, from_short)
+
     N = n1 + n2
-    U1 = R1 - n1 * (n1 + 1.0) / 2.0
+    U1 = _u_of_x(R_short, n1, n2, x_short)
     U2 = n1 * n2 - U1
     U = jnp.maximum(U1, U2)
 
@@ -447,11 +469,12 @@ def ks_2samp(x, x_mask, y, y_mask):
 def two_sample_tests(x, x_mask, y, y_mask):
     """Mann-Whitney + 2-group Kruskal + Wilcoxon + KS on one window pair.
 
-    The combined sample is sorted ONCE, carrying x-membership and validity
-    payloads (the rank_sum_stats design, ranks.py). From that single sorted
-    view come:
-      * the Mann-Whitney / Kruskal-Wallis rank sums (tie-averaged ranks via
-        cummax/cummin group bounds);
+    The combined sample is sorted ONCE, carrying the short side's
+    membership and validity payloads (the rank_sum_stats design, ranks.py).
+    From that single sorted view come:
+      * the short side's rank sum (tie-averaged ranks via cummax/cummin
+        group bounds), hence Mann-Whitney's U and the two-group
+        Kruskal-Wallis H, which is a function of U;
       * the KS sup-distance: at each sorted valid point, #x <= value is the
         cumulative x-count at the END of its tie group (the `<=` semantics
         of the O(T^2) formulation, same cummin smear as the tie bounds) —
@@ -465,20 +488,18 @@ def two_sample_tests(x, x_mask, y, y_mask):
     from_x = jnp.concatenate(
         [jnp.ones((Tx,), _F), jnp.zeros((y.shape[-1],), _F)]
     )
+    n1 = jnp.sum(x_mask.astype(_F))
+    n2 = jnp.sum(y_mask.astype(_F))
+    x_short, from_short = _short_side(from_x, n1, n2)
 
-    w = from_x * cmask.astype(_F)  # valid member of x
+    w = from_short * cmask.astype(_F)  # valid member of the short side
     view = _sorted_rank_view(comb, cmask, extras=(w,))
     (sw,) = view.extras
-    R1 = jnp.sum(view.avg * sw)
     tie = _tie_term(view)
     N = view.n_valid
 
-    n1 = jnp.sum(x_mask.astype(_F))
-    n2 = jnp.sum(y_mask.astype(_F))
-    R2 = N * (N + 1.0) / 2.0 - R1
-
     # Mann-Whitney from shared ranks
-    U1 = R1 - n1 * (n1 + 1.0) / 2.0
+    U1 = _u_of_x(jnp.sum(view.avg * sw), n1, n2, x_short)
     U = jnp.maximum(U1, n1 * n2 - U1)
     mu = n1 * n2 / 2.0
     s2 = n1 * n2 / 12.0 * ((N + 1.0) - _safe_div(tie, N * (N - 1.0)))
@@ -486,10 +507,11 @@ def two_sample_tests(x, x_mask, y, y_mask):
     z = _safe_div(U - mu - 0.5, s)
     p_mw = jnp.where(s > 0.0, jnp.clip(2.0 * norm_sf(z), 0.0, 1.0), 1.0)
 
-    # Kruskal-Wallis (k=2) from the same rank sums
-    H = _safe_div(12.0, N * (N + 1.0)) * (
-        _safe_div(R1**2, n1) + _safe_div(R2**2, n2)
-    ) - 3.0 * (N + 1.0)
+    # Kruskal-Wallis (k=2) from U: 12/(N(N+1)) (R1^2/n1 + R2^2/n2) - 3(N+1)
+    # is 12 (U1 - n1 n2/2)^2 / (n1 n2 (N+1)), which subtracts no two
+    # numbers of 1e11 at N = 10,112
+    d = U1 - mu
+    H = _safe_div(12.0 * d * d, n1 * n2 * (N + 1.0))
     correction = 1.0 - _safe_div(tie, N**3 - N)
     H = _safe_div(H, correction)
     ok = (correction > 0.0) & (N > 0.0)
@@ -501,7 +523,8 @@ def two_sample_tests(x, x_mask, y, y_mask):
     # (Tie groups split on validity, but the sentinel group contributes no
     # valid counts, so group-end cumulatives are unaffected by the split.)
     # The sup is the exact integer statistic t = max|cx*n2 - cy*n1|.
-    cx_inc = jnp.cumsum(sw)
+    sw_x = jnp.where(x_short, sw, view.sv - sw)  # valid member of x
+    cx_inc = jnp.cumsum(sw_x)
     cx_end = _cummin_rev(jnp.where(view.group_end, cx_inc, jnp.inf))
     cy_end = view.g1 - cx_end  # valid y count = valid count - valid x count
     t_ks = jnp.max(

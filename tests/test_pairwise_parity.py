@@ -292,3 +292,162 @@ def test_sign_test_exact_all_tied_is_p1():
     m = np.ones(30, bool)
     n, p = sign_test_exact(x, x, m)
     assert int(n) == 0 and float(p) == 1.0
+
+
+# ------------------------------------------- a short window against 7 days
+# foremast-trigger's rollover judges a 20-to-31-point current window
+# against the trailing 7 days (10,081 points at 60 s), in the T=16384
+# bucket. The rank sum of the 10,081-point side is about 5e7, where a
+# float32 step is 4: U is taken from the short side's rank sum instead.
+LONG_T = 16384
+LONG_SHAPES = [(10081, 31), (31, 10081), (10081, 20)]
+LONG_P_GAP = 1e-6
+
+
+def _long_rows(seed, n_x, n_y, rows=8):
+    """`rows` pairs in the T=16384 bucket, samples at four decimals (as
+    a metric store serves them), the y side shifted a little per row."""
+    rng = np.random.default_rng(seed)
+    x = np.zeros((rows, LONG_T), np.float32)
+    y = np.zeros((rows, LONG_T), np.float32)
+    xm = np.zeros((rows, LONG_T), bool)
+    ym = np.zeros((rows, LONG_T), bool)
+    for r in range(rows):
+        x[r, :n_x] = np.round(10.0 + rng.normal(size=n_x), 4)
+        y[r, :n_y] = np.round(10.0 + rng.normal(size=n_y)
+                              + 0.3 * rng.random(), 4)
+        xm[r, :n_x] = ym[r, :n_y] = True
+    return x, xm, y, ym
+
+
+def _scipy_rows(x, xm, y, ym, test):
+    return np.asarray([test(a[am].astype(np.float64),
+                            b[bm].astype(np.float64))
+                       for a, am, b, bm in zip(x, xm, y, ym)])
+
+
+def _mw_ref(a, b):
+    r = sps.mannwhitneyu(a, b, alternative="two-sided",
+                         method="asymptotic", use_continuity=True)
+    return r.statistic, r.pvalue
+
+
+def _kw_ref(a, b):
+    r = sps.kruskal(a, b)
+    return r.statistic, r.pvalue
+
+
+@pytest.mark.parametrize("seed", [1, 2])
+@pytest.mark.parametrize("n_x,n_y", LONG_SHAPES)
+@pytest.mark.parametrize("route", ["fused", "standalone"])
+def test_mann_whitney_long_bucket(route, n_x, n_y, seed):
+    import jax
+
+    from foremast_tpu.ops import two_sample_tests
+    from foremast_tpu.ops.pairwise import mann_whitney_u_batch
+
+    x, xm, y, ym = _long_rows(seed, n_x, n_y)
+    if route == "fused":
+        U, p = jax.jit(jax.vmap(two_sample_tests))(
+            x, xm, y, ym)["mann_whitney"]
+    else:
+        U, p = mann_whitney_u_batch(x, xm, y, ym)
+    ref = _scipy_rows(x, xm, y, ym, _mw_ref)
+    # U is a whole or half number of at most n_x n_y: exact
+    np.testing.assert_array_equal(np.asarray(U, np.float64), ref[:, 0])
+    assert np.max(np.abs(np.asarray(p, np.float64) - ref[:, 1])) \
+        <= LONG_P_GAP
+
+
+@pytest.mark.parametrize("seed", [1, 2])
+@pytest.mark.parametrize("n_x,n_y", LONG_SHAPES)
+def test_kruskal_two_groups_long_bucket(n_x, n_y, seed):
+    import jax
+
+    from foremast_tpu.ops import two_sample_tests
+
+    x, xm, y, ym = _long_rows(seed, n_x, n_y)
+    H, p = jax.jit(jax.vmap(two_sample_tests))(x, xm, y, ym)["kruskal"]
+    ref = _scipy_rows(x, xm, y, ym, _kw_ref)
+    np.testing.assert_allclose(np.asarray(H, np.float64), ref[:, 0],
+                               rtol=1e-5, atol=1e-5)
+    assert np.max(np.abs(np.asarray(p, np.float64) - ref[:, 1])) \
+        <= LONG_P_GAP
+
+
+@pytest.mark.parametrize("n_x,n_y", LONG_SHAPES)
+def test_score_pairs_long_bucket_min_p(n_x, n_y):
+    """The fleet program under `mann_whitney_all`: the baseline is x, its
+    smallest enabled p-value is Mann-Whitney's."""
+    from foremast_tpu.parallel import fleet as fl
+
+    x, xm, y, ym = _long_rows(3, n_x, n_y)
+    args = list(fl.pair_arg_spec(8, LONG_T))
+    args[:4] = x, xm, y, ym
+    args[4] = np.full(8, 0.01, np.float32)
+    args[5] = np.full(8, fl.TEST_MANN_WHITNEY, np.int32)
+    args[6] = np.full(8, fl.COMBINE_ALL, np.int32)
+    args[8] = np.full(8, 3.0, np.float32)
+    out = fl.score_pairs(*args)
+    ref = _scipy_rows(x, xm, y, ym, _mw_ref)
+    assert np.max(np.abs(np.asarray(out["min_p"], np.float64)
+                         - ref[:, 1])) <= LONG_P_GAP
+
+
+def _mw_long_side(x, x_mask, y, y_mask):
+    """Mann-Whitney with U taken from x's own rank sum, whichever side is
+    shorter: the route the kernels took before the short side's."""
+    import jax.numpy as jnp
+
+    from foremast_tpu.ops.ranks import rank_sum_stats
+    from foremast_tpu.ops.stats import norm_sf
+
+    f32 = jnp.float32
+    comb = jnp.concatenate([x, y]).astype(f32)
+    cmask = jnp.concatenate([x_mask, y_mask])
+    from_x = jnp.concatenate([jnp.ones(x.shape, f32), jnp.zeros(y.shape, f32)])
+    R1, tie, _ = rank_sum_stats(comb, cmask, from_x)
+    n1 = jnp.sum(x_mask.astype(f32))
+    n2 = jnp.sum(y_mask.astype(f32))
+    N = n1 + n2
+    U1 = R1 - n1 * (n1 + 1.0) / 2.0
+    U = jnp.maximum(U1, n1 * n2 - U1)
+    s2 = n1 * n2 / 12.0 * ((N + 1.0) - tie / jnp.where(
+        N * (N - 1.0) == 0, 1.0, N * (N - 1.0)))
+    s = jnp.sqrt(jnp.maximum(s2, 0.0))
+    z = (U - n1 * n2 / 2.0 - 0.5) / jnp.where(s == 0, 1.0, s)
+    p = jnp.where(s > 0.0, jnp.clip(2.0 * norm_sf(z), 0.0, 1.0), 1.0)
+    return U1, p
+
+
+@pytest.mark.parametrize("route", ["fused", "standalone"])
+@pytest.mark.parametrize("ties", [False, True])
+def test_short_side_route_is_todays_at_t128(route, ties):
+    """At T=128, as in the rolling-update cells, every rank sum is exact
+    in float32 whichever side it is taken of: the short side's route
+    gives today's U1 and p bit for bit, with x the short side and with y."""
+    import jax
+
+    from foremast_tpu.ops import two_sample_tests
+    from foremast_tpu.ops.pairwise import mann_whitney_u_batch
+
+    rng = np.random.default_rng(128)
+    B, T = 16, 128
+    x = np.round(rng.normal(10.0, 1.0, (B, T)), 1 if ties else 4)
+    y = np.round(rng.normal(10.3, 1.0, (B, T)), 1 if ties else 4)
+    n_x = rng.integers(20, T + 1, B)
+    n_y = rng.integers(20, T + 1, B)
+    n_x[:4] = 80  # the rolling-update cells' baseline against 80..128
+    n_y[:4] = [80, 81, 100, 128]
+    cols = np.arange(T)
+    xm, ym = cols < n_x[:, None], cols < n_y[:, None]
+    x, y = x.astype(np.float32), y.astype(np.float32)
+    assert (n_x < n_y).any() and (n_x > n_y).any()
+    if route == "fused":
+        U, p = jax.jit(jax.vmap(two_sample_tests))(
+            x, xm, y, ym)["mann_whitney"]
+    else:
+        U, p = mann_whitney_u_batch(x, xm, y, ym)
+    U0, p0 = jax.jit(jax.vmap(_mw_long_side))(x, xm, y, ym)
+    np.testing.assert_array_equal(np.asarray(U), np.asarray(U0))
+    np.testing.assert_array_equal(np.asarray(p), np.asarray(p0))

@@ -925,6 +925,65 @@ def test_transfer_and_pack_counters_equal_hand_counts(family):
     assert eng.pack_total_elems_total == total
 
 
+@pytest.mark.parametrize("family", ["pair", "band"])
+def test_pair_sort_counters_equal_hand_counts(family):
+    """The pair program sorts both windows of every launched row at the
+    bucket, 2T a row; the counters hold that against the valid samples
+    of the real rows, and the widest bucket. A band launch sorts none."""
+    from foremast_tpu.engine.analyzer import _BandItem, _PairItem
+
+    rng = np.random.default_rng(7)
+    eng = Analyzer(EngineConfig(), None, JobStore())
+    policy = eng.config.policy_for("latency")
+    T, lens = 32, [(30, 20), (25, 28), (31, 22)]
+    cls = _PairItem if family == "pair" else _BandItem
+    items = [cls(f"j{i}", "latency", *_two_windows(rng, b, c), policy)
+             for i, (b, c) in enumerate(lens)]
+    if family == "pair":
+        # a masked slot on each side of the first row is not a sample
+        items[0].baseline.mask[5] = False
+        items[0].current.mask[3] = False
+    families.family(family).score(eng, items)
+    if family == "pair":
+        # three rows pad to the 16-row rung
+        assert eng.pair_sort_elems_total == 16 * 2 * T
+        assert eng.pair_real_elems_total == sum(b + c for b, c in lens) - 2
+        assert eng._cycle_pair_T_max == T
+    else:
+        assert eng.pair_sort_elems_total == eng.pair_real_elems_total == 0
+        assert eng._cycle_pair_T_max == 0
+
+
+def test_pair_sort_counters_ride_the_score_span_and_a_gauge():
+    exporter = VerdictExporter()
+    store, fixtures = _mixed_fleet(n_pair=6, n_band=2, n_bi=0, n_lstm=0,
+                                   n_hpa=0)
+    eng = Analyzer(EngineConfig(), FixtureDataSource(fixtures), store,
+                   exporter)
+    eng.run_cycle(now=1000.0)
+    score = next(sp for sp in _spans(_cycle_root(eng))
+                 if sp["name"] == tracing.SPAN_ENGINE_SCORE)["attrs"]
+    counters = eng.last_cycle_stages["partition"]["counters"]
+    for key in ("pair_sort_elems", "pair_real_elems", "pair_T_max"):
+        assert score[key] == counters[key]
+    assert counters["pair_sort_elems"] == eng.pair_sort_elems_total
+    assert 0 < counters["pair_real_elems"] < counters["pair_sort_elems"]
+    assert counters["pair_sort_elems"] % (2 * counters["pair_T_max"]) == 0
+    gauges = {name: value for name, _, value in exporter.samples()}
+    assert gauges["foremastbrain:pair_sort_fill_share"] == round(
+        counters["pair_real_elems"] / counters["pair_sort_elems"], 6)
+    # a cycle with no pair launch reads 0, and nothing is carried over
+    store2, fixtures2 = _mixed_fleet(n_pair=0, n_band=2, n_bi=0, n_lstm=0,
+                                     n_hpa=0)
+    eng2 = Analyzer(EngineConfig(), FixtureDataSource(fixtures2), store2,
+                    exporter)
+    eng2.run_cycle(now=1000.0)
+    counters = eng2.last_cycle_stages["partition"]["counters"]
+    assert counters["pair_sort_elems"] == counters["pair_T_max"] == 0
+    gauges = {name: value for name, _, value in exporter.samples()}
+    assert gauges["foremastbrain:pair_sort_fill_share"] == 0.0
+
+
 def test_no_span_opens_on_a_fetch_pool_thread(monkeypatch):
     """A span on a pool thread would be the innermost span over the
     device's long idle gap and rename it after a chunk of the pool: the
