@@ -398,11 +398,13 @@ class Analyzer:
         self.pack_real_elems_total = 0
         self.pack_total_elems_total = 0
         # -- the pair program's sort (cumulative): elements each launched
-        # row sorts (both windows at the bucket, 2T) against the valid
-        # samples of the real rows' windows; and the widest bucket a pair
-        # launch of the cycle used (per cycle)
+        # row sorts (each window at its bucket, Tb + Tc) against the valid
+        # samples of the real rows' windows, and the real rows whose
+        # current window was packed narrower than their baseline; and the
+        # widest bucket a pair launch of the cycle used (per cycle)
         self.pair_sort_elems_total = 0
         self.pair_real_elems_total = 0
+        self.pair_split_rows_total = 0
         self._cycle_pair_T_max = 0
         # -- seasonal band launches: partitions by detected period
         # (cumulative), and the largest seasonal state a Holt-Winters fit
@@ -967,10 +969,14 @@ class Analyzer:
         e = max(n.bit_length() - 5, 0)  # keeps the mantissa in [16, 32)
         return -(-n // (1 << e)) << e
 
-    def _mega_cap(self, T: int) -> int:
+    def _mega_cap(self, T) -> int:
         """Mega-launch row ceiling for a T bucket: MEGABATCH_MAX_ROWS at
         T <= 1024, scaled ~1/T beyond (floor 1024) so a long-history
-        bucket's mega launch costs the same peak bytes as a short one."""
+        bucket's mega launch costs the same peak bytes as a short one.
+        T may be a route key: a pair's (Tb, Tc) is capped at its wider
+        side, as `_launch_chunks` chunks it."""
+        if isinstance(T, tuple):
+            T = max(T)
         max_rows = max(int(self.config.megabatch_max_rows), 1024)
         budget = max_rows * 1024  # row-steps at the base T
         return int(min(max_rows, max(budget // max(int(T), 1024), 1024)))
@@ -1162,23 +1168,48 @@ class Analyzer:
     # per-job retry and prewarm (`Family.score`) are launch + immediate
     # collect over the same code, so the paths cannot drift.
 
-    @staticmethod
-    def _pair_T(it: _PairItem) -> int:
-        return bucket_length(
-            max(it.baseline.values.shape[0], it.current.values.shape[0])
-        )
+    # A pair row's current window is packed narrower than its baseline at
+    # one width alone, and only under a baseline bucket at least
+    # _PAIR_SPLIT_RATIO times that width: the row then carries at most
+    # 17/32 of its symmetric elements (the 7-day baseline against a
+    # 20-to-31-point window: 16,416 of 32,768). In the narrower buckets
+    # the saving (at most 96 of 256 elements a row at T=128) would not
+    # pay for a second launch key, its own partly filled rung and its own
+    # program, so their rows stay symmetric. One width a bucket keeps
+    # prewarm's grid to two programs a (rung, T).
+    _PAIR_SHORT_T = 32
+    _PAIR_SPLIT_RATIO = 16
 
-    def _launch_pairs(self, group: list, T: int):
+    @classmethod
+    def _pair_short_T(cls, Tb: int) -> int:
+        """The bucket a short current window is packed at under baseline
+        bucket Tb: `_PAIR_SHORT_T`, or Tb itself where no row splits."""
+        s = cls._PAIR_SHORT_T
+        return s if Tb >= cls._PAIR_SPLIT_RATIO * s else Tb
+
+    @classmethod
+    def _pair_T(cls, it: _PairItem) -> tuple:
+        """(Tb, Tc): the baseline at the bucket of the row's longer
+        window; the current window at `_pair_short_T(Tb)` where it fits
+        there, else at Tb. The baseline keeps the row's bucket, so the
+        band's judged region starts where it always did."""
+        nc = it.current.values.shape[0]
+        Tb = bucket_length(max(it.baseline.values.shape[0], nc))
+        Tc = cls._pair_short_T(Tb)
+        return (Tb, Tc if nc <= Tc else Tb)
+
+    def _launch_pairs(self, group: list, T: tuple):
         cfg = self.config
+        Tb, Tc = T
         B = len(group)
         # a pair's rows are its routed windows: nothing is built a row
         with tracing.span(tracing.SPAN_ENGINE_PACK_ROWS, rows=B, bytes=0):
             pass
         with tracing.span(tracing.SPAN_ENGINE_PACK_BLOCK, rows=B) as sp:
-            R = self._launch_rows(B, T)
-            bvals, bm = pack_windows([it.baseline for it in group], pad_to=T,
-                                     rows=R)
-            cv, cm = pack_windows([it.current for it in group], pad_to=T,
+            R = self._launch_rows(B, Tb)
+            bvals, bm = pack_windows([it.baseline for it in group],
+                                     pad_to=Tb, rows=R)
+            cv, cm = pack_windows([it.current for it in group], pad_to=Tc,
                                   rows=R)
             arrays = [
                 bvals, bm, cv, cm,
@@ -1210,11 +1241,13 @@ class Analyzer:
                 [it.baseline.values.shape[0] + it.current.values.shape[0]
                  for it in group])
             sp.attrs.update(bytes=_nbytes(arrays), edge_rows=R - B)
-        self.pair_sort_elems_total += R * 2 * T
+        self.pair_sort_elems_total += R * (Tb + Tc)
         self.pair_real_elems_total += sum(
             int(np.count_nonzero(it.baseline.mask))
             + int(np.count_nonzero(it.current.mask)) for it in group)
-        self._cycle_pair_T_max = max(self._cycle_pair_T_max, T)
+        if Tb != Tc:
+            self.pair_split_rows_total += B
+        self._cycle_pair_T_max = max(self._cycle_pair_T_max, Tb)
         launches = self._launch_chunks(fl.score_pairs, arrays, donate=4,
                                        row_elems=row_elems, rows=B)
         return (group, launches)
@@ -2544,6 +2577,7 @@ class Analyzer:
         real0, total0 = self.pack_real_elems_total, self.pack_total_elems_total
         sort0 = self.pair_sort_elems_total
         pair_real0 = self.pair_real_elems_total
+        split0 = self.pair_split_rows_total
         self._cycle_pair_T_max = 0
         parts0 = self.period_partitions_total
         self._cycle_hw_state_bytes = 0
@@ -2679,6 +2713,7 @@ class Analyzer:
                 "pack_total_elems": self.pack_total_elems_total - total0,
                 "pair_sort_elems": self.pair_sort_elems_total - sort0,
                 "pair_real_elems": self.pair_real_elems_total - pair_real0,
+                "pair_split_rows": self.pair_split_rows_total - split0,
                 "pair_T_max": self._cycle_pair_T_max,
                 "period_partitions": self.period_partitions_total - parts0,
                 "hw_candidates": (fc.HW_CANDIDATES
@@ -2930,6 +2965,12 @@ class Analyzer:
                 help="Share of the elements the last cycle's pair launches "
                      "sorted that were valid samples of real rows (0: no "
                      "pair launch).")
+            self.exporter.record_gauge(
+                "foremastbrain:pair_split_rows", {},
+                counters["pair_split_rows"],
+                help="Pair rows the last cycle launched with the current "
+                     "window packed at a narrower bucket than its "
+                     "baseline.")
             self.exporter.record_gauge(
                 "foremastbrain:period_partitions", {},
                 counters["period_partitions"],

@@ -65,7 +65,7 @@ class Family:
     key: Callable = None  # item -> its result's key
     # -- the stream. `route` None: the family is scored at finish, over
     # the cycle's items in claim order, by `scorer`
-    route: Callable = None  # (an, row) -> (entry, T bucket)
+    route: Callable = None  # (an, row) -> (entry, T bucket: pair's (Tb, Tc))
     rows: Callable = lambda an, items: items  # one job's items -> rows
     row_items: Callable = lambda row: [row]  # of a row `route` failed on
     items_of: Callable = list  # entries -> items, for the per-job retry
@@ -88,6 +88,9 @@ class Family:
     # (rung, n_h, n_c, win, policy) -> `rung` rows of items in bucket
     # n_h + n_c (`win(n)`: a window of n samples). None: prewarm skips it
     prewarm_items: Callable = None
+    # (an, T, n_c) -> the current windows' lengths prewarm gives
+    # `prewarm_items` in bucket T, one launch key each
+    prewarm_currents: Callable = lambda an, T, n_c: (n_c,)
     # -- triage: may the screen clear its rows under this config
     # (TRIAGE_FAMILIES chooses among those that say yes), and if so
     screens: Callable = lambda cfg: False
@@ -132,6 +135,13 @@ def _pair_cause(an, it, r):
     if r["band_unhealthy"]:
         causes.append(f"{r['band_count']} points outside the baseline band")
     return (it.metric, "; ".join(causes), [])
+
+
+def _pair_prewarm_currents(an, T, n_c):
+    # the bucket's symmetric rows, and where a short current window has
+    # a key of its own (`Analyzer._pair_short_T`), one such window
+    short = an._pair_short_T(T)
+    return (n_c,) if short == T else (n_c, short)
 
 
 def _bi_route(an, it):
@@ -199,6 +209,7 @@ FAMILIES: tuple = (
         prewarm_items=lambda rung, n_h, n_c, win, policy: [
             _PairItem(f"w{i}", "latency", win(n_h), win(n_c), policy)
             for i in range(rung)],
+        prewarm_currents=_pair_prewarm_currents,
         screens=lambda cfg: True,
         screen_rows=lambda it: [
             (*_concat_trimmed(it.baseline, it.current), it.policy)],

@@ -534,14 +534,15 @@ def prewarm(config=None,
     programs = 0
 
     @contextmanager
-    def program(family, rung, T):
+    def program(family, rung, T, cur=None):
         # one log line per program: on the chip a single program can take
         # a minute to compile, and a silent prewarm looks like a hang
         nonlocal programs
         t1 = time.perf_counter()
         yield
         programs += 1
-        log.info("prewarm %s rung=%d T=%d: %.1fs", family, rung, T,
+        log.info("prewarm %s rung=%d T=%d%s: %.1fs", family, rung, T,
+                 "" if cur is None else f" current={cur}",
                  time.perf_counter() - t1)
 
     # one root for the grid's engine.launch / engine.materialize spans:
@@ -569,9 +570,11 @@ def prewarm(config=None,
                             cfg.ma_window)["count"])
             for r in rungs:
                 for fam in table:
-                    with program(fam.name, r, T):
-                        fam.score(an, fam.prewarm_items(
-                            r, n_h, n_c, win, policy))
+                    for cur in fam.prewarm_currents(an, T, n_c):
+                        with program(fam.name, r, T,
+                                     None if cur == n_c else cur):
+                            fam.score(an, fam.prewarm_items(
+                                r, n_h, cur, win, policy))
     return {
         "families": list(families),
         "rungs": list(rungs),

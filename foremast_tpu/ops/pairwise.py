@@ -45,6 +45,7 @@ __all__ = [
     "kruskal_wallis",
     "friedman_chi_square",
     "sign_test_exact",
+    "paired_columns",
     "ks_2samp",
     "mann_whitney_u_batch",
     "wilcoxon_batch",
@@ -152,6 +153,17 @@ def _ks_pvalue(t, n1, n2, Ti: int, Tj: int):
         )
         p = jnp.where((n1 <= Ki) & (n2 <= Kj), p_exact, p_asym)
     return jnp.where((n1 > 0) & (n2 > 0), p, 1.0)
+
+
+def paired_columns(x, x_mask, y, y_mask):
+    """Two windows cut to their common width: the positions where a
+    sample of each side can sit, which is all a paired test (Wilcoxon,
+    the exact sign test) may pair. A window is right-padded into its own
+    bucket, so a 31-point current window against a 10,081-point baseline
+    pairs at most its first 31 slots. The cut is static, and at equal
+    widths it traces to nothing."""
+    P = min(x.shape[-1], y.shape[-1])
+    return x[..., :P], x_mask[..., :P], y[..., :P], y_mask[..., :P]
 
 
 def _short_side(from_x, n1, n2):
@@ -479,8 +491,10 @@ def two_sample_tests(x, x_mask, y, y_mask):
         cumulative x-count at the END of its tie group (the `<=` semantics
         of the O(T^2) formulation, same cummin smear as the tie bounds) —
         no (2T x T) comparison matrix, no gathers.
-    Only Wilcoxon needs its own (shorter) sort of |diffs|. Returns
-    {test: (stat, p)} identical to the standalone kernels.
+    Only Wilcoxon needs its own (shorter) sort of |diffs|, over the
+    windows' common width (`paired_columns`): x and y may differ in
+    width, and the combined sort then covers the two widths' sum.
+    Returns {test: (stat, p)} identical to the standalone kernels.
     """
     Tx = x.shape[-1]
     comb = jnp.concatenate([x, y]).astype(_F)
@@ -533,7 +547,7 @@ def two_sample_tests(x, x_mask, y, y_mask):
     D = _safe_div(t_ks, n1 * n2)
     p_ks = _ks_pvalue(t_ks, n1, n2, Tx, y.shape[-1])
 
-    W, p_w = wilcoxon_signed_rank(x, x_mask, y, y_mask)
+    W, p_w = wilcoxon_signed_rank(*paired_columns(x, x_mask, y, y_mask))
     return {
         "mann_whitney": (U1, p_mw),
         "kruskal": (H, p_k),

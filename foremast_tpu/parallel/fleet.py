@@ -29,7 +29,7 @@ from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops import forecast as fc
-from ..ops.pairwise import sign_test_exact, two_sample_tests
+from ..ops.pairwise import paired_columns, sign_test_exact, two_sample_tests
 from ..ops.rowblock import vmap_rows
 from .mesh import FLEET_AXIS, fleet_sharding
 
@@ -71,6 +71,11 @@ def _pair_verdict(
 ):
     """Single (baseline, current) judgment. vmapped by score_pairs.
 
+    The two windows may differ in width (each is packed at its own
+    bucket): the rank sort and the KS grid cover each side at its own,
+    the paired tests the common width, and the band's judged region
+    starts where the baseline ends.
+
     min_points: (3,) or (4,) gates for mann-whitney/wilcoxon/kruskal
     [/friedman] — the MIN_*_DATA_POINTS config surface
     (foremast-brain.yaml:74-79); a 3-wide vector keeps Friedman at its
@@ -94,9 +99,11 @@ def _pair_verdict(
     # the p-value comes from the exact sign test rather than the df=1
     # chi-square approximation, which is anti-conservative at small block
     # counts (see ops.pairwise.sign_test_exact).
-    paired_blocks = b_mask & c_mask
+    pb, pb_mask, pc, pc_mask = paired_columns(baseline, b_mask, current,
+                                              c_mask)
+    paired_blocks = pb_mask & pc_mask
     n_blocks = jnp.sum(paired_blocks.astype(_F))
-    _, p_friedman = sign_test_exact(baseline, current, paired_blocks)
+    _, p_friedman = sign_test_exact(pb, pc, paired_blocks)
     pvals = jnp.stack(
         [
             tests["mann_whitney"][1],
@@ -180,19 +187,22 @@ def _score_rows(*args):
 score_pairs = jax.jit(_score_rows)
 
 
-def pair_arg_spec(B: int, T: int):
+def pair_arg_spec(B: int, T: int, Tc: int | None = None):
     """Zeroed argument tuple matching score_pairs' PRODUCTION signature.
 
     Mirrors analyzer._launch_pairs' packing (shapes and dtypes) so a
     caller (chip_smoke.py, the row-block tests) can compile or cost a
     (rung, T) program without synthesizing windows; the transfer-counter
     test (tests/test_pipeline.py) holds its bytes to the real packing's.
+    `Tc` is the current window's width where it is packed narrower than
+    the baseline's T (default T).
     """
     import numpy as np
 
+    Tc = T if Tc is None else Tc
     return (
         np.zeros((B, T), np.float32), np.zeros((B, T), bool),
-        np.zeros((B, T), np.float32), np.zeros((B, T), bool),
+        np.zeros((B, Tc), np.float32), np.zeros((B, Tc), bool),
         np.zeros(B, np.float32),                    # pairwise p threshold
         np.zeros(B, np.int32),                      # enabled-test bitmask
         np.zeros(B, np.int32),                      # ANY/ALL combinator

@@ -451,3 +451,110 @@ def test_short_side_route_is_todays_at_t128(route, ties):
     U0, p0 = jax.jit(jax.vmap(_mw_long_side))(x, xm, y, ym)
     np.testing.assert_array_equal(np.asarray(U), np.asarray(U0))
     np.testing.assert_array_equal(np.asarray(p), np.asarray(p0))
+
+
+# --------------------------------- each window of a pair at its own bucket
+# A pair row is packed with its current window at the current's own
+# bucket (Tc = 32 for a 20-to-31-point window) and its baseline at the
+# row's: the program must judge it as it judged the same row padded
+# symmetric at the baseline's bucket.
+SPLIT_TC = 32
+
+
+def _split_rows(Tb, nb, seed):
+    """Eight (Tb, 32) pair rows, each a case the split must carry:
+    float samples, heavy integer ties, a NaN, an all-masked current,
+    a current window with more samples than its baseline, gaps."""
+    rng = np.random.default_rng(seed)
+    rows, Tc = 8, SPLIT_TC
+    b = np.zeros((rows, Tb), np.float32)
+    c = np.zeros((rows, Tc), np.float32)
+    bm = np.zeros((rows, Tb), bool)
+    cm = np.zeros((rows, Tc), bool)
+
+    def put(r, base, cur):
+        b[r, :len(base)], c[r, :len(cur)] = base, cur
+        bm[r, :len(base)], cm[r, :len(cur)] = True, True
+
+    put(0, np.round(10 + rng.normal(size=nb), 4),
+        np.round(10.4 + rng.normal(size=31), 4))
+    put(1, np.round(10 + rng.normal(size=nb), 4),
+        np.round(10 + rng.normal(size=20), 4))
+    put(2, rng.integers(0, 4, nb), rng.integers(0, 4, 27))
+    put(3, rng.integers(0, 4, nb), rng.integers(2, 6, 31))
+    put(4, np.round(10 + rng.normal(size=nb), 4),
+        np.round(10 + rng.normal(size=24), 4))
+    c[4, 7] = np.nan
+    put(5, np.round(10 + rng.normal(size=nb), 4),
+        np.round(10 + rng.normal(size=25), 4))
+    cm[5] = False
+    put(6, np.round(10 + rng.normal(size=12), 4),
+        np.round(10.2 + rng.normal(size=31), 4))
+    put(7, rng.integers(0, 50, nb), rng.integers(0, 50, 30))
+    bm[7, rng.random(Tb) < 0.1] = False
+    cm[7, [2, 3, 17]] = False
+    return b, bm, c, cm
+
+
+def _symmetric(c, cm, Tb):
+    """The same current windows right-padded to the baseline's bucket."""
+    pad = ((0, 0), (0, Tb - c.shape[1]))
+    return np.pad(c, pad), np.pad(cm, pad)
+
+
+SPLIT_SHAPES = [(1024, 600), (16384, 10081)]
+
+
+@pytest.mark.parametrize("Tb,nb", SPLIT_SHAPES)
+def test_score_pairs_split_equals_symmetric(Tb, nb):
+    """Every output of the fleet program on (Tb, 32) rows equals the same
+    rows at (Tb, Tb): flags and counts exactly, p-values within 1e-7
+    (a float32 tie term or tail sum may be reduced in another order)."""
+    from foremast_tpu.parallel import fleet as fl
+
+    b, bm, c, cm = _split_rows(Tb, nb, seed=Tb)
+    rows = b.shape[0]
+    args = list(fl.pair_arg_spec(rows, Tb, SPLIT_TC))
+    args[:4] = b, bm, c, cm
+    args[4] = np.full(rows, 0.01, np.float32)
+    args[5] = np.full(rows, 31, np.int32)  # all five tests
+    args[6] = np.full(rows, fl.COMBINE_ANY, np.int32)
+    args[8] = np.full(rows, 3.0, np.float32)
+    args[9] = np.full(rows, 3, np.int32)  # both sides of the band
+    split = {k: np.asarray(v) for k, v in fl.score_pairs(*args).items()}
+    args[2:4] = _symmetric(c, cm, Tb)
+    sym = {k: np.asarray(v) for k, v in fl.score_pairs(*args).items()}
+    for k in ("unhealthy", "pairwise_unhealthy", "band_unhealthy",
+              "band_count"):
+        np.testing.assert_array_equal(split[k], sym[k], err_msg=k)
+    for k in ("pvalues", "min_p"):
+        np.testing.assert_allclose(split[k], sym[k], rtol=0, atol=1e-7,
+                                   err_msg=k)
+    # the cases are live: a rejection, a band hit, a row that cannot judge
+    assert split["pairwise_unhealthy"].any()
+    assert split["band_count"].max() > 0
+    assert split["min_p"][5] == 1.0
+
+
+@pytest.mark.parametrize("Tb,nb", SPLIT_SHAPES)
+def test_two_sample_tests_split_statistics_are_exact(Tb, nb):
+    """U, W and the KS distance are counts and half-integers: the split
+    gives them bit for bit; H carries the tie term's float32 sum."""
+    import jax
+
+    from foremast_tpu.ops import two_sample_tests
+
+    b, bm, c, cm = _split_rows(Tb, nb, seed=Tb + 1)
+    run = jax.jit(jax.vmap(two_sample_tests))
+    split = run(b, bm, c, cm)
+    sym = run(b, bm, *_symmetric(c, cm, Tb))
+    for test in ("mann_whitney", "wilcoxon", "ks"):
+        np.testing.assert_array_equal(np.asarray(split[test][0]),
+                                      np.asarray(sym[test][0]),
+                                      err_msg=test)
+    np.testing.assert_allclose(np.asarray(split["kruskal"][0]),
+                               np.asarray(sym["kruskal"][0]), rtol=1e-6)
+    for test in split:
+        np.testing.assert_allclose(np.asarray(split[test][1]),
+                                   np.asarray(sym[test][1]), rtol=0,
+                                   atol=1e-7, err_msg=test)

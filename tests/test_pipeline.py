@@ -420,7 +420,14 @@ def test_every_family_answers_every_question_of_the_table(name):
         rows = fam.rows(eng, items)
         assert len(rows) == rung
         entry, bucket = fam.route(eng, rows[0])
-        assert bucket == T == bucket_length(bucket)
+        if name == "pair":
+            # a pair's key is (Tb, Tc); no row of a T=128 bucket splits,
+            # and from T=512 prewarm gives the short-current key too
+            assert bucket == (T, T) and T == bucket_length(T)
+            assert fam.prewarm_currents(eng, 512, 128) == (128, 32)
+        else:
+            assert bucket == T == bucket_length(bucket)
+        assert fam.prewarm_currents(eng, T, n_c) == (n_c,)
         assert {it.job_id for it in fam.row_items(rows[0])} == {"w0"}
         assert {it.job_id for it in fam.items_of([entry])} == {"w0"}
         key, fp, nbytes, hashed, reused = eng._memo_key_fp(fam, entry,
@@ -982,6 +989,165 @@ def test_pair_sort_counters_ride_the_score_span_and_a_gauge():
     assert counters["pair_sort_elems"] == counters["pair_T_max"] == 0
     gauges = {name: value for name, _, value in exporter.samples()}
     assert gauges["foremastbrain:pair_sort_fill_share"] == 0.0
+
+
+def test_pair_route_keys_each_window_at_its_own_bucket():
+    """A rollover row (a 20-to-31-point window against the trailing 7
+    days) routes to (16384, 32), and so does any window of at most 32
+    points against a baseline bucket of 512 or more; a longer window, or
+    any row of a narrower bucket (a T=128 canary, a 20-point window at
+    T=64), is symmetric, and so is a row whose current window is the
+    longer one: the baseline keeps the row's bucket."""
+    from foremast_tpu.engine.analyzer import _PairItem
+
+    rng = np.random.default_rng(2)
+    eng = Analyzer(EngineConfig(), None, JobStore())
+    policy = eng.config.policy_for("latency")
+    route = families.family("pair").route
+    for (nb, nc), key in [((10081, 20), (16384, 32)),
+                          ((10081, 31), (16384, 32)),
+                          ((10081, 40), (16384, 16384)),
+                          ((300, 20), (512, 32)),
+                          ((200, 20), (256, 256)),
+                          ((100, 20), (128, 128)),
+                          ((40, 20), (64, 64)),
+                          ((80, 100), (128, 128)),
+                          ((80, 80), (128, 128)),
+                          ((20, 100), (128, 128))]:
+        it = _PairItem("j", "latency", *_two_windows(rng, nb, nc), policy)
+        assert route(eng, it) == (it, key), (nb, nc)
+
+
+def test_split_pair_launch_counts_what_it_packs():
+    """Three rows of a long baseline against a short window launch at
+    (1024, 32): what crosses is the split packing's bytes, the sort is
+    R x (Tb + Tc), and the three real rows count as split."""
+    import jax
+
+    from foremast_tpu.engine.analyzer import _PairItem
+    from foremast_tpu.parallel import fleet as fl
+
+    rng = np.random.default_rng(9)
+    eng = Analyzer(EngineConfig(), None, JobStore())
+    policy = eng.config.policy_for("latency")
+    R, Tb, Tc = 16, 1024, 32
+    lens = [(600, 20), (590, 31), (601, 25)]
+    items = [_PairItem(f"j{i}", "latency", *_two_windows(rng, b, c), policy)
+             for i, (b, c) in enumerate(lens)]
+    results = families.family("pair").score(eng, items)
+    assert len(results) == 3 and eng.device_launches == 1
+    spec = fl.pair_arg_spec(R, Tb, Tc)
+    assert eng.h2d_bytes_total == sum(a.nbytes for a in spec)
+    assert eng.d2h_bytes_total == sum(
+        int(np.prod(o.shape)) * o.dtype.itemsize
+        for o in jax.tree_util.tree_leaves(
+            jax.eval_shape(fl.score_pairs, *spec)))
+    assert eng.pair_sort_elems_total == R * (Tb + Tc)
+    assert eng.pack_total_elems_total == R * (Tb + Tc)
+    assert eng.pair_real_elems_total == eng.pack_real_elems_total \
+        == sum(b + c for b, c in lens)
+    assert eng.pair_split_rows_total == 3
+    assert eng._cycle_pair_T_max == Tb
+
+
+def _rollover_fleet(n, n_hist, n_cur=24, seed=13):
+    """`n` pair jobs judging a short current window against a long
+    baseline, as foremast-trigger's rollover request does, with healthy
+    data so every job is judged again next cycle: (store, fixtures)."""
+    rng = np.random.default_rng(seed)
+    fixtures, store = {}, JobStore()
+    for i in range(n):
+        cur, base = f"u/r{i}/c", f"u/r{i}/b"
+        fixtures[base] = _series(rng, 0.5, n_hist)
+        fixtures[cur] = _series(rng, 0.5, n_cur)
+        store.create(Document(
+            id=f"r{i}", app_name=f"app-r{i}", namespace="px",
+            strategy="canary", start_time=to_rfc3339(0.0),
+            end_time=to_rfc3339(5_000_000.0),
+            metrics={"error5xx": MetricQueries(current=cur, baseline=base)}))
+    return store, fixtures
+
+
+def test_pair_split_rows_ride_the_score_span_and_a_gauge():
+    exporter = VerdictExporter()
+    store, fixtures = _rollover_fleet(5, 600)
+    eng = Analyzer(EngineConfig(), FixtureDataSource(fixtures), store,
+                   exporter)
+    eng.run_cycle(now=1000.0)
+    score = next(sp for sp in _spans(_cycle_root(eng))
+                 if sp["name"] == tracing.SPAN_ENGINE_SCORE)["attrs"]
+    counters = eng.last_cycle_stages["partition"]["counters"]
+    assert score["pair_split_rows"] == counters["pair_split_rows"] == 5
+    assert counters["pair_sort_elems"] == 16 * (1024 + 32)
+    assert counters["pair_T_max"] == 1024
+    gauges = {name: value for name, _, value in exporter.samples()}
+    assert gauges["foremastbrain:pair_split_rows"] == 5
+    # symmetric rows are not split
+    store2, fixtures2 = _mixed_fleet(n_pair=4, n_band=0, n_bi=0, n_lstm=0,
+                                     n_hpa=0)
+    eng2 = Analyzer(EngineConfig(), FixtureDataSource(fixtures2), store2,
+                    exporter)
+    eng2.run_cycle(now=1000.0)
+    assert eng2.last_cycle_stages["partition"]["counters"][
+        "pair_split_rows"] == 0
+    gauges = {name: value for name, _, value in exporter.samples()}
+    assert gauges["foremastbrain:pair_split_rows"] == 0
+
+
+@pytest.mark.perf
+def test_prewarm_covers_short_current_windows():
+    """A pair grid prewarmed at T 64/512/16384 covers every key a short
+    current window routes to: a 20-point window at T=64 (symmetric), at
+    T=512 (512, 32), and the rollover's 31-point window against the
+    7-day baseline (16384, 32). A cycle over them compiles nothing."""
+    cfg = EngineConfig(pairwise_threshold=1e-4)
+    summary = prewarm(cfg, families=("pair",), rungs=(16,),
+                      t_buckets=(64, 512, 16384))
+    assert summary["programs"] == 5  # (64, 64), two at 512, two at 16384
+    rng = np.random.default_rng(4)
+    fixtures, store = {}, JobStore()
+    for i, (nb, nc) in enumerate([(40, 20), (60, 20), (300, 20),
+                                  (400, 31), (10081, 31)]):
+        cur, base = f"u/s{i}/c", f"u/s{i}/b"
+        fixtures[cur] = _series(rng, 0.5, nc)
+        fixtures[base] = _series(rng, 0.5, nb)
+        store.create(Document(
+            id=f"s{i}", app_name="a", namespace="n", strategy="canary",
+            start_time=to_rfc3339(0.0), end_time=to_rfc3339(5_000_000.0),
+            metrics={"error5xx": MetricQueries(current=cur, baseline=base)},
+        ))
+    eng = Analyzer(cfg, FixtureDataSource(fixtures), store)
+    with CompileCounter() as cc:
+        out = eng.run_cycle(now=1000.0)
+    assert len(out) == 5
+    assert eng.last_cycle_stages["partition"]["counters"][
+        "pair_split_rows"] == 3
+    assert cc.compiles == 0, (
+        f"cycle after prewarm compiled {cc.compiles} program(s): a short "
+        "current window's key is missing from the prewarm grid")
+
+
+@pytest.mark.perf
+def test_rollover_cycles_trigger_zero_recompiles():
+    """A 7-day baseline against a window that grows a sample a cycle
+    inside bucket 32: after one warm-up cycle, two more launch the
+    (16384, 32) program already compiled."""
+    store, fixtures = _rollover_fleet(3, 10081)
+    eng = Analyzer(EngineConfig(), FixtureDataSource(fixtures), store)
+    eng.run_cycle(now=1000.0)
+    launches = eng.device_launches
+    with CompileCounter() as cc:
+        for _ in range(2):
+            for i in range(3):
+                ts, vals = fixtures[f"u/r{i}/c"]
+                fixtures[f"u/r{i}/c"] = (ts + [ts[-1] + STEP],
+                                         vals + [vals[-1]])
+            eng.run_cycle(now=1000.0)
+            assert eng.last_cycle_stages["partition"]["counters"][
+                "pair_split_rows"] == 3
+    assert eng.device_launches == launches + 2
+    assert cc.compiles == 0, (
+        f"rollover cycles compiled {cc.compiles} program(s) after warm-up")
 
 
 def test_no_span_opens_on_a_fetch_pool_thread(monkeypatch):
